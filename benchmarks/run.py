@@ -445,10 +445,11 @@ def main() -> None:
     grad = "--grad" in sys.argv
     qnt = "--quant" in sys.argv
     srv = "--serve" in sys.argv
+    from repro import compile_cache, obs
+
+    compile_cache.enable()
     # arm the dispatch-layer counters (not tracing) so the provenance
     # header records which rung served each autotune key and for how long
-    from repro import obs
-
     obs.enable_dispatch()
     from benchmarks import fig1_speedup, fig2_throughput, roofline_report, table_conv1d
 

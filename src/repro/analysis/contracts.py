@@ -2,8 +2,8 @@
 
 Every Pallas kernel family in this repo follows the same halo-tiled shape:
 a grid over (batch, spatial tiles, channel blocks, reduction sweep), input
-BlockSpecs whose ``pl.unblocked`` index maps read a halo-widened window
-from a pre-padded array, and — when a grid dim revisits an output block —
+``pl.Element`` BlockSpecs whose index maps read a halo-widened window from
+a pre-padded array, and — when a grid dim revisits an output block —
 an accumulation scratch in a widened dtype with the output written only on
 the final visit. Each of those properties broke at least once in this
 repo's history (the seed's out-of-bounds halo indexing is why PR 1
@@ -15,9 +15,10 @@ cannot drift on tile defaults — and the checker evaluates the declaration
 over the autotune key space:
 
   * **halo_oob** — every index-mapped block stays inside its (padded)
-    array for every grid point: ``pl.unblocked`` maps return *element*
-    offsets, so ``offset + block_shape <= array_shape`` per axis (blocked
-    maps return block indices, scaled by the block shape first).
+    array for every grid point: ``pl.Element`` maps return *element*
+    offsets on every axis, so ``offset + block_shape <= array_shape`` per
+    axis (blocked maps return block indices, scaled by the block shape
+    first).
   * **vmem_budget** — per-grid-instance working set: in/out blocks are
     double-buffered by the pipeline (×2) plus scratch, must fit the
     configurable budget (default 16 MB — one TPU core's VMEM). This is
@@ -74,8 +75,8 @@ class Block:
     """One BlockSpec (or scratch buffer) of a kernel instance.
 
     ``index_map`` maps grid indices to offsets — *element* offsets when
-    ``unblocked`` (the halo specs), block indices otherwise. Scratch
-    buffers have no map and no backing array.
+    ``element`` (the ``pl.Element`` halo specs), block indices otherwise.
+    Scratch buffers have no map and no backing array.
     """
 
     name: str
@@ -83,7 +84,7 @@ class Block:
     dtype: str
     index_map: Callable[..., tuple] | None = None
     array_shape: tuple[int, ...] | None = None
-    unblocked: bool = False
+    element: bool = False
 
     def nbytes(self) -> int:
         return math.prod(self.shape) * DTYPE_BYTES[self.dtype]
@@ -157,7 +158,7 @@ def _block_bounds_violation(
                 f"block rank {len(blk.shape)}",
             )
         for d, (o, bs, asz) in enumerate(zip(off, blk.shape, blk.array_shape)):
-            lo = o if blk.unblocked else o * bs
+            lo = o if blk.element else o * bs
             if lo < 0 or lo + bs > asz:
                 return Violation(
                     "halo_oob", inst.family, inst.key,
@@ -266,12 +267,26 @@ def check_instance(
 # ---------------------------------------------------------------------------
 
 def _conv1d_geom(L, K, stride, tile_l, out_len):
+    """(tile_l, n_tiles, padded_out, halo, padded input length) — the halo
+    rounded to whole sublanes as ``sliding_conv1d.halo_input`` does."""
+    from repro.kernels.sliding_conv1d import halo_rows
+
     tile_l = min(tile_l, out_len)
     n_tiles = _cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    return tile_l, n_tiles, padded_out, halo, max(L, need)
+    halo = halo_rows((tile_l - 1) * stride + K)
+    return (
+        tile_l, n_tiles, padded_out, halo,
+        max(L, (n_tiles - 1) * tile_l * stride + halo),
+    )
+
+
+def _phase_split(L, Cin, K, stride):
+    """(L, Cin, K) of the stride-1 launch a stride-s multi-channel conv
+    becomes (``sliding_conv1d.phase_split``/``phase_taps``)."""
+    if stride == 1:
+        return L, Cin, K
+    return _cdiv(L, stride), stride * Cin, _cdiv(K, stride)
 
 
 def build_conv1d(
@@ -279,20 +294,24 @@ def build_conv1d(
     tile_l=None, cin_block=0, cout_block=0, regime=None,
 ) -> KernelInstance:
     """Contract for ``sliding_conv1d.conv1d_sliding_pallas`` (fp) and
-    ``sliding_conv_quant.conv1d_quant_pallas`` (w8a8/w8a16)."""
+    ``sliding_conv_quant.conv1d_quant_pallas`` (w8a8/w8a16). A stride
+    above 1 is checked as the stride-1 launch over the phase-split input
+    it runs as."""
     from repro.core.conv import regime_for
     from repro.kernels.sliding_conv1d import (
-        DEFAULT_TILE_L, TAP_CHUNK, _resolve_block,
+        DEFAULT_TILE_L, TAP_CHUNK, _resolve_block, halo_rows,
     )
 
-    out_len = (L - K) // stride + 1
-    if out_len < 1:
+    if (L - K) // stride + 1 < 1:
         raise ValueError(f"K={K} stride={stride} exceeds L={L}")
-    tile_l, n_tiles, padded_out, halo, xlen = _conv1d_geom(
-        L, K, stride, tile_l or DEFAULT_TILE_L, out_len
-    )
+    key = f"conv1d|B{B}|L{L}|Cin{Cin}|Cout{Cout}|K{K}|s{stride}|{precision}"
     if regime is None:
         regime = "custom" if K in (3, 5) else regime_for(K)
+    L, Cin, K = _phase_split(L, Cin, K, stride)
+    out_len = L - K + 1
+    tile_l, n_tiles, padded_out, halo, xlen = _conv1d_geom(
+        L, K, 1, tile_l or DEFAULT_TILE_L, out_len
+    )
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
     n_ci, n_co = _cdiv(Cin, cb), _cdiv(Cout, ob)
@@ -300,21 +319,21 @@ def build_conv1d(
     w8a8 = precision == "w8a8"
     xdt = "int8" if w8a8 else dtype
     wdt = "int8" if precision in ("w8a8", "w8a16") else dtype
-    key = f"conv1d|B{B}|L{L}|Cin{Cin}|Cout{Cout}|K{K}|s{stride}|{precision}"
 
     if regime == "compound":
         n_chunks = _cdiv(K, TAP_CHUNK)
         kp = n_chunks * TAP_CHUNK
         n_red = n_ci * n_chunks
-        chunk_halo = (tile_l - 1) * stride + TAP_CHUNK
+        chunk_halo = halo_rows(tile_l - 1 + TAP_CHUNK)
+        last = (n_tiles - 1) * tile_l + (n_chunks - 1) * TAP_CHUNK
         x_blk = Block(
             "x", (1, chunk_halo, cb), xdt,
             lambda b, i, co, r: (
                 b,
-                i * tile_l * stride + (r % n_chunks) * TAP_CHUNK,
+                i * tile_l + (r % n_chunks) * TAP_CHUNK,
                 (r // n_chunks) * cb,
             ),
-            (B, xlen + (kp - K), cin_p), unblocked=True,
+            (B, max(L, last + chunk_halo), cin_p), element=True,
         )
         w_blk = Block(
             "w", (TAP_CHUNK, cb, ob), wdt,
@@ -325,8 +344,8 @@ def build_conv1d(
         n_red = n_ci
         x_blk = Block(
             "x", (1, halo, cb), xdt,
-            lambda b, i, co, r: (b, i * tile_l * stride, r * cb),
-            (B, xlen, cin_p), unblocked=True,
+            lambda b, i, co, r: (b, i * tile_l, r * cb),
+            (B, xlen, cin_p), element=True,
         )
         w_blk = Block(
             "w", (K, cb, ob), wdt,
@@ -363,7 +382,7 @@ def build_conv2d(
     """Contract for ``sliding_conv2d.conv2d_sliding_pallas`` (fp) and
     ``sliding_conv_quant.conv2d_quant_pallas``."""
     from repro.core.conv import regime_for
-    from repro.kernels.sliding_conv1d import _resolve_block
+    from repro.kernels.sliding_conv1d import _resolve_block, halo_rows
     from repro.kernels.sliding_conv2d import (
         DEFAULT_TILE_H, DEFAULT_TILE_W, ROW_CHUNK,
     )
@@ -379,11 +398,10 @@ def build_conv2d(
     th = min(tile_h or DEFAULT_TILE_H, oh)
     tw = min(tile_w or DEFAULT_TILE_W, ow)
     nh, nw = _cdiv(oh, th), _cdiv(ow, tw)
-    need_h = (nh * th - 1) * sh + kh
-    need_w = (nw * tw - 1) * sw + kw
-    hp, wp = max(H, need_h), max(W, need_w)
     halo_h = (th - 1) * sh + kh
-    halo_w = (tw - 1) * sw + kw
+    halo_w = halo_rows((tw - 1) * sw + kw)
+    hp = max(H, (nh - 1) * th * sh + halo_h)
+    wp = max(W, (nw - 1) * tw * sw + halo_w)
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
     n_ci, n_co = _cdiv(Cin, cb), _cdiv(Cout, ob)
@@ -401,6 +419,7 @@ def build_conv2d(
         khp = n_chunks * ROW_CHUNK
         n_red = n_ci * n_chunks
         chunk_halo_h = (th - 1) * sh + ROW_CHUNK
+        last_h = (nh - 1) * th * sh + (n_chunks - 1) * ROW_CHUNK
         x_blk = Block(
             "x", (1, chunk_halo_h, halo_w, cb), xdt,
             lambda b, i, j, co, r: (
@@ -409,7 +428,7 @@ def build_conv2d(
                 j * tw * sw,
                 (r // n_chunks) * cb,
             ),
-            (B, hp + (khp - kh), wp, cin_p), unblocked=True,
+            (B, max(H, last_h + chunk_halo_h), wp, cin_p), element=True,
         )
         w_blk = Block(
             "w", (ROW_CHUNK, kw, cb, ob), wdt,
@@ -421,7 +440,7 @@ def build_conv2d(
         x_blk = Block(
             "x", (1, halo_h, halo_w, cb), xdt,
             lambda b, i, j, co, r: (b, i * th * sh, j * tw * sw, r * cb),
-            (B, hp, wp, cin_p), unblocked=True,
+            (B, hp, wp, cin_p), element=True,
         )
         w_blk = Block(
             "w", (kh, kw, cb, ob), wdt,
@@ -474,7 +493,7 @@ def build_conv1d_depthwise(
         Block(
             "x", (1, halo, cb), xdt,
             lambda b, i, c: (b, i * tile_l * stride, c * cb),
-            (B, xlen, cp), unblocked=True,
+            (B, xlen, cp), element=True,
         ),
         Block("w", (K, cb), wdt, lambda b, i, c: (0, c), (K, cp)),
         Block(
@@ -502,6 +521,7 @@ def build_pool1d(
 ) -> KernelInstance:
     """Contract for ``sliding_pool.sliding_pool_pallas`` — halo indexing
     with no reduction dim and no scratch."""
+    from repro.kernels.sliding_conv1d import halo_rows
     from repro.kernels.sliding_pool import DEFAULT_TILE
 
     out_len = L - window + 1
@@ -510,13 +530,12 @@ def build_pool1d(
     tile_l = min(tile_l or DEFAULT_TILE, out_len)
     n_tiles = _cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = tile_l + window - 1
-    need = padded_out + window - 1
+    halo = halo_rows(tile_l + window - 1)
     key = f"pool1d|B{B}|L{L}|C{C}|w{window}|{dtype}"
     inputs = [Block(
         "x", (1, halo, C), dtype,
-        lambda b, i: (b, i * tile_l, 0), (B, max(L, need), C),
-        unblocked=True,
+        lambda b, i: (b, i * tile_l, 0),
+        (B, max(L, (n_tiles - 1) * tile_l + halo), C), element=True,
     )]
     out = Block(
         "out", (1, tile_l, C), dtype,
@@ -535,25 +554,28 @@ def build_conv1d_bwd_dw(
 ) -> KernelInstance:
     """Contract for ``sliding_conv_bwd.conv1d_bwd_dw_pallas`` — the dw
     reduction: output (the weight gradient) indexed by the LEADING channel
-    dims, reduction over trailing (batch, tile) dims into f32 scratch."""
+    dims, reduction over trailing (batch, tile) dims into f32 scratch. A
+    stride above 1 is checked as its stride-1 launch over the phase-split
+    input."""
     from repro.kernels.sliding_conv1d import DEFAULT_TILE_L, _resolve_block
 
     out_len = (L - K) // stride + 1
     if out_len < 1:
         raise ValueError(f"K={K} stride={stride} exceeds L={L}")
+    key = f"conv1d|B{B}|L{L}|Cin{Cin}|Cout{Cout}|K{K}|s{stride}|{dtype}|grad"
+    L, Cin, K = _phase_split(L, Cin, K, stride)
     tile_l, n_tiles, padded_out, halo, xlen = _conv1d_geom(
-        L, K, stride, tile_l or DEFAULT_TILE_L, out_len
+        L, K, 1, tile_l or DEFAULT_TILE_L, out_len
     )
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
     n_ci, n_co = _cdiv(Cin, cb), _cdiv(Cout, ob)
     cin_p, cout_p = n_ci * cb, n_co * ob
-    key = f"conv1d|B{B}|L{L}|Cin{Cin}|Cout{Cout}|K{K}|s{stride}|{dtype}|grad"
     inputs = [
         Block(
             "x", (1, halo, cb), dtype,
-            lambda co, ci, b, i: (b, i * tile_l * stride, ci * cb),
-            (B, xlen, cin_p), unblocked=True,
+            lambda co, ci, b, i: (b, i * tile_l, ci * cb),
+            (B, xlen, cin_p), element=True,
         ),
         Block(
             "dz", (1, tile_l, ob), dtype,
@@ -585,7 +607,7 @@ def build_conv2d_bwd_dw(
     tile_h=None, tile_w=None, cin_block=0, cout_block=0,
 ) -> KernelInstance:
     """Contract for ``sliding_conv_bwd.conv2d_bwd_dw_pallas``."""
-    from repro.kernels.sliding_conv1d import _resolve_block
+    from repro.kernels.sliding_conv1d import _resolve_block, halo_rows
     from repro.kernels.sliding_conv2d import DEFAULT_TILE_H, DEFAULT_TILE_W
 
     sh, sw = stride
@@ -595,9 +617,9 @@ def build_conv2d_bwd_dw(
     th = min(tile_h or DEFAULT_TILE_H, oh)
     tw = min(tile_w or DEFAULT_TILE_W, ow)
     nh, nw = _cdiv(oh, th), _cdiv(ow, tw)
-    hp = max(H, (nh * th - 1) * sh + kh)
-    wp = max(W, (nw * tw - 1) * sw + kw)
-    halo_h, halo_w = (th - 1) * sh + kh, (tw - 1) * sw + kw
+    halo_h, halo_w = (th - 1) * sh + kh, halo_rows((tw - 1) * sw + kw)
+    hp = max(H, (nh - 1) * th * sh + halo_h)
+    wp = max(W, (nw - 1) * tw * sw + halo_w)
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
     n_ci, n_co = _cdiv(Cin, cb), _cdiv(Cout, ob)
@@ -610,7 +632,7 @@ def build_conv2d_bwd_dw(
         Block(
             "x", (1, halo_h, halo_w, cb), dtype,
             lambda co, ci, b, i, j: (b, i * th * sh, j * tw * sw, ci * cb),
-            (B, hp, wp, cin_p), unblocked=True,
+            (B, hp, wp, cin_p), element=True,
         ),
         Block(
             "dz", (1, th, tw, ob), dtype,
@@ -658,7 +680,7 @@ def build_conv1d_depthwise_bwd_dw(
         Block(
             "x", (1, halo, cb), dtype,
             lambda c, b, i: (b, i * tile_l * stride, c * cb),
-            (B, xlen, cp), unblocked=True,
+            (B, xlen, cp), element=True,
         ),
         Block(
             "dz", (1, tile_l, cb), dtype,
@@ -680,13 +702,14 @@ def build_attention_decode(
 ) -> KernelInstance:
     """Contract for ``attention_decode.decode_attention_pallas`` — the
     flash-style single-query read: kv_seq is the trailing sequential
-    revisit dim over (m, l, o) f32 online-softmax scratches."""
-    from repro.kernels.attention_decode import DEFAULT_BLOCK_S
+    revisit dim over (m, l, o) f32 online-softmax scratches. ``lengths``
+    is scalar-prefetched to SMEM, so it has no VMEM block."""
+    from repro.kernels.attention_decode import DEFAULT_BLOCK_S, head_block
 
     bs = min(block_s or DEFAULT_BLOCK_S, S)
     n_s = _cdiv(S, bs)
     sp = n_s * bs
-    hb = h_block if h_block and KV % h_block == 0 else 1
+    hb = head_block(h_block, KV, D)
     n_h = KV // hb
     quantized = kind == "int8"
     kvdt = "int8" if quantized else kind
@@ -697,22 +720,19 @@ def build_attention_decode(
             lambda b, h, s: (b, h, 0, 0), (B, KV, G, D),
         ),
         Block(
-            "k", (1, bs, hb, D), kvdt,
-            lambda b, h, s: (b, s, h, 0), (B, sp, KV, D),
+            "k", (1, bs, hb * D), kvdt,
+            lambda b, h, s: (b, s, h), (B, sp, KV * D),
         ),
         Block(
-            "v", (1, bs, hb, D), kvdt,
-            lambda b, h, s: (b, s, h, 0), (B, sp, KV, D),
-        ),
-        Block(
-            "len", (1, 1), "int32", lambda b, h, s: (b, 0), (B, 1)
+            "v", (1, bs, hb * D), kvdt,
+            lambda b, h, s: (b, s, h), (B, sp, KV * D),
         ),
     ]
     if quantized:
         for nm in ("k_scale", "v_scale"):
             inputs.append(Block(
-                nm, (1, bs, hb), "float32",
-                lambda b, h, s: (b, s, h), (B, sp, KV),
+                nm, (1, KV, bs), "float32",
+                lambda b, h, s: (b, 0, s), (B, KV, sp),
             ))
     out = Block(
         "out", (1, hb, G, D), "float32",

@@ -18,7 +18,7 @@ resident (Dettmers et al., 2022):
     lengths), applied blockwise inside the online softmax.
 
 No float K/V view is ever materialized: per grid step one ``(block_s,
-h_block, D)`` cache block lives in VMEM, the f32 running state is
+h_block·D)`` cache block lives in VMEM, the f32 running state is
 ``(h_block, G)`` + a ``(h_block, G, D)`` accumulator in scratch.
 
 The **fp-cache variant is the same kernel** with the scale operands absent
@@ -95,19 +95,21 @@ def _finish(l, acc):
 
 
 def _decode_kernel(
-    q_ref, k_ref, v_ref, len_ref, *rest, bs, hb, n_s, quantized, sm_scale
+    len_ref, q_ref, k_ref, v_ref, *rest, bs, hb, d, n_s, quantized, sm_scale
 ):
     """Grid (B, KV/hb, n_s); the kv_seq dim (last, sequential) revisits one
-    (batch, head-block) output with the online-softmax state in scratch."""
+    (batch, head-block) output with the online-softmax state in scratch.
+    ``len_ref`` is the scalar-prefetched (B,) lengths vector in SMEM."""
     if quantized:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
     o_ref, m_ref, l_ref, acc_ref = rest
+    h_idx = pl.program_id(1)
     s_idx = pl.program_id(2)
     q = q_ref[0].astype(jnp.float32)  # (hb, G, D)
-    kblk = k_ref[0]  # (bs, hb, D) — int8 codes or float rows
+    kblk = k_ref[0]  # (bs, hb·D) — int8 codes or float rows
     vblk = v_ref[0]
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     pos = s_idx * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     valid = pos < length  # (1, bs)
 
@@ -118,17 +120,21 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     for i in range(hb):  # static head-block loop: one 2-D dot per head
-        ki = kblk[:, i, :].astype(jnp.float32)
-        s = jnp.dot(q[i], ki.T, preferred_element_type=jnp.float32)
-        s = s * sm_scale  # (G, bs)
+        ki = kblk[:, i * d : (i + 1) * d].astype(jnp.float32)
+        s = jax.lax.dot_general(  # q·kᵀ without a transpose: (G, bs)
+            q[i], ki, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        s = s * sm_scale
+        head = h_idx * hb + i
         if quantized:
             # scale-fold algebra: s_k is constant along head_dim, so it
             # commutes out of the q·k reduction — fold it AFTER the dot
-            s = s * ks_ref[0][:, i][None, :]
+            s = s * ks_ref[0, pl.ds(head, 1), :]
         s = jnp.where(valid, s, -jnp.inf)
-        vs_row = vs_ref[0][:, i][None, :] if quantized else None
+        vs_row = vs_ref[0, pl.ds(head, 1), :] if quantized else None
         m_new, l_new, acc_new = _online_update(
-            s, vs_row, vblk[:, i, :].astype(jnp.float32),
+            s, vs_row, vblk[:, i * d : (i + 1) * d].astype(jnp.float32),
             m_ref[i], l_ref[i], acc_ref[i],
         )
         m_ref[i], l_ref[i], acc_ref[i] = m_new, l_new, acc_new
@@ -136,6 +142,16 @@ def _decode_kernel(
     @pl.when(s_idx == n_s - 1)
     def _done():
         o_ref[0] = _finish(l_ref[...], acc_ref[...]).astype(o_ref.dtype)
+
+
+def head_block(h_block: int | None, KV: int, D: int) -> int:
+    """KV heads per grid step. A K/V block is ``hb·D`` lanes wide, which
+    the TPU tiles in 128s unless it spans all ``KV·D``: an ``h_block``
+    that does not divide KV or breaks that tiling (and None) means all KV
+    heads."""
+    if h_block and KV % h_block == 0 and (h_block * D) % 128 == 0:
+        return h_block
+    return KV
 
 
 @functools.partial(
@@ -150,7 +166,7 @@ def decode_attention_pallas(
     lengths: jax.Array | None = None,
     *,
     block_s: int = DEFAULT_BLOCK_S,
-    h_block: int = 1,
+    h_block: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused decode attention. q: (B, KV, G, D) grouped queries (any float
@@ -160,8 +176,13 @@ def decode_attention_pallas(
     all S rows valid). Returns (B, KV, G, D) f32.
 
     ``block_s`` tiles kv_seq (the reduction grid dim); ``h_block`` groups
-    KV heads per grid step (must divide KV; falls back to 1). Both are
-    tuned under the ``attn_dec|…`` autotune key.
+    KV heads per grid step (``head_block``). Both are tuned under the
+    ``attn_dec|…`` autotune key.
+
+    Layout: k/v travel as (B, S, KV·D) — a free reshape — so each grid
+    step reads one (block_s, hb·D) tile whose last two dims meet the
+    TPU's (8, 128) tiling; the scale rows travel as (B, KV, S) so a head's
+    row is a (1, block_s) slice; ``lengths`` is scalar-prefetched to SMEM.
     """
     B, KV, G, D = q.shape
     S = k.shape[1]
@@ -173,44 +194,48 @@ def decode_attention_pallas(
     bs = min(block_s, S)
     n_s = pl.cdiv(S, bs)
     Sp = n_s * bs
-    k = _pad_seq(k, Sp)
-    v = _pad_seq(v, Sp)
-    hb = h_block if (h_block and KV % h_block == 0) else 1
+    k = _pad_seq(k, Sp).reshape(B, Sp, KV * D)
+    v = _pad_seq(v, Sp).reshape(B, Sp, KV * D)
+    hb = head_block(h_block, KV, D)
     n_h = KV // hb
-    len2 = lengths.reshape(B, 1).astype(jnp.int32)
     kernel = functools.partial(
-        _decode_kernel, bs=bs, hb=hb, n_s=n_s, quantized=quantized,
+        _decode_kernel, bs=bs, hb=hb, d=D, n_s=n_s, quantized=quantized,
         sm_scale=D ** -0.5,
     )
     in_specs = [
-        pl.BlockSpec((1, hb, G, D), lambda b, h, s: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, hb, D), lambda b, h, s: (b, s, h, 0)),
-        pl.BlockSpec((1, bs, hb, D), lambda b, h, s: (b, s, h, 0)),
-        pl.BlockSpec((1, 1), lambda b, h, s: (b, 0)),
+        pl.BlockSpec((1, hb, G, D), lambda b, h, s, lens: (b, h, 0, 0)),
+        pl.BlockSpec((1, bs, hb * D), lambda b, h, s, lens: (b, s, h)),
+        pl.BlockSpec((1, bs, hb * D), lambda b, h, s, lens: (b, s, h)),
     ]
-    args = [q, k, v, len2]
+    args = [q, k, v]
     if quantized:
-        # scale rows travel as (B, Sp, KV) — the head_dim axis is collapsed
+        # scale rows travel as (B, KV, Sp) — head_dim collapsed, kv_seq on
+        # the lanes — and every step sees all KV heads' rows of its block
         ks3 = _pad_seq(k_scale, Sp)[..., 0].astype(jnp.float32)
         vs3 = _pad_seq(v_scale, Sp)[..., 0].astype(jnp.float32)
         in_specs += [
-            pl.BlockSpec((1, bs, hb), lambda b, h, s: (b, s, h)),
-            pl.BlockSpec((1, bs, hb), lambda b, h, s: (b, s, h)),
+            pl.BlockSpec((1, KV, bs), lambda b, h, s, lens: (b, 0, s)),
+            pl.BlockSpec((1, KV, bs), lambda b, h, s, lens: (b, 0, s)),
         ]
-        args += [ks3, vs3]
+        args += [ks3.transpose(0, 2, 1), vs3.transpose(0, 2, 1)]
     return pl.pallas_call(
         kernel,
-        grid=(B, n_h, n_s),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hb, G, D), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, n_h, n_s),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, hb, G, D), lambda b, h, s, lens: (b, h, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((hb, G), jnp.float32),  # running max
+                pltpu.VMEM((hb, G), jnp.float32),  # running denominator
+                pltpu.VMEM((hb, G, D), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((hb, G), jnp.float32),  # running max
-            pltpu.VMEM((hb, G), jnp.float32),  # running denominator
-            pltpu.VMEM((hb, G, D), jnp.float32),  # output accumulator
-        ],
         interpret=interpret,
-    )(*args)
+    )(lengths.astype(jnp.int32), *args)
 
 
 # ---------------------------------------------------------------------------

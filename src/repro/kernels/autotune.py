@@ -24,8 +24,10 @@ dicts, e.g. ::
 ``cin_block``/``cout_block`` of 0 mean "unblocked" (full channel axis).
 ``us``/``default_us`` record the measured winner vs the default config so
 speedup trajectories survive across PRs. The cache path is
-``$REPRO_AUTOTUNE_CACHE`` (default ``.cache/autotune.json`` under the
-current working directory); writes go through a temp file + rename.
+``$REPRO_AUTOTUNE_CACHE``, by default ``.cache/autotune-<device kind>.json``
+under the current working directory (``default_cache_path``): timings are
+only meaningful on the device that took them, so a chip run never reads
+what a CPU run tuned. Writes go through a temp file + rename.
 
 The file additionally carries a reserved ``"__schema__"`` version entry
 (never returned by ``lookup``). A cache that fails to parse or was written
@@ -40,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import re
 import sys
 import time
 from pathlib import Path
@@ -52,7 +55,6 @@ from repro.health import HEALTH
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
-DEFAULT_CACHE = ".cache/autotune.json"
 
 # bump when the cache entry layout changes incompatibly; readers quarantine
 # files stamped with a DIFFERENT version (missing field = legacy schema 1)
@@ -70,8 +72,16 @@ AUTO_BLOCK_THRESHOLD = 256
 AUTO_BLOCK = 128
 
 
+def default_cache_path() -> Path:
+    """``.cache/autotune-<device kind>.json``, e.g. ``autotune-cpu.json``
+    or ``autotune-TPU_v5_lite.json``."""
+    kind = re.sub(r"\W+", "_", jax.devices()[0].device_kind).strip("_")
+    return Path(".cache") / f"autotune-{kind}.json"
+
+
 def cache_path() -> Path:
-    return Path(os.environ.get("REPRO_AUTOTUNE_CACHE", DEFAULT_CACHE))
+    env = os.environ.get("REPRO_AUTOTUNE_CACHE")
+    return Path(env) if env else default_cache_path()
 
 
 _cache: dict[str, dict[str, Any]] | None = None
@@ -329,6 +339,20 @@ def _search(
     reg = obs_metrics.REGISTRY
     reg.counter("autotune.searches").inc(1.0, key=key)
     cands = [c for c in candidates if c != default]
+    # prune first: the cost-ranked early exit below must not decide
+    # whether a provably-bad candidate is ever looked at
+    pruned = 0
+    for cand in list(cands):
+        verdict = contract(cand) if contract is not None else None
+        if verdict is not None:
+            cands.remove(cand)
+            pruned += 1
+            reg.counter("autotune.pruned").inc(1.0, key=key)
+            print(
+                f"[autotune] pruned {key} cand={cand}: "
+                f"{verdict.kind} ({verdict.detail})",
+                file=sys.stderr,
+            )
     cands, ranked = _ranked(cands, cost)
     patience = _cost_patience() if ranked else 0
     with obs_trace.span("autotune.search", key=key):
@@ -336,19 +360,8 @@ def _search(
             default_t = _time_fn(lambda: run(default))
         reg.counter("autotune.candidates").inc(1.0, key=key)
         best_cfg, best_t = dict(default), default_t
-        pruned = timed = cost_skipped = since_improve = 0
+        timed = cost_skipped = since_improve = 0
         for i, cand in enumerate(cands):
-            if contract is not None:
-                verdict = contract(cand)
-                if verdict is not None:
-                    pruned += 1
-                    reg.counter("autotune.pruned").inc(1.0, key=key)
-                    print(
-                        f"[autotune] pruned {key} cand={cand}: "
-                        f"{verdict.kind} ({verdict.detail})",
-                        file=sys.stderr,
-                    )
-                    continue
             try:
                 with obs_trace.span(
                     "autotune.candidate", key=key, cand=str(cand)
@@ -622,7 +635,7 @@ def autotune_attention_decode(
     default_bs = (
         S if resolved_impl != "pallas" else min(attn_dec.DEFAULT_BLOCK_S, S)
     )
-    default = {"block_s": default_bs, "h_block": 1}
+    default = {"block_s": default_bs, "h_block": KV}
     cshape = dict(B=B, S=S, KV=KV, G=H // KV, D=D, kind=kind)
     return _search(key, run, cands, default,
                    contract=_contract_checker("attention_decode", cshape),

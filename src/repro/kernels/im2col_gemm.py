@@ -22,6 +22,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sliding_conv1d import halo_input, halo_spec
+from repro.kernels.sliding_conv2d import halo_input_2d
+
 DEFAULT_TM = 128
 DEFAULT_TN = 128
 DEFAULT_TK = 128
@@ -116,10 +119,8 @@ def conv1d_im2col_fused_pallas(
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
+    step = tile_l * stride
+    x, halo = halo_input(x, 1, (n_tiles - 1) * step, (tile_l - 1) * stride + K)
     kernel = functools.partial(
         _im2col_fused_kernel, taps=K, tile_l=tile_l, stride=stride
     )
@@ -127,11 +128,7 @@ def conv1d_im2col_fused_pallas(
         kernel,
         grid=(B, n_tiles),
         in_specs=[
-            pl.BlockSpec(
-                (1, halo, Cin),
-                lambda b, i: (b, i * tile_l * stride, 0),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, Cin), 1, lambda b, i: (b, i * step, 0)),
             pl.BlockSpec((K, Cin, Cout), lambda b, i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, tile_l, Cout), lambda b, i: (b, i, 0)),
@@ -205,15 +202,10 @@ def conv2d_im2col_fused_pallas(
     tw = min(tile_w, ow)
     nh = pl.cdiv(oh, th)
     nw = pl.cdiv(ow, tw)
-    need_h = (nh * th - 1) * sh + kh
-    need_w = (nw * tw - 1) * sw + kw
-    if need_h > H or need_w > W:
-        x = jnp.pad(
-            x,
-            ((0, 0), (0, max(0, need_h - H)), (0, max(0, need_w - W)), (0, 0)),
-        )
-    halo_h = (th - 1) * sh + kh
-    halo_w = (tw - 1) * sw + kw
+    x, halo_h, halo_w = halo_input_2d(
+        x, (nh - 1) * th * sh, (th - 1) * sh + kh,
+        (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+    )
     kernel = functools.partial(
         _im2col2d_fused_kernel, kh=kh, kw=kw, th=th, tw=tw, sh=sh, sw=sw
     )
@@ -221,11 +213,9 @@ def conv2d_im2col_fused_pallas(
         kernel,
         grid=(B, nh, nw),
         in_specs=[
-            pl.BlockSpec(
-                (1, halo_h, halo_w, Cin),
-                lambda b, i, j: (b, i * th * sh, j * tw * sw, 0),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_h, halo_w, Cin), 1, lambda b, i, j: (
+                b, i * th * sh, j * tw * sw, 0,
+            )),
             pl.BlockSpec((kh, kw, Cin, Cout), lambda b, i, j: (0, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, th, tw, Cout), lambda b, i, j: (b, i, j, 0)),

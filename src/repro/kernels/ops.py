@@ -1041,12 +1041,13 @@ def attention_decode(
     cfg = _tuned_fill(key, block_s=block_s, h_block=h_block)
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "jax"
-    # untuned defaults: the Pallas kernel tiles kv_seq to bound VMEM; the
-    # compiled CPU path defaults to ONE block (the whole cache) — decode
-    # caches are cache-hierarchy-resident there and the blocked scan only
-    # adds carry overhead (measured: single-block 1.3× over block_s=128 at
-    # S=512). The ``attn_dec|…`` tuned entry overrides either way.
-    h_block = cfg["h_block"] or 1
+    # untuned defaults: the Pallas kernel tiles kv_seq to bound VMEM and
+    # takes all KV heads per step; the compiled CPU path defaults to ONE
+    # block (the whole cache) — decode caches are cache-hierarchy-resident
+    # there and the blocked scan only adds carry overhead (measured:
+    # single-block 1.3× over block_s=128 at S=512). The ``attn_dec|…``
+    # tuned entry overrides either way.
+    h_block = cfg["h_block"]
     interpret = use_interpret() if interpret is None else interpret
     q4 = q.reshape(B, KV, G, D)
 

@@ -41,8 +41,12 @@ output dtype) on the same final reduction visit. The custom-VJP layer in
 All kernels: NLC layout, stride ≥ 1 (loaded-tile register slicing), f32
 accumulation, bf16/f32 in/out. HBM traffic is O(input + output) — the im2col
 column matrix is never materialized (compare ``repro.kernels.im2col_gemm``).
-Halo (overlapping) input windows use ``pl.unblocked`` index maps: offsets
-are element-granular, so consecutive tiles may share (K-1)·stride rows.
+Halo (overlapping) input windows are ``pl.Element`` blocks (``halo_spec``):
+their index maps return element offsets, so consecutive tiles may share
+K-1 rows. The halo is rounded up to whole sublanes (``halo_rows``) so the
+block satisfies the TPU's (8, 128) tiling; the extra rows are zero padding
+the taps never read. A stride above 1 becomes channels (``phase_split``):
+the multi-channel kernels always slide by one row.
 """
 from __future__ import annotations
 
@@ -82,12 +86,32 @@ def _epilogue(acc, bias_ref, o_ref, z_ref=None, *, activation: str):
     o_ref[0] = apply_activation(acc, activation).astype(o_ref.dtype)
 
 
-def _slide(x, k: int, tile: int, stride: int):
-    """Tap-k shifted view of the halo tile (the paper's vector slide)."""
-    xs = x[k : k + (tile - 1) * stride + 1]
-    if stride > 1:
-        xs = xs[::stride]
-    return xs
+def _slide(x_ref, k: int, tile: int, stride: int = 1):
+    """Tap-k shifted rows of the halo tile (the paper's vector slide), read
+    straight from the VMEM ref. A stride above 1 is a strided load, which
+    the TPU supports for 32-bit data only: the multi-channel kernels never
+    need one (``phase_split`` turns their stride into channels)."""
+    return x_ref[0, pl.ds(k, tile, stride=stride), :]
+
+
+def phase_split(x: jax.Array, stride: int) -> jax.Array:
+    """(B, L, C) → (B, ⌈L/s⌉, s·C): row ``j·s + p`` moves to row ``j``,
+    channels ``[p·C, (p+1)·C)``. A free reshape once L is padded to a
+    multiple of ``s``. A stride-s conv over ``x`` is the stride-1 conv over
+    ``phase_split(x, s)`` with the taps of ``phase_taps(w, s)``, so the
+    kernels only ever slide by one row."""
+    B, L, C = x.shape
+    x = _pad_axis(x, 1, pl.cdiv(L, stride) * stride)
+    return x.reshape(B, -1, stride * C)
+
+
+def phase_taps(w: jax.Array, stride: int) -> jax.Array:
+    """(K, Cin, Cout) → (⌈K/s⌉, s·Cin, Cout): tap ``t·s + p`` moves to tap
+    ``t``, input channels ``[p·Cin, (p+1)·Cin)``; the padded taps are
+    zero. The inverse of ``reshape(-1, Cin, Cout)[:K]``."""
+    K, Cin, Cout = w.shape
+    w = _pad_axis(w, 0, pl.cdiv(K, stride) * stride)
+    return w.reshape(-1, stride * Cin, Cout)
 
 
 # ---------------------------------------------------------------------------
@@ -136,16 +160,15 @@ def _reduce_store(acc, rest, *, has_bias, n_red, red_axis, finish, n_out=1):
 
 
 def _kernel_generic(
-    x_ref, w_ref, *rest, taps, tile_l, stride, n_red, activation, has_bias,
-    n_out,
+    x_ref, w_ref, *rest, taps, tile_l, n_red, activation, has_bias, n_out,
 ):
-    """Unrolled shift-and-MXU-matmul over taps (generic / vector-slide)."""
-    x = x_ref[0]  # ((TL-1)*s + K, cin_block) halo tile, VMEM-resident
+    """Unrolled shift-and-MXU-matmul over taps (generic / vector-slide).
+    x_ref holds the (TL + K - 1, cin_block) halo tile, VMEM-resident."""
     cout = w_ref.shape[2]
     acc = jnp.zeros((tile_l, cout), jnp.float32)
     for k in range(taps):
         acc += jnp.dot(
-            _slide(x, k, tile_l, stride), w_ref[k],
+            _slide(x_ref, k, tile_l), w_ref[k],
             preferred_element_type=jnp.float32,
         )
     _reduce_store(
@@ -155,12 +178,10 @@ def _kernel_generic(
 
 
 def _kernel_custom(
-    x_ref, w_ref, *rest, taps, tile_l, stride, n_red, activation, has_bias,
-    n_out,
+    x_ref, w_ref, *rest, taps, tile_l, n_red, activation, has_bias, n_out,
 ):
     """Tap-stacked single-matmul kernel for K in {3, 5} (custom regime)."""
-    x = x_ref[0]
-    cols = [_slide(x, k, tile_l, stride) for k in range(taps)]
+    cols = [_slide(x_ref, k, tile_l) for k in range(taps)]
     stacked = jnp.concatenate(cols, axis=-1)  # (TL, K*cin_block) — VMEM only
     wf = w_ref[...].reshape(taps * w_ref.shape[1], w_ref.shape[2])
     acc = jnp.dot(stacked, wf, preferred_element_type=jnp.float32)
@@ -171,18 +192,16 @@ def _kernel_custom(
 
 
 def _kernel_compound(
-    x_ref, w_ref, *rest, chunk, tile_l, stride, n_red, activation, has_bias,
-    n_out,
+    x_ref, w_ref, *rest, chunk, tile_l, n_red, activation, has_bias, n_out,
 ):
     """Tap-chunked accumulation (compound regime): the reduction dimension
     sweeps Cin blocks × tap chunks; chunk c covers taps [c·chunk, (c+1)·chunk).
     """
-    x = x_ref[0]
     cout = w_ref.shape[2]
     acc = jnp.zeros((tile_l, cout), jnp.float32)
     for k in range(chunk):  # taps within the chunk: unrolled slides
         acc += jnp.dot(
-            _slide(x, k, tile_l, stride), w_ref[k],
+            _slide(x_ref, k, tile_l), w_ref[k],
             preferred_element_type=jnp.float32,
         )
     _reduce_store(
@@ -198,10 +217,9 @@ def _kernel_depthwise(
     literal TPU transcription of the paper's vector-slide inner loop."""
     bias_ref, outs, _ = _unpack(rest, has_bias, n_out, False)
     o_ref = outs[0]
-    x = x_ref[0]
     acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
     for k in range(taps):
-        acc += _slide(x, k, tile_l, stride).astype(jnp.float32) * w_ref[
+        acc += _slide(x_ref, k, tile_l, stride).astype(jnp.float32) * w_ref[
             k
         ].astype(jnp.float32)
     _epilogue(acc, bias_ref, *outs, activation=activation)
@@ -210,6 +228,42 @@ def _kernel_depthwise(
 # ---------------------------------------------------------------------------
 # pallas_call wrappers
 # ---------------------------------------------------------------------------
+
+SUBLANES = 8  # the TPU's second-to-last tiling: block rows come in eights
+
+
+def halo_rows(n: int) -> int:
+    """A halo of ``n`` rows, rounded up to whole sublanes."""
+    return -(-n // SUBLANES) * SUBLANES
+
+
+def halo_input(x: jax.Array, axis: int, last: int, span: int):
+    """Round a halo of ``span`` rows up to whole sublanes and zero-pad
+    ``x`` along ``axis`` so the read at element offset ``last`` (the last
+    tile's) stays in bounds. Returns ``(x, halo)``."""
+    halo = halo_rows(span)
+    return _pad_axis(x, axis, last + halo), halo
+
+
+def halo_spec(block: tuple[int, ...], n_c: int, index_map) -> pl.BlockSpec:
+    """BlockSpec of a ``(1, *block)`` halo tile of a channels-last array
+    whose last axis holds ``n_c`` blocks of ``block[-1]`` channels.
+    ``index_map`` returns (batch, element offset per spatial axis…,
+    channel block).
+
+    The TPU takes element windows on every axis or on none, so the channel
+    block becomes an element offset too. With one channel block it is the
+    constant 0: the compiler must prove a lane offset a multiple of 128,
+    and ``c · cb`` over a grid index ``c`` is not provably one when cb is
+    not."""
+    cb = block[-1]
+
+    def element_map(*ids):
+        *lead, c = index_map(*ids)
+        return (*lead, c * cb if n_c > 1 else 0)
+
+    return pl.BlockSpec(tuple(pl.Element(n) for n in (1, *block)), element_map)
+
 
 def _resolve_block(total: int, block: int | None) -> int:
     if block is None or block <= 0:
@@ -267,14 +321,19 @@ def conv1d_sliding_pallas(
         from repro.core.conv import regime_for
 
         regime = regime_for(K)
+    if stride > 1:
+        out = conv1d_sliding_pallas(
+            phase_split(x, stride), phase_taps(w, stride), bias,
+            tile_l=tile_l, cin_block=cin_block, cout_block=cout_block,
+            regime=regime, activation=activation, interpret=interpret,
+            save_preact=save_preact,
+        )
+        if save_preact:
+            return tuple(o[:, :out_len] for o in out)
+        return out[:, :out_len]
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K  # input rows a tile touches
-    # pad input so every tile's halo read is in-bounds
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
 
     # -- channel blocking: pad Cin/Cout to block multiples (zero taps/outputs
     #    contribute nothing / are trimmed), one grid dim per blocked axis.
@@ -296,29 +355,23 @@ def conv1d_sliding_pallas(
 
     if regime == "compound":
         n_chunks = pl.cdiv(K, TAP_CHUNK)
-        Kp = n_chunks * TAP_CHUNK
-        if Kp > K:
-            w = jnp.pad(w, ((0, Kp - K), (0, 0), (0, 0)))
-            x = jnp.pad(x, ((0, 0), (0, Kp - K), (0, 0)))
+        w = _pad_axis(w, 0, n_chunks * TAP_CHUNK)
         n_red = n_ci * n_chunks
-        chunk_halo = (tile_l - 1) * stride + TAP_CHUNK
+        x, halo = halo_input(
+            x, 1, (n_tiles - 1) * tile_l + (n_chunks - 1) * TAP_CHUNK,
+            tile_l - 1 + TAP_CHUNK,
+        )
         kernel = functools.partial(
-            _kernel_compound, chunk=TAP_CHUNK, tile_l=tile_l, stride=stride,
+            _kernel_compound, chunk=TAP_CHUNK, tile_l=tile_l,
             n_red=n_red, activation=activation, has_bias=has_bias,
             n_out=n_out,
         )
         # reduction index r decomposes as (cin block, tap chunk): the tap
         # chunk is fastest so a cin block's taps complete consecutively.
         in_specs = [
-            pl.BlockSpec(
-                (1, chunk_halo, cb),
-                lambda b, i, co, r: (
-                    b,
-                    i * tile_l * stride + (r % n_chunks) * TAP_CHUNK,
-                    (r // n_chunks) * cb,
-                ),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, cb), n_ci, lambda b, i, co, r: (
+                b, i * tile_l + (r % n_chunks) * TAP_CHUNK, r // n_chunks,
+            )),
             pl.BlockSpec(
                 (TAP_CHUNK, cb, ob),
                 lambda b, i, co, r: (r % n_chunks, r // n_chunks, co),
@@ -328,16 +381,13 @@ def conv1d_sliding_pallas(
         n_red = n_ci
         body = _kernel_custom if regime == "custom" else _kernel_generic
         kernel = functools.partial(
-            body, taps=K, tile_l=tile_l, stride=stride,
+            body, taps=K, tile_l=tile_l,
             n_red=n_red, activation=activation, has_bias=has_bias,
             n_out=n_out,
         )
+        x, halo = halo_input(x, 1, (n_tiles - 1) * tile_l, tile_l - 1 + K)
         in_specs = [
-            pl.BlockSpec(
-                (1, halo, cb),
-                lambda b, i, co, r: (b, i * tile_l * stride, r * cb),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, cb), n_ci, lambda b, i, co, r: (b, i * tile_l, r)),
             pl.BlockSpec((K, cb, ob), lambda b, i, co, r: (0, r, co)),
         ]
     args = [x, w]
@@ -402,10 +452,8 @@ def conv1d_depthwise_pallas(
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
+    step = tile_l * stride
+    x, halo = halo_input(x, 1, (n_tiles - 1) * step, (tile_l - 1) * stride + K)
     cb = _resolve_block(C, c_block)
     n_c = pl.cdiv(C, cb)
     if n_c * cb > C:
@@ -418,11 +466,7 @@ def conv1d_depthwise_pallas(
         activation=activation, has_bias=has_bias, n_out=n_out,
     )
     in_specs = [
-        pl.BlockSpec(
-            (1, halo, cb),
-            lambda b, i, c: (b, i * tile_l * stride, c * cb),
-            indexing_mode=pl.unblocked,
-        ),
+        halo_spec((halo, cb), n_c, lambda b, i, c: (b, i * step, c)),
         pl.BlockSpec((K, cb), lambda b, i, c: (0, c)),
     ]
     args = [x, w]

@@ -23,8 +23,9 @@ Fused epilogue: ``bias`` (Cout,) + ``activation`` (none/relu/gelu/silu)
 applied on the last reduction visit — conv→bias→act in one launch.
 
 Layout NHWC, weights HWIO, f32 accumulation. Output tiling is (TH, TW);
-input blocks carry a (kh-1, kw-1) halo via ``pl.unblocked`` (element-offset)
-index maps. The im2col column tensor is never materialized — compare
+input blocks carry a (kh-1, kw-1) halo as ``pl.Element`` blocks whose index
+maps return element offsets (``halo_input_2d``, ``sliding_conv1d.halo_spec``).
+The im2col column tensor is never materialized — compare
 ``repro.kernels.im2col_gemm``.
 """
 from __future__ import annotations
@@ -41,11 +42,23 @@ from repro.kernels.sliding_conv1d import (
     _reduce_store,
     _resolve_block,
     apply_activation,
+    halo_rows,
+    halo_spec,
 )
 
 DEFAULT_TILE_H = 16
 DEFAULT_TILE_W = 128
 ROW_CHUNK = 4  # filter rows per compound chunk
+
+
+def halo_input_2d(x, last_h: int, span_h: int, last_w: int, span_w: int):
+    """2-D ``sliding_conv1d.halo_input``: the width halo (the block's
+    second-to-last axis) is rounded up to whole sublanes, and ``x``
+    (B, H, W, C) is zero-padded so the windows read at element offsets
+    ``(last_h, last_w)`` stay in bounds. Returns ``(x, halo_h, halo_w)``."""
+    halo_w = halo_rows(span_w)
+    x = _pad_axis(_pad_axis(x, 1, last_h + span_h), 2, last_w + halo_w)
+    return x, span_h, halo_w
 
 
 def _shifted(x, i, j, th, tw, sh, sw):
@@ -167,13 +180,6 @@ def conv2d_sliding_pallas(
     tw = min(tile_w, ow)
     nh = pl.cdiv(oh, th)
     nw = pl.cdiv(ow, tw)
-    # pad input so every halo read is in-bounds for the padded output grid
-    need_h = (nh * th - 1) * sh + kh
-    need_w = (nw * tw - 1) * sw + kw
-    if need_h > H or need_w > W:
-        x = jnp.pad(x, ((0, 0), (0, max(0, need_h - H)), (0, max(0, need_w - W)), (0, 0)))
-    halo_h = (th - 1) * sh + kh
-    halo_w = (tw - 1) * sw + kw
 
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
@@ -191,12 +197,12 @@ def conv2d_sliding_pallas(
     n_out = 2 if save_preact else 1
     if regime == "compound":
         n_chunks = pl.cdiv(kh, ROW_CHUNK)
-        khp = n_chunks * ROW_CHUNK
-        if khp > kh:
-            w = jnp.pad(w, ((0, khp - kh), (0, 0), (0, 0), (0, 0)))
-            x = jnp.pad(x, ((0, 0), (0, khp - kh), (0, 0), (0, 0)))
+        w = _pad_axis(w, 0, n_chunks * ROW_CHUNK)
         n_red = n_ci * n_chunks
-        chunk_halo_h = (th - 1) * sh + ROW_CHUNK
+        x, halo_h, halo_w = halo_input_2d(
+            x, (nh - 1) * th * sh + (n_chunks - 1) * ROW_CHUNK,
+            (th - 1) * sh + ROW_CHUNK, (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+        )
         kernel = functools.partial(
             _kernel_compound, rows=ROW_CHUNK, kw=kw, th=th, tw=tw, sh=sh,
             sw=sw, n_red=n_red, activation=activation, has_bias=has_bias,
@@ -204,16 +210,10 @@ def conv2d_sliding_pallas(
         )
         # reduction r = (cin block, filter-row chunk), chunk fastest
         in_specs = [
-            pl.BlockSpec(
-                (1, chunk_halo_h, halo_w, cb),
-                lambda b, i, j, co, r: (
-                    b,
-                    i * th * sh + (r % n_chunks) * ROW_CHUNK,
-                    j * tw * sw,
-                    (r // n_chunks) * cb,
-                ),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_h, halo_w, cb), n_ci, lambda b, i, j, co, r: (
+                b, i * th * sh + (r % n_chunks) * ROW_CHUNK, j * tw * sw,
+                r // n_chunks,
+            )),
             pl.BlockSpec(
                 (ROW_CHUNK, kw, cb, ob),
                 lambda b, i, j, co, r: (r % n_chunks, 0, r // n_chunks, co),
@@ -227,12 +227,14 @@ def conv2d_sliding_pallas(
             n_red=n_red, activation=activation, has_bias=has_bias,
             n_out=n_out,
         )
+        x, halo_h, halo_w = halo_input_2d(
+            x, (nh - 1) * th * sh, (th - 1) * sh + kh,
+            (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+        )
         in_specs = [
-            pl.BlockSpec(
-                (1, halo_h, halo_w, cb),
-                lambda b, i, j, co, r: (b, i * th * sh, j * tw * sw, r * cb),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_h, halo_w, cb), n_ci, lambda b, i, j, co, r: (
+                b, i * th * sh, j * tw * sw, r,
+            )),
             pl.BlockSpec(
                 (kh, kw, cb, ob), lambda b, i, j, co, r: (0, 0, r, co)
             ),

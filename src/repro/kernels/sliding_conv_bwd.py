@@ -46,12 +46,16 @@ from repro.kernels.sliding_conv1d import (
     apply_activation,
     conv1d_depthwise_pallas,
     conv1d_sliding_pallas,
+    halo_input,
+    halo_spec,
+    phase_split,
 )
 from repro.kernels.sliding_conv2d import (
     DEFAULT_TILE_H,
     DEFAULT_TILE_W,
     _shifted,
     conv2d_sliding_pallas,
+    halo_input_2d,
 )
 
 
@@ -188,21 +192,18 @@ def _accumulate(acc, scratch, out_ref, first, last, gate=None):
         out_ref[...] = scratch[...].astype(out_ref.dtype)
 
 
-def _dw1d_kernel(
-    x_ref, dz_ref, *rest, taps, tile_l, stride, nb, nt, has_bias
-):
+def _dw1d_kernel(x_ref, dz_ref, *rest, taps, tile_l, nb, nt, has_bias):
     """One visit: per-tap ``x_slideᵀ @ dz`` partial products for this
     (cout block, cin block) weight-gradient tile."""
     if has_bias:
         dw_ref, db_ref, dw_acc, db_acc = rest
     else:
         (dw_ref, dw_acc), db_ref, db_acc = rest, None, None
-    x = x_ref[0]
     dz = dz_ref[0].astype(jnp.float32)
     acc = jnp.stack(
         [
             jnp.dot(
-                _slide(x, k, tile_l, stride).astype(jnp.float32).T, dz,
+                _slide(x_ref, k, tile_l).astype(jnp.float32).T, dz,
                 preferred_element_type=jnp.float32,
             )
             for k in range(taps)
@@ -243,17 +244,23 @@ def conv1d_bwd_dw_pallas(
     x: (B, L, Cin) — the (padded) forward input; dz: (B, out_len, Cout) —
     the post-epilogue gradient. Returns ``(dw, db)`` with
     dw: (K, Cin, Cout) f32 and db: (Cout,) f32 (db is None without bias).
+    A stride above 1 runs the stride-1 kernel over ``phase_split(x)`` and
+    folds the phase-stacked taps back (see ``phase_taps``).
     """
     K = w_shape_k
     B, L, Cin = x.shape
     _, out_len, Cout = dz.shape
+    if stride > 1:
+        dw, db = conv1d_bwd_dw_pallas(
+            phase_split(x, stride), dz, pl.cdiv(K, stride), tile_l=tile_l,
+            cin_block=cin_block, cout_block=cout_block, has_bias=has_bias,
+            interpret=interpret,
+        )
+        return dw.reshape(-1, Cin, Cout)[:K], db
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
+    x, halo = halo_input(x, 1, (n_tiles - 1) * tile_l, tile_l - 1 + K)
     if padded_out > out_len:  # zero rows contribute nothing to the reduction
         dz = jnp.pad(dz, ((0, 0), (0, padded_out - out_len), (0, 0)))
     cb = _resolve_block(Cin, cin_block)
@@ -266,17 +273,13 @@ def conv1d_bwd_dw_pallas(
         dz = _pad_axis(dz, 2, n_co * ob)
 
     kernel = functools.partial(
-        _dw1d_kernel, taps=K, tile_l=tile_l, stride=stride, nb=B,
-        nt=n_tiles, has_bias=has_bias,
+        _dw1d_kernel, taps=K, tile_l=tile_l, nb=B, nt=n_tiles,
+        has_bias=has_bias,
     )
     # grid: weight-gradient blocks outermost, the (batch, spatial-tile)
     # reduction innermost so each (co, ci) block's visits are consecutive.
     in_specs = [
-        pl.BlockSpec(
-            (1, halo, cb),
-            lambda co, ci, b, i: (b, i * tile_l * stride, ci * cb),
-            indexing_mode=pl.unblocked,
-        ),
+        halo_spec((halo, cb), n_ci, lambda co, ci, b, i: (b, i * tile_l, ci)),
         pl.BlockSpec((1, tile_l, ob), lambda co, ci, b, i: (b, i, co)),
     ]
     out_specs = [
@@ -311,11 +314,12 @@ def _dw_depthwise_kernel(
         dw_ref, db_ref, dw_acc, db_acc = rest
     else:
         (dw_ref, dw_acc), db_ref, db_acc = rest, None, None
-    x = x_ref[0]
     dz = dz_ref[0].astype(jnp.float32)
     acc = jnp.stack(
         [
-            (_slide(x, k, tile_l, stride).astype(jnp.float32) * dz).sum(axis=0)
+            (_slide(x_ref, k, tile_l, stride).astype(jnp.float32) * dz).sum(
+                axis=0
+            )
             for k in range(taps)
         ]
     )  # (K, c_block)
@@ -352,10 +356,8 @@ def conv1d_depthwise_bwd_dw_pallas(
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
+    step = tile_l * stride
+    x, halo = halo_input(x, 1, (n_tiles - 1) * step, (tile_l - 1) * stride + K)
     if padded_out > out_len:
         dz = jnp.pad(dz, ((0, 0), (0, padded_out - out_len), (0, 0)))
     cb = _resolve_block(C, c_block)
@@ -368,11 +370,7 @@ def conv1d_depthwise_bwd_dw_pallas(
         nt=n_tiles, has_bias=has_bias,
     )
     in_specs = [
-        pl.BlockSpec(
-            (1, halo, cb),
-            lambda c, b, i: (b, i * tile_l * stride, c * cb),
-            indexing_mode=pl.unblocked,
-        ),
+        halo_spec((halo, cb), n_c, lambda c, b, i: (b, i * step, c)),
         pl.BlockSpec((1, tile_l, cb), lambda c, b, i: (b, i, c)),
     ]
     out_specs = [pl.BlockSpec((K, cb), lambda c, b, i: (0, c))]
@@ -460,19 +458,14 @@ def conv2d_bwd_dw_pallas(
     tw = min(tile_w, ow)
     nh = pl.cdiv(oh, th)
     nw = pl.cdiv(ow, tw)
-    need_h = (nh * th - 1) * sh + kh
-    need_w = (nw * tw - 1) * sw + kw
-    if need_h > H or need_w > W:
-        x = jnp.pad(
-            x,
-            ((0, 0), (0, max(0, need_h - H)), (0, max(0, need_w - W)), (0, 0)),
-        )
+    x, halo_h, halo_w = halo_input_2d(
+        x, (nh - 1) * th * sh, (th - 1) * sh + kh,
+        (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+    )
     if nh * th > oh or nw * tw > ow:
         dz = jnp.pad(
             dz, ((0, 0), (0, nh * th - oh), (0, nw * tw - ow), (0, 0))
         )
-    halo_h = (th - 1) * sh + kh
-    halo_w = (tw - 1) * sw + kw
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
     n_ci = pl.cdiv(Cin, cb)
@@ -486,11 +479,9 @@ def conv2d_bwd_dw_pallas(
         nh=nh, nw=nw, has_bias=has_bias,
     )
     in_specs = [
-        pl.BlockSpec(
-            (1, halo_h, halo_w, cb),
-            lambda co, ci, b, i, j: (b, i * th * sh, j * tw * sw, ci * cb),
-            indexing_mode=pl.unblocked,
-        ),
+        halo_spec((halo_h, halo_w, cb), n_ci, lambda co, ci, b, i, j: (
+            b, i * th * sh, j * tw * sw, ci,
+        )),
         pl.BlockSpec((1, th, tw, ob), lambda co, ci, b, i, j: (b, i, j, co)),
     ]
     out_specs = [

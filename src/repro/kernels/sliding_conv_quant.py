@@ -21,7 +21,7 @@ the kernel, so chained quantized convs never materialize f32 activations.
 
 Grid/blocking structure is the forward kernels' (sliding_conv1d/2d):
 ``(B, spatial tiles…, Cout blocks, Cin-block reduction)`` with halo input
-tiles via ``pl.unblocked`` index maps and revisit-accumulation in VMEM
+tiles as ``pl.Element`` blocks (``halo_spec``) and revisit-accumulation in VMEM
 scratch — **int32 scratch** for w8a8, f32 for w8a16. All three regimes
 are supported: ``custom`` (tap-stacked single matmul, K ∈ {3,5}),
 ``generic`` (unrolled tap loop, K ≤ 17), and ``compound`` (K > 17) —
@@ -56,12 +56,17 @@ from repro.kernels.sliding_conv1d import (
     _resolve_block,
     _slide,
     apply_activation,
+    halo_input,
+    halo_spec,
+    phase_split,
+    phase_taps,
 )
 from repro.kernels.sliding_conv2d import (
     DEFAULT_TILE_H,
     DEFAULT_TILE_W,
     ROW_CHUNK,
     _shifted,
+    halo_input_2d,
 )
 
 
@@ -110,16 +115,15 @@ def _reduce_dequant(acc, rest, *, n_red, red_axis, requant, finish):
 
 
 def _qkernel_1d(
-    x_ref, w_ref, s_ref, b_ref, *rest, taps, tile_l, stride, n_red,
+    x_ref, w_ref, s_ref, b_ref, *rest, taps, tile_l, n_red,
     activation, w8a8, requant, regime,
 ):
     """int8 sliding conv1d body. w8a8: int8 slides × int8 taps → int32;
     w8a16: float slides × register-dequantized taps → f32."""
-    x = x_ref[0]
     cout = w_ref.shape[2]
     adt = _acc_dtype(w8a8)
     if regime == "custom":
-        cols = [_slide(x, k, tile_l, stride) for k in range(taps)]
+        cols = [_slide(x_ref, k, tile_l) for k in range(taps)]
         stacked = jnp.concatenate(cols, axis=-1)  # (TL, K·cb) — VMEM only
         wf = w_ref[...].reshape(taps * w_ref.shape[1], cout)
         if not w8a8:
@@ -129,7 +133,7 @@ def _qkernel_1d(
     else:
         acc = jnp.zeros((tile_l, cout), adt)
         for k in range(taps):
-            xs = _slide(x, k, tile_l, stride)
+            xs = _slide(x_ref, k, tile_l)
             wk = w_ref[k]
             if not w8a8:
                 xs = xs.astype(jnp.float32)
@@ -254,13 +258,17 @@ def conv1d_quant_pallas(
             f"filter K={K} (stride {stride}) exceeds input length {L}"
         )
     regime = _quant_regime(regime, K)
+    if stride > 1:  # stride → channels: the kernel slides by one row
+        out = conv1d_quant_pallas(
+            phase_split(x, stride), phase_taps(w_q, stride), w_scale, bias,
+            x_scale=x_scale, out_scale=out_scale, mode=mode, tile_l=tile_l,
+            cin_block=cin_block, cout_block=cout_block, regime=regime,
+            activation=activation, out_dtype=out_dtype, interpret=interpret,
+        )
+        return out[:, :out_len]
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
 
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
@@ -282,29 +290,24 @@ def conv1d_quant_pallas(
         # loop over ONE chunk (taps=TAP_CHUNK), so the VMEM working set is
         # chunk-bounded regardless of K.
         n_chunks = pl.cdiv(K, TAP_CHUNK)
-        Kp = n_chunks * TAP_CHUNK
-        if Kp > K:  # zero taps contribute nothing (int8 zeros)
-            w_q = jnp.pad(w_q, ((0, Kp - K), (0, 0), (0, 0)))
-            x = jnp.pad(x, ((0, 0), (0, Kp - K), (0, 0)))
+        # zero taps contribute nothing (int8 zeros)
+        w_q = _pad_axis(w_q, 0, n_chunks * TAP_CHUNK)
         n_red = n_ci * n_chunks
-        chunk_halo = (tile_l - 1) * stride + TAP_CHUNK
+        x, halo = halo_input(
+            x, 1, (n_tiles - 1) * tile_l + (n_chunks - 1) * TAP_CHUNK,
+            tile_l - 1 + TAP_CHUNK,
+        )
         kernel = functools.partial(
-            _qkernel_1d, taps=TAP_CHUNK, tile_l=tile_l, stride=stride,
+            _qkernel_1d, taps=TAP_CHUNK, tile_l=tile_l,
             n_red=n_red, activation=activation, w8a8=w8a8, requant=requant,
             regime="generic",
         )
         # reduction index r decomposes as (cin block, tap chunk): the tap
         # chunk is fastest so a cin block's taps complete consecutively
         in_specs = [
-            pl.BlockSpec(
-                (1, chunk_halo, cb),
-                lambda b, i, co, r: (
-                    b,
-                    i * tile_l * stride + (r % n_chunks) * TAP_CHUNK,
-                    (r // n_chunks) * cb,
-                ),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, cb), n_ci, lambda b, i, co, r: (
+                b, i * tile_l + (r % n_chunks) * TAP_CHUNK, r // n_chunks,
+            )),
             pl.BlockSpec(
                 (TAP_CHUNK, cb, ob),
                 lambda b, i, co, r: (r % n_chunks, r // n_chunks, co),
@@ -315,15 +318,12 @@ def conv1d_quant_pallas(
     else:
         n_red = n_ci
         kernel = functools.partial(
-            _qkernel_1d, taps=K, tile_l=tile_l, stride=stride, n_red=n_red,
+            _qkernel_1d, taps=K, tile_l=tile_l, n_red=n_red,
             activation=activation, w8a8=w8a8, requant=requant, regime=regime,
         )
+        x, halo = halo_input(x, 1, (n_tiles - 1) * tile_l, tile_l - 1 + K)
         in_specs = [
-            pl.BlockSpec(
-                (1, halo, cb),
-                lambda b, i, co, r: (b, i * tile_l * stride, r * cb),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, cb), n_ci, lambda b, i, co, r: (b, i * tile_l, r)),
             pl.BlockSpec((K, cb, ob), lambda b, i, co, r: (0, r, co)),
             pl.BlockSpec((1, ob), lambda b, i, co, r: (0, co)),  # dequant scale
             pl.BlockSpec((1, ob), lambda b, i, co, r: (0, co)),  # bias
@@ -401,15 +401,6 @@ def conv2d_quant_pallas(
     tw = min(tile_w, ow)
     nh = pl.cdiv(oh, th)
     nw = pl.cdiv(ow, tw)
-    need_h = (nh * th - 1) * sh + kh
-    need_w = (nw * tw - 1) * sw + kw
-    if need_h > H or need_w > W:
-        x = jnp.pad(
-            x,
-            ((0, 0), (0, max(0, need_h - H)), (0, max(0, need_w - W)), (0, 0)),
-        )
-    halo_h = (th - 1) * sh + kh
-    halo_w = (tw - 1) * sw + kw
 
     cb = _resolve_block(Cin, cin_block)
     ob = _resolve_block(Cout, cout_block)
@@ -428,28 +419,22 @@ def conv2d_quant_pallas(
         # filter-ROW chunking (the f32 compound structure): reduction grid
         # sweeps Cin blocks × row chunks, the body unrolls ROW_CHUNK×kw taps
         n_chunks = pl.cdiv(kh, ROW_CHUNK)
-        khp = n_chunks * ROW_CHUNK
-        if khp > kh:
-            w_q = jnp.pad(w_q, ((0, khp - kh), (0, 0), (0, 0), (0, 0)))
-            x = jnp.pad(x, ((0, 0), (0, khp - kh), (0, 0), (0, 0)))
+        w_q = _pad_axis(w_q, 0, n_chunks * ROW_CHUNK)
         n_red = n_ci * n_chunks
-        chunk_halo_h = (th - 1) * sh + ROW_CHUNK
+        x, halo_h, halo_w = halo_input_2d(
+            x, (nh - 1) * th * sh + (n_chunks - 1) * ROW_CHUNK,
+            (th - 1) * sh + ROW_CHUNK, (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+        )
         kernel = functools.partial(
             _qkernel_2d, kh=ROW_CHUNK, kw=kw, th=th, tw=tw, sh=sh, sw=sw,
             n_red=n_red, activation=activation, w8a8=w8a8, requant=requant,
             regime="generic",
         )
         in_specs = [
-            pl.BlockSpec(
-                (1, chunk_halo_h, halo_w, cb),
-                lambda b, i, j, co, r: (
-                    b,
-                    i * th * sh + (r % n_chunks) * ROW_CHUNK,
-                    j * tw * sw,
-                    (r // n_chunks) * cb,
-                ),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_h, halo_w, cb), n_ci, lambda b, i, j, co, r: (
+                b, i * th * sh + (r % n_chunks) * ROW_CHUNK, j * tw * sw,
+                r // n_chunks,
+            )),
             pl.BlockSpec(
                 (ROW_CHUNK, kw, cb, ob),
                 lambda b, i, j, co, r: (r % n_chunks, 0, r // n_chunks, co),
@@ -464,12 +449,14 @@ def conv2d_quant_pallas(
             n_red=n_red, activation=activation, w8a8=w8a8, requant=requant,
             regime=regime,
         )
+        x, halo_h, halo_w = halo_input_2d(
+            x, (nh - 1) * th * sh, (th - 1) * sh + kh,
+            (nw - 1) * tw * sw, (tw - 1) * sw + kw,
+        )
         in_specs = [
-            pl.BlockSpec(
-                (1, halo_h, halo_w, cb),
-                lambda b, i, j, co, r: (b, i * th * sh, j * tw * sw, r * cb),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_h, halo_w, cb), n_ci, lambda b, i, j, co, r: (
+                b, i * th * sh, j * tw * sw, r,
+            )),
             pl.BlockSpec(
                 (kh, kw, cb, ob), lambda b, i, j, co, r: (0, 0, r, co)
             ),
@@ -513,11 +500,10 @@ def _qkernel_depthwise(
     (no reduction grid dim), so no revisit scratch is needed."""
     os_ref = rest[0] if requant else None
     o_ref = rest[1] if requant else rest[0]
-    x = x_ref[0]
     adt = _acc_dtype(w8a8)
-    acc = jnp.zeros((tile_l, x.shape[-1]), adt)
+    acc = jnp.zeros((tile_l, x_ref.shape[-1]), adt)
     for k in range(taps):
-        xs = _slide(x, k, tile_l, stride)
+        xs = _slide(x_ref, k, tile_l, stride)
         acc += xs.astype(adt) * w_ref[k].astype(adt)
     _dequant_epilogue(
         acc, os_ref, o_ref, s_ref=s_ref, b_ref=b_ref, activation=activation
@@ -564,10 +550,8 @@ def conv1d_depthwise_quant_pallas(
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = (tile_l - 1) * stride + K
-    need = (padded_out - 1) * stride + K
-    if need > L:
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)))
+    step = tile_l * stride
+    x, halo = halo_input(x, 1, (n_tiles - 1) * step, (tile_l - 1) * stride + K)
     cb = _resolve_block(C, c_block)
     n_c = pl.cdiv(C, cb)
     if n_c * cb > C:
@@ -585,11 +569,7 @@ def conv1d_depthwise_quant_pallas(
         activation=activation, w8a8=w8a8, requant=requant,
     )
     in_specs = [
-        pl.BlockSpec(
-            (1, halo, cb),
-            lambda b, i, c: (b, i * tile_l * stride, c * cb),
-            indexing_mode=pl.unblocked,
-        ),
+        halo_spec((halo, cb), n_c, lambda b, i, c: (b, i * step, c)),
         pl.BlockSpec((K, cb), lambda b, i, c: (0, c)),
         pl.BlockSpec((1, cb), lambda b, i, c: (0, c)),  # dequant scale
         pl.BlockSpec((1, cb), lambda b, i, c: (0, c)),  # bias

@@ -4,8 +4,10 @@ The companion-paper (arXiv:2305.16513) kernel structure shared by pooling
 and 1-D convolution: phase 1 computes an in-VMEM prefix scan along the
 window axis; phase 2 emits the strided difference (sum/avg) or combines the
 block prefix/suffix scans (max — the van Herk / Gil-Werman decomposition).
-Work is O(n) per tile independent of window size — the property the paper
-exploits for large-window pooling.
+Phase 2 does O(n) work per tile independent of window size — the property
+the paper exploits for large-window pooling. Phase 1 is a log-depth scan
+built from row shifts (``_scan_rows``): log₂(tile) passes for sum/avg,
+log₂(w) for max.
 
 Backward kernels (DESIGN.md §6):
 
@@ -26,16 +28,51 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sliding_conv1d import halo_rows, halo_spec
+
 DEFAULT_TILE = 512
+
+
+def _halo_pad(x, rows: int, value=0.0):
+    """Pad axis 1 of ``x`` with ``value`` up to ``rows`` rows."""
+    if x.shape[1] >= rows:
+        return x
+    return jnp.pad(
+        x, ((0, 0), (0, rows - x.shape[1]), (0, 0)), constant_values=value
+    )
+
+
+def _shift_rows(x, d: int):
+    """Rows of ``x`` moved ``d`` down (up for d < 0); vacated rows are 0."""
+    pad = jnp.zeros((abs(d),) + x.shape[1:], x.dtype)
+    if d > 0:
+        return jnp.concatenate([pad, x[:-d]], axis=0)
+    return jnp.concatenate([x[-d:], pad], axis=0)
+
+
+def _scan_rows(x, op, *, seg: int | None = None, reverse: bool = False):
+    """Inclusive log-depth (Hillis–Steele) scan of ``op`` along axis 0 —
+    built from row shifts, which the TPU lowers (its compiler has no
+    cumsum/cummax). ``seg`` restarts the scan every ``seg`` rows;
+    ``reverse`` scans from the end (a suffix scan)."""
+    n = seg or x.shape[0]
+    if seg is not None:
+        pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) % seg
+        if reverse:
+            pos = seg - 1 - pos
+    d = 1
+    while d < n:
+        y = op(x, _shift_rows(x, -d if reverse else d))
+        x = y if seg is None else jnp.where(pos >= d, y, x)
+        d *= 2
+    return x
 
 
 def _sum_pool_kernel(x_ref, o_ref, *, window, tile_l):
     x = x_ref[0].astype(jnp.float32)
-    s = jnp.cumsum(x, axis=0)  # phase 1: prefix scan in VMEM
+    s = _scan_rows(x, jnp.add)  # phase 1: prefix scan in VMEM
     upper = s[window - 1 : window - 1 + tile_l]
-    lower = jnp.concatenate(
-        [jnp.zeros((1,) + s.shape[1:], s.dtype), s[: tile_l - 1]], axis=0
-    )
+    lower = _shift_rows(s, 1)[:tile_l]
     o_ref[0] = (upper - lower).astype(o_ref.dtype)  # phase 2: difference
 
 
@@ -56,29 +93,18 @@ def _max_pool_kernel(x_ref, o_ref, *, window, tile_l):
     """Two-phase max: block prefix/suffix cummax (van Herk / Gil-Werman).
 
     The halo tile is split into window-aligned blocks; phase 1 computes the
-    within-block prefix max P and suffix max S (log-depth scans), phase 2
-    emits ``y[j] = max(S[j], P[j+w-1])`` — O(n) comparisons per tile
-    independent of the window size (vs the O(n·w) shift-and-max loop).
+    within-block prefix max P and suffix max S (log-depth scans, O(n·log w)
+    comparisons), phase 2 emits ``y[j] = max(S[j], P[j+w-1])`` in O(n) (vs
+    the O(n·w) shift-and-max loop).
     """
     x = x_ref[0]
     if window == 1:
         o_ref[0] = x[:tile_l]
         return
-    halo = tile_l + window - 1
-    nb = pl.cdiv(halo, window)
-    pad = nb * window - halo
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.full((pad,) + x.shape[1:], -jnp.inf, x.dtype)], axis=0
-        )
-    blocks = x.reshape(nb, window, -1)
-    pre = jax.lax.cummax(blocks, axis=1).reshape(nb * window, -1)
-    suf = jax.lax.cummax(blocks[:, ::-1], axis=1)[:, ::-1].reshape(
-        nb * window, -1
-    )
-    o_ref[0] = jnp.maximum(
-        suf[:tile_l], pre[window - 1 : window - 1 + tile_l]
-    ).reshape(o_ref.shape[1:])
+    # window-aligned blocks start at the tile's first row
+    pre = _scan_rows(x, jnp.maximum, seg=window)
+    suf = _scan_rows(x, jnp.maximum, seg=window, reverse=True)
+    o_ref[0] = jnp.maximum(suf[:tile_l], pre[window - 1 : window - 1 + tile_l])
 
 
 @functools.partial(
@@ -105,11 +131,9 @@ def sliding_pool_pallas(
     tile_l = min(tile_l, out_len)
     n_tiles = pl.cdiv(out_len, tile_l)
     padded_out = n_tiles * tile_l
-    halo = tile_l + window - 1
-    need = padded_out + window - 1
-    if need > L:
-        pad_val = 0.0 if op in ("sum", "avg") else -jnp.inf
-        x = jnp.pad(x, ((0, 0), (0, need - L), (0, 0)), constant_values=pad_val)
+    halo = halo_rows(tile_l + window - 1)
+    pad_val = 0.0 if op in ("sum", "avg") else -jnp.inf
+    x = _halo_pad(x, (n_tiles - 1) * tile_l + halo, pad_val)
     if op in ("sum", "avg"):
         body = _sum_pool_kernel
     else:
@@ -118,13 +142,7 @@ def sliding_pool_pallas(
     out = pl.pallas_call(
         kernel,
         grid=(B, n_tiles),
-        in_specs=[
-            pl.BlockSpec(
-                (1, halo, C),
-                lambda b, i: (b, i * tile_l, 0),
-                indexing_mode=pl.unblocked,
-            )
-        ],
+        in_specs=[halo_spec((halo, C), 1, lambda b, i: (b, i * tile_l, 0))],
         out_specs=pl.BlockSpec((1, tile_l, C), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, padded_out, C), x.dtype),
         interpret=interpret,
@@ -201,20 +219,14 @@ def max_pool_bwd_pallas(
     to = min(tile_l, out_len)
     nt_o = pl.cdiv(out_len, to)
     pad_o = nt_o * to - out_len
-    need_x = nt_o * to + window - 1  # last tile's halo end
-    xp = x
-    if need_x > padded:
-        xp = jnp.pad(x, ((0, 0), (0, need_x - padded), (0, 0)))
+    halo_o = halo_rows(to + window - 1)
+    xp = _halo_pad(x, (nt_o - 1) * to + halo_o)  # last tile's halo end
     yp = jnp.pad(y, ((0, 0), (0, pad_o), (0, 0))) if pad_o else y
     cnt = pl.pallas_call(
         functools.partial(_max_pool_count_kernel, window=window, tile_l=to),
         grid=(B, nt_o),
         in_specs=[
-            pl.BlockSpec(
-                (1, to + window - 1, C),
-                lambda b, i: (b, i * to, 0),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo_o, C), 1, lambda b, i: (b, i * to, 0)),
             pl.BlockSpec((1, to, C), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, to, C), lambda b, i: (b, i, 0)),
@@ -226,28 +238,20 @@ def max_pool_bwd_pallas(
     # pass 2: scatter each window's (split) gradient onto its argmaxes.
     # front pad (w-1) aligns dy[j-k] reads; zero dy rows nullify windows that
     # fall outside [0, out_len) regardless of the y pad value.
-    rear = padded - out_len
+    halo = halo_rows(tile_l + window - 1)
+    rear = (n_tiles - 1) * tile_l + halo - (window - 1) - out_len
     y = jnp.pad(y, ((0, 0), (window - 1, rear), (0, 0)))
     dy = jnp.pad(dy, ((0, 0), (window - 1, rear), (0, 0)))
     kernel = functools.partial(
         _max_pool_bwd_kernel, window=window, tile_l=tile_l
     )
-    halo = tile_l + window - 1
     out = pl.pallas_call(
         kernel,
         grid=(B, n_tiles),
         in_specs=[
             pl.BlockSpec((1, tile_l, C), lambda b, i: (b, i, 0)),
-            pl.BlockSpec(
-                (1, halo, C),
-                lambda b, i: (b, i * tile_l, 0),
-                indexing_mode=pl.unblocked,
-            ),
-            pl.BlockSpec(
-                (1, halo, C),
-                lambda b, i: (b, i * tile_l, 0),
-                indexing_mode=pl.unblocked,
-            ),
+            halo_spec((halo, C), 1, lambda b, i: (b, i * tile_l, 0)),
+            halo_spec((halo, C), 1, lambda b, i: (b, i * tile_l, 0)),
         ],
         out_specs=pl.BlockSpec((1, tile_l, C), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, padded, C), jnp.float32),

@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import faults, obs
+from repro import compile_cache, faults, obs
 from repro.configs import get_config, smoke_config
 from repro.distributed.ft import RestartPolicy, StepWatchdog, beat
 from repro.distributed.sharding import ParamDef, Runtime
@@ -692,6 +692,7 @@ def main():
                          "reproduces the clean tokens)")
     args = ap.parse_args()
 
+    compile_cache.enable()
     if args.trace:
         obs.enable()
     cfg = get_config(args.arch)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import faults, obs
+from repro import compile_cache, faults, obs
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, smoke_config
 from repro.data import SyntheticLMData, make_batch_iterator
@@ -272,6 +272,7 @@ def main():
                          "--run-dir")
     args = ap.parse_args()
 
+    compile_cache.enable()
     if args.trace:
         obs.enable()
     policy = RestartPolicy(max_restarts=args.max_restarts)

@@ -7,7 +7,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro._compat import optimization_barrier
+from jax.lax import optimization_barrier
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ParamDef, Runtime
 

@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro._compat import optimization_barrier
+from jax.lax import optimization_barrier
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ParamDef, Runtime, abstract_params, init_params
 from repro.models import layers as L

@@ -21,7 +21,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from repro._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig

@@ -35,7 +35,7 @@ def _clean_conv_like(**overrides) -> KernelInstance:
         inputs=[
             Block("x", (1, halo, cb), "float32",
                   lambda b, i, co, r: (b, i * tile_l, r * cb),
-                  (2, need, 2 * cb), unblocked=True),
+                  (2, need, 2 * cb), element=True),
             Block("w", (K, cb, ob), "float32",
                   lambda b, i, co, r: (0, r, co), (K, 2 * cb, ob)),
         ],
@@ -58,7 +58,7 @@ def test_clean_fixture_passes():
 
 
 def test_fixture_halo_oob():
-    """The seed bug: an unblocked halo index map over an UNPADDED array —
+    """The seed bug: an element-offset halo index map over an UNPADDED array —
     the final tile reads past the end."""
     tile_l, K, cb = 64, 5, 8
     halo = tile_l - 1 + K
@@ -66,7 +66,7 @@ def test_fixture_halo_oob():
         "x", (1, halo, cb), "float32",
         lambda b, i, co, r: (b, i * tile_l, r * cb),
         (2, 4 * tile_l, 2 * cb),  # length 256: tile 3 reads [192, 260)
-        unblocked=True,
+        element=True,
     )
     inst = _clean_conv_like()
     inst.inputs[0] = bad_x
@@ -117,7 +117,7 @@ def test_fixture_leading_revisit_dim():
         inputs=[
             Block("x", (1, halo, cb), "float32",
                   lambda b, r, i, co: (b, i * tile_l, r * cb),
-                  (2, need, 2 * cb), unblocked=True),
+                  (2, need, 2 * cb), element=True),
             Block("w", (K, cb, ob), "float32",
                   lambda b, r, i, co: (0, r, co), (K, 2 * cb, ob)),
         ],

@@ -4,8 +4,11 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
+
+ROOT = str(Path(__file__).resolve().parents[1])
 
 ENV = dict(os.environ, PYTHONPATH="src",
            XLA_FLAGS="--xla_force_host_platform_device_count=8")
@@ -14,7 +17,7 @@ ENV = dict(os.environ, PYTHONPATH="src",
 def run_py(body: str) -> str:
     r = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(body)],
-        capture_output=True, text=True, env=ENV, cwd="/root/repo",
+        capture_output=True, text=True, env=ENV, cwd=ROOT,
         timeout=600,
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-4000:]}"
@@ -28,7 +31,7 @@ def test_sharded_loss_matches_single_device():
         from repro.distributed.sharding import Runtime, DEFAULT_RULES
         from repro.models import build_model
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro._compat import set_mesh
+        from jax import set_mesh
 
         cfg = smoke_config(get_config('qwen3-moe-30b-a3b')).replace(
             d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
@@ -43,7 +46,8 @@ def test_sharded_loss_matches_single_device():
         l1 = float(jax.jit(m1.loss)(p1, batch))
 
         # 2x4 mesh (data x model)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(2, 4)
         rt = Runtime(mesh=mesh, rules=dict(DEFAULT_RULES))
         m2 = build_model(cfg, rt)
         shard = rt.param_shardings(m2.param_defs())
@@ -67,7 +71,7 @@ def test_ep_moe_matches_dense_fallback():
         from repro.distributed.sharding import Runtime, DEFAULT_RULES, init_params
         from repro.models import moe as moe_lib
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro._compat import set_mesh
+        from jax import set_mesh
 
         cfg = smoke_config(get_config('phi3.5-moe-42b-a6.6b')).replace(
             d_model=32, d_ff=64, num_experts=8, experts_per_token=2,
@@ -98,7 +102,7 @@ def test_ep_moe_matches_dense_fallback():
 def test_compressed_allreduce_error_feedback():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro._compat import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.optim.compress import ef_allreduce_grads
 
@@ -117,7 +121,7 @@ def test_compressed_allreduce_error_feedback():
                        out_specs=(P('data'), P('data')), check_vma=False)
         err = jnp.zeros_like(g_all)
         mean, err = sm(g_all, err)
-        got = np.asarray(mean[0])
+        got = np.asarray(mean)[0]
         rel = np.abs(got - np.asarray(exact)).max() / np.abs(exact).max()
         print('rel err', rel)
         assert rel < 0.05          # one step: quantized but close
@@ -128,7 +132,7 @@ def test_compressed_allreduce_error_feedback():
         err = jnp.zeros_like(g_all)
         for i in range(20):
             mean, err = sm(g_all, err)
-            total += np.asarray(mean[0])
+            total += np.asarray(mean)[0]
         avg = total / 20
         rel2 = np.abs(avg - np.asarray(exact)).max() / np.abs(exact).max()
         print('rel err after EF', rel2)
@@ -162,14 +166,14 @@ def test_elastic_restore_across_meshes(tmp_path):
     assert "ELASTIC OK" in out
 
 
-def test_dryrun_entry_on_tiny_cell():
+def test_dryrun_entry_on_tiny_cell(tmp_path):
     """The dry-run driver itself (512 virtual devices) on the smallest cell."""
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "whisper-medium",
          "--shape", "decode_32k", "--mesh", "single", "--out",
-         "/tmp/dryrun_test"],
+         str(tmp_path / "dryrun")],
         capture_output=True, text=True,
-        env=dict(os.environ, PYTHONPATH="src"), cwd="/root/repo", timeout=900,
+        env=dict(os.environ, PYTHONPATH="src"), cwd=ROOT, timeout=900,
     )
     assert r.returncode == 0, r.stdout + r.stderr[-3000:]
     assert "OK" in r.stdout

@@ -712,14 +712,17 @@ def test_train_runtime_fault_demotes_and_recovers(tmp_path):
         )
 
     clean = train_loop(args(tmp_path / "clean"))
+    # the same clean run on the rung the retry falls back to
+    HEALTH.demote("conv1d", "pallas", reason="pallas_runtime")
+    clean_demoted = train_loop(args(tmp_path / "clean_demoted"))
     HEALTH.reset()
     with faults.inject("pallas_runtime", site="conv1d", times=1):
         chaos = train_loop(args(tmp_path / "chaos"))
     assert np.isfinite(chaos["losses"]).all()
-    # the retried step 0 must match the clean run exactly: the poisoned
-    # attempt's output never reached `state`
+    # the retried step 0 must match a clean run on its rung exactly: the
+    # poisoned attempt's output never reached `state`
     np.testing.assert_array_equal(np.asarray(chaos["losses"][0]),
-                                  np.asarray(clean["losses"][0]))
+                                  np.asarray(clean_demoted["losses"][0]))
     # later steps run on the demoted rung, whose backward may differ from
     # the pallas rung in the final ulp — allclose, not bitwise
     np.testing.assert_allclose(np.asarray(chaos["losses"]),

@@ -13,10 +13,21 @@ settings.register_profile("ci", max_examples=25, deadline=None)
 settings.load_profile("ci")
 
 
+def f32s(lo, hi):
+    """Floats in [lo, hi] at float32 width. The strategy needs bounds that
+    float32 holds exactly, so each bound snaps inward to the nearest one."""
+    lo32, hi32 = np.float32(lo), np.float32(hi)
+    if lo32 < lo:
+        lo32 = np.nextafter(lo32, np.float32(np.inf))
+    if hi32 > hi:
+        hi32 = np.nextafter(hi32, np.float32(-np.inf))
+    return st.floats(float(lo32), float(hi32), width=32)
+
+
 def arr(draw, shape, lo=-4, hi=4):
     vals = draw(
         st.lists(
-            st.floats(lo, hi, width=32),
+            f32s(lo, hi),
             min_size=int(np.prod(shape)),
             max_size=int(np.prod(shape)),
         )
@@ -45,7 +56,7 @@ def test_conv_linearity(data):
     x = arr(data.draw, (1, 16, 2))
     y = arr(data.draw, (1, 16, 2))
     w = arr(data.draw, (k, 2, 3), lo=-2, hi=2)
-    a = data.draw(st.floats(-2, 2, width=32))
+    a = data.draw(f32s(-2, 2))
     lhs = core.conv1d_sliding(a * x + y, w)
     rhs = a * core.conv1d_sliding(x, w) + core.conv1d_sliding(y, w)
     np.testing.assert_allclose(lhs, rhs, rtol=1e-2, atol=1e-2)
@@ -120,7 +131,7 @@ def test_quantize_roundtrip_ndim_sweep(data):
         data.draw(st.integers(1, 12), label=f"d{i}") for i in range(ndim)
     )
     x = (
-        jnp.asarray(data.draw(st.floats(-50, 50, width=32)), jnp.float32)
+        jnp.asarray(data.draw(f32s(-50, 50)), jnp.float32)
         if ndim == 0
         else arr(data.draw, dims, lo=-50, hi=50)
     )
@@ -141,7 +152,7 @@ def test_quantize_zero_rows_exact(data):
     from repro.optim import dequantize_int8, quantize_int8
 
     n = data.draw(st.integers(1, 16), label="n")
-    big = data.draw(st.floats(100, 1e4, width=32), label="big")
+    big = data.draw(f32s(100, 1e4), label="big")
     x = np.zeros((3, n), np.float32)
     x[1, :] = big  # rows: zero, big, zero
     q, s = quantize_int8(jnp.asarray(x))
@@ -161,8 +172,8 @@ def test_restart_policy_budget_and_cap(data):
     from repro.distributed.ft import RestartPolicy
 
     max_restarts = data.draw(st.integers(0, 8), label="max_restarts")
-    base = data.draw(st.floats(0.01, 10, width=32), label="base")
-    cap = data.draw(st.floats(0.01, 100, width=32), label="cap")
+    base = data.draw(f32s(0.01, 10), label="base")
+    cap = data.draw(f32s(0.01, 100), label="cap")
     p = RestartPolicy(max_restarts=max_restarts, base_backoff_s=base,
                       max_backoff_s=cap)
     delays = [p.next_backoff() for _ in range(max_restarts + 3)]
@@ -189,9 +200,9 @@ def test_restart_policy_jitter_monotone_capped_deterministic(data):
     from repro.distributed.ft import RestartPolicy
 
     max_restarts = data.draw(st.integers(1, 8), label="max_restarts")
-    base = data.draw(st.floats(0.01, 10, width=32), label="base")
-    cap = data.draw(st.floats(0.01, 100, width=32), label="cap")
-    jitter = data.draw(st.floats(0.0, 1.0, width=32), label="jitter")
+    base = data.draw(f32s(0.01, 10), label="base")
+    cap = data.draw(f32s(0.01, 100), label="cap")
+    jitter = data.draw(f32s(0.0, 1.0), label="jitter")
     seed = data.draw(st.integers(0, 2**31), label="seed")
 
     def grants():
@@ -220,7 +231,7 @@ def test_watchdog_never_flags_during_warmup(data):
     warmup = data.draw(st.integers(0, 6), label="warmup")
     wd = StepWatchdog(threshold=1.01, warmup_steps=warmup)
     for i in range(max(warmup, 1)):
-        sec = data.draw(st.floats(1e-3, 100, width=32), label=f"t{i}")
+        sec = data.draw(f32s(1e-3, 100), label=f"t{i}")
         assert not wd.observe(i, sec)
     assert wd.events == []
 
@@ -232,8 +243,8 @@ def test_watchdog_flags_spike_not_steady_state(data):
     from repro.distributed.ft import StepWatchdog
 
     warmup = data.draw(st.integers(0, 6), label="warmup")
-    threshold = data.draw(st.floats(1.5, 5, width=32), label="threshold")
-    base = data.draw(st.floats(0.01, 1.0, width=32), label="base")
+    threshold = data.draw(f32s(1.5, 5), label="threshold")
+    base = data.draw(f32s(0.01, 1.0), label="base")
     wd = StepWatchdog(threshold=threshold, warmup_steps=warmup, decay=0.9)
     for i in range(warmup + 8):
         assert not wd.observe(i, base)
@@ -248,9 +259,9 @@ def test_watchdog_ema_decays_toward_steady_state(data):
     ``decay``): after n constant steps the distance shrinks by decay^n."""
     from repro.distributed.ft import StepWatchdog
 
-    v0 = data.draw(st.floats(1.0, 100, width=32), label="v0")
-    v = data.draw(st.floats(0.01, 1.0, width=32), label="v")
-    decay = data.draw(st.floats(0.1, 0.9, width=32), label="decay")
+    v0 = data.draw(f32s(1.0, 100), label="v0")
+    v = data.draw(f32s(0.01, 1.0), label="v")
+    decay = data.draw(f32s(0.1, 0.9), label="decay")
     wd = StepWatchdog(decay=decay, warmup_steps=10_000)  # detection off
     wd.observe(0, v0)
     for i in range(1, 40):
