@@ -422,8 +422,8 @@ def serve_rows(quick: bool) -> list[str]:
 def _provenance() -> dict:
     """``__meta__`` header for BENCH_conv.json: enough to know what
     machine/toolchain produced the numbers, plus the obs registry
-    snapshot (per-autotune-key dispatch call counts + wall time) so a
-    perf regression can be traced to WHICH kernels actually ran."""
+    snapshot (per-autotune-key dispatch call counts) so a perf
+    regression can be traced to WHICH kernels actually ran."""
     import jax
 
     from repro import obs
@@ -445,12 +445,9 @@ def main() -> None:
     grad = "--grad" in sys.argv
     qnt = "--quant" in sys.argv
     srv = "--serve" in sys.argv
-    from repro import compile_cache, obs
+    from repro import compile_cache
 
     compile_cache.enable()
-    # arm the dispatch-layer counters (not tracing) so the provenance
-    # header records which rung served each autotune key and for how long
-    obs.enable_dispatch()
     from benchmarks import fig1_speedup, fig2_throughput, roofline_report, table_conv1d
 
     rows: list[str] = []

@@ -24,7 +24,7 @@ the lint only moves the failure from "first hit in production" to "CI":
   * **lint_obs_name** — literal metric names at ``.counter(`` /
     ``.gauge(`` / ``.histogram(`` / ``.facts(`` call sites must come from
     the frozen ``obs.names.METRICS`` vocabulary, literal span names at
-    ``span`` / ``instant`` / ``traced`` from ``obs.names.SPANS``, and
+    ``span`` from ``obs.names.SPANS``, and
     neither may be an f-string — dynamic names fork the telemetry
     namespace the report CLI and CI assertions key on (the registry
     enforces the same at runtime; the lint moves the failure to CI).
@@ -77,7 +77,7 @@ _REASON_VALUES = {r.value for r in Reason}
 _METRIC_METHODS = {"counter", "gauge", "histogram", "facts"}
 
 #: tracing entry points whose literal first arg is a span name
-_SPAN_FUNCS = {"span", "instant", "traced"}
+_SPAN_FUNCS = {"span"}
 
 #: the explicit wall-clock registry: files (package-relative, posix)
 #: allowed to call ``time.time()``, with the reason — these produce

@@ -54,8 +54,9 @@ import sys
 import threading
 import time
 
-# stdlib-only like this module — no cycle, and every health event mirrors
-# into the obs metrics/trace surfaces (DESIGN.md §12)
+# obs imports nothing else of repro (JAX only at the first span) — no
+# cycle, and every health event mirrors into the obs metrics/trace
+# surfaces (DESIGN.md §12)
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs_trace
 from repro.obs.metrics import DispatchLog  # noqa: F401 — canonical home
@@ -252,6 +253,16 @@ class Health:
                 f"unknown health reason {reason!r} at site {site!r}: "
                 f"add it to health.Reason or canonicalize via canon_reason"
             ) from None
+        # while a profiler records, the event is a span on the timeline
+        # next to the kernel ops it explains
+        with _obs_trace.span(
+            "health.event", site=site, reason=reason, action=action
+        ):
+            return self._log(site, reason, action, detail)
+
+    def _log(
+        self, site: str, reason: str, action: str, detail: str
+    ) -> HealthEvent:
         with self._lock:
             hit = None
             for ev in self.events:
@@ -265,14 +276,9 @@ class Health:
                 first = True
             else:
                 first = False
-        # mirror into obs: a counter series per (site, reason, action) and,
-        # when tracing is armed, an instant so demotions land on the
-        # timeline next to the kernel spans they explain
+        # mirror into obs: a counter series per (site, reason, action)
         _obs_metrics.REGISTRY.counter("health.events").inc(
             1.0, site=site, reason=reason, action=action
-        )
-        _obs_trace.instant(
-            "health.event", site=site, reason=reason, action=action
         )
         if first:
             print(f"[health] {hit.line()}", file=sys.stderr)

@@ -220,6 +220,7 @@ def decode_attention_pallas(
         args += [ks3.transpose(0, 2, 1), vs3.transpose(0, 2, 1)]
     return pl.pallas_call(
         kernel,
+        name="decode_attention_pallas",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, n_h, n_s),

@@ -360,6 +360,10 @@ def _search(
             default_t = _time_fn(lambda: run(default))
         reg.counter("autotune.candidates").inc(1.0, key=key)
         best_cfg, best_t = dict(default), default_t
+        if contract is not None and contract(default) is not None:
+            # timed as the baseline untuned dispatch runs, but a default
+            # that breaks the contract wins only if nothing else survives
+            best_t = float("inf")
         timed = cost_skipped = since_improve = 0
         for i, cand in enumerate(cands):
             try:
@@ -383,6 +387,8 @@ def _search(
                         float(cost_skipped), key=key
                     )
                 break
+    if best_t == float("inf"):  # no candidate survived: the default stays
+        best_t = default_t
     best_cfg["us"] = round(best_t * 1e6, 2)
     best_cfg["default_us"] = round(default_t * 1e6, 2)
     record(key, best_cfg)

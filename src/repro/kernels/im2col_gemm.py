@@ -69,6 +69,7 @@ def matmul_pallas(
         b = jnp.pad(b, ((0, gk * tk - K), (0, gn * tn - N)))
     out = pl.pallas_call(
         _matmul_kernel,
+        name="matmul_pallas",
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
@@ -126,6 +127,7 @@ def conv1d_im2col_fused_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="conv1d_im2col_fused_pallas",
         grid=(B, n_tiles),
         in_specs=[
             halo_spec((halo, Cin), 1, lambda b, i: (b, i * step, 0)),
@@ -211,6 +213,7 @@ def conv2d_im2col_fused_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="conv2d_im2col_fused_pallas",
         grid=(B, nh, nw),
         in_specs=[
             halo_spec((halo_h, halo_w, Cin), 1, lambda b, i, j: (

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import functools
 import sys
-import time
 from typing import Literal, NamedTuple
 
 import jax
@@ -51,9 +50,7 @@ import numpy as np
 from repro import faults, health
 from repro.core import conv as core_conv
 from repro.health import HEALTH
-from repro.launch.hlo_flops import est_hbm_bytes
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.kernels import (
     attention_decode as attn_dec,
     autotune,
@@ -78,7 +75,7 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _ladder(site: str, rungs, *, key: str | None = None, operands=()):
+def _ladder(site: str, rungs, *, key: str | None = None):
     """Graceful-degradation dispatch (DESIGN.md §10).
 
     ``rungs`` is an ordered list of ``(name, thunk)`` — pallas kernel →
@@ -104,42 +101,22 @@ def _ladder(site: str, rungs, *, key: str | None = None, operands=()):
     through this exact path — success repromotes it, failure re-demotes
     with a grown cooldown.
 
-    Observability (DESIGN.md §12): when tracing (``REPRO_TRACE``) or the
-    dispatch metrics (``obs.metrics.enable_dispatch``) are armed, the
-    winning rung is wrapped in a ``kernel.dispatch`` span and recorded
-    under its autotune shape ``key`` — call count, cumulative wall time,
-    and estimated HBM bytes of ``operands`` + result. Because dispatch
-    runs at trace time, the wall time measures trace/eager cost, not
-    per-step compiled runtime — free in jitted hot loops, which re-trace
-    only on new shapes. Disabled path: one flag check, no allocation.
+    Observability (DESIGN.md §12): the winning rung bumps
+    ``dispatch.calls`` under its autotune shape ``key``. Dispatch runs at
+    trace time, so a jitted hot loop pays this once per shape, never per
+    step.
     """
     live = [(n, t) for n, t in rungs if not HEALTH.is_demoted(site, n)]
     if not live:
         live = [rungs[-1]]  # fully demoted site: keep serving the oracle
-    obs_on = obs_trace.TRACING or obs_metrics.DISPATCH_ON
     for i, (name, thunk) in enumerate(live):
         try:
             faults.maybe_fail_rung(name, site)
-            if not obs_on:
-                out = thunk()
-                out = faults.guest_trap(site, name, key, out)
-                HEALTH.note_success(site, name)
-                return out
-            t0 = time.perf_counter()
-            with obs_trace.span(
-                "kernel.dispatch", site=site, key=key or site, rung=name
-            ):
-                out = thunk()
+            out = thunk()
             out = faults.guest_trap(site, name, key, out)
-            dt = time.perf_counter() - t0
-            labels = dict(site=site, key=key or site, rung=name)
-            reg = obs_metrics.REGISTRY
-            reg.counter("dispatch.calls").inc(1.0, **labels)
-            reg.counter("dispatch.seconds_total").inc(dt, **labels)
-            if operands:
-                reg.counter("dispatch.est_hbm_bytes_total").inc(
-                    float(est_hbm_bytes(*operands, out)), **labels
-                )
+            obs_metrics.REGISTRY.counter("dispatch.calls").inc(
+                1.0, site=site, key=key or site, rung=name
+            )
             HEALTH.note_success(site, name)
             return out
         except Exception as e:  # noqa: BLE001 — any failure → next rung
@@ -522,9 +499,7 @@ def conv1d(
                 accumulate=accumulate, out_dtype=out_dtype,
             )
 
-        return _ladder(site, key=qkey,
-                       operands=(x, w, bias, w_scale, x_scale, out_scale),
-                       rungs=[
+        return _ladder(site, key=qkey, rungs=[
             ("pallas", lambda: sliding_conv_quant.conv1d_quant_pallas(
                 x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
                 mode=precision, activation=activation, out_dtype=out_dtype,
@@ -555,7 +530,7 @@ def conv1d(
             bwd_tile_l=_bwd_tile1d(x, w, stride, bwd_tile_l),
             interpret=interpret, **tuned,
         )
-        return _ladder("conv1d", key=key, operands=(x, w, bias), rungs=[
+        return _ladder("conv1d", key=key, rungs=[
             ("pallas", lambda: _conv1d_sliding_op(cfg, x, w, bias)),
             ("jax", lambda: epilogue_unfused(
                 core_conv.conv1d_sliding(
@@ -697,9 +672,7 @@ def conv1d_depthwise(
                 accumulate=accumulate, out_dtype=out_dtype,
             )
 
-        return _ladder(site, key=key,
-                       operands=(x, w, bias, w_scale, x_scale, out_scale),
-                       rungs=[
+        return _ladder(site, key=key, rungs=[
             ("pallas", lambda: sliding_conv_quant.conv1d_depthwise_quant_pallas(
                 x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
                 mode=precision, stride=stride,
@@ -722,8 +695,7 @@ def conv1d_depthwise(
     dw_key = autotune.conv1d_dw_key(
         *x.shape, w.shape[0], stride, x.dtype.name
     )
-    return _ladder("conv1d_depthwise", key=dw_key,
-                   operands=(x, w, bias), rungs=[
+    return _ladder("conv1d_depthwise", key=dw_key, rungs=[
         ("pallas", lambda: _conv1d_depthwise_op(cfg, x, w, bias)),
         ("jax", lambda: epilogue_unfused(
             core_conv.conv1d_depthwise_sliding(
@@ -920,9 +892,7 @@ def conv2d(
                 accumulate=accumulate, out_dtype=out_dtype,
             )
 
-        return _ladder(site, key=qkey,
-                       operands=(x, w, bias, w_scale, x_scale, out_scale),
-                       rungs=[
+        return _ladder(site, key=qkey, rungs=[
             ("pallas", lambda: sliding_conv_quant.conv2d_quant_pallas(
                 x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
                 mode=precision, activation=activation, out_dtype=out_dtype,
@@ -958,7 +928,7 @@ def conv2d(
             activation=activation, has_bias=bias is not None,
             bwd_tile_h=bth, bwd_tile_w=btw, interpret=interpret, **tuned,
         )
-        return _ladder("conv2d", key=key, operands=(x, w, bias), rungs=[
+        return _ladder("conv2d", key=key, rungs=[
             ("pallas", lambda: _conv2d_sliding_op(cfg, x, w, bias)),
             ("jax", lambda: epilogue_unfused(
                 core_conv.conv2d_sliding(
@@ -1081,7 +1051,7 @@ def attention_decode(
     out = _ladder(
         "attention_decode",
         [(im, functools.partial(_run, im)) for im in order],
-        key=key, operands=(q, k, v, k_scale, v_scale),
+        key=key,
     )
     return out.reshape(B, H, D)
 
@@ -1164,7 +1134,7 @@ def pool1d(
     interpret = use_interpret() if interpret is None else interpret
     resolved = _pool_method(x, window, op, method)
     pool_key = autotune.pool1d_key(*x.shape, window, op, x.dtype.name)
-    return _ladder("pool1d", key=pool_key, operands=(x,), rungs=[
+    return _ladder("pool1d", key=pool_key, rungs=[
         ("pallas", lambda: _pool1d_op(window, op, resolved, interpret, x)),
         ("jax", lambda: kernels_ref.pool_ref(x, window=window, op=op)),
     ])

@@ -400,6 +400,7 @@ def conv1d_sliding_pallas(
     out_sds = jax.ShapeDtypeStruct((B, padded_out, n_co * ob), out_dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv1d_sliding_pallas",
         grid=(B, n_tiles, n_co, n_red),
         in_specs=in_specs,
         out_specs=[out_spec] * n_out,
@@ -477,6 +478,7 @@ def conv1d_depthwise_pallas(
     out_sds = jax.ShapeDtypeStruct((B, padded_out, n_c * cb), x.dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv1d_depthwise_pallas",
         grid=(B, n_tiles, n_c),
         in_specs=in_specs,
         out_specs=[out_spec] * n_out,

@@ -251,6 +251,7 @@ def conv2d_sliding_pallas(
     out_sds = jax.ShapeDtypeStruct((B, nh * th, nw * tw, n_co * ob), x.dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv2d_sliding_pallas",
         grid=(B, nh, nw, n_co, n_red),
         in_specs=in_specs,
         out_specs=[out_spec] * n_out,

@@ -295,6 +295,7 @@ def conv1d_bwd_dw_pallas(
         scratch.append(pltpu.VMEM((1, ob), jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name="conv1d_bwd_dw_pallas",
         grid=(n_co, n_ci, B, n_tiles),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -382,6 +383,7 @@ def conv1d_depthwise_bwd_dw_pallas(
         scratch.append(pltpu.VMEM((1, cb), jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name="conv1d_depthwise_bwd_dw_pallas",
         grid=(n_c, B, n_tiles),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -499,6 +501,7 @@ def conv2d_bwd_dw_pallas(
         scratch.append(pltpu.VMEM((1, ob), jnp.float32))
     out = pl.pallas_call(
         kernel,
+        name="conv2d_bwd_dw_pallas",
         grid=(n_co, n_ci, B, nh, nw),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -335,6 +335,7 @@ def conv1d_quant_pallas(
     odt = jnp.int8 if requant else jnp.dtype(out_dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv1d_quant_pallas",
         grid=(B, n_tiles, n_co, n_red),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -470,6 +471,7 @@ def conv2d_quant_pallas(
     odt = jnp.int8 if requant else jnp.dtype(out_dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv2d_quant_pallas",
         grid=(B, nh, nw, n_co, n_red),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -581,6 +583,7 @@ def conv1d_depthwise_quant_pallas(
     odt = jnp.int8 if requant else jnp.dtype(out_dtype)
     out = pl.pallas_call(
         kernel,
+        name="conv1d_depthwise_quant_pallas",
         grid=(B, n_tiles, n_c),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile_l, cb), lambda b, i, c: (b, i, c)),

@@ -141,6 +141,7 @@ def sliding_pool_pallas(
     kernel = functools.partial(body, window=window, tile_l=tile_l)
     out = pl.pallas_call(
         kernel,
+        name="sliding_pool_pallas",
         grid=(B, n_tiles),
         in_specs=[halo_spec((halo, C), 1, lambda b, i: (b, i * tile_l, 0))],
         out_specs=pl.BlockSpec((1, tile_l, C), lambda b, i: (b, i, 0)),
@@ -224,6 +225,7 @@ def max_pool_bwd_pallas(
     yp = jnp.pad(y, ((0, 0), (0, pad_o), (0, 0))) if pad_o else y
     cnt = pl.pallas_call(
         functools.partial(_max_pool_count_kernel, window=window, tile_l=to),
+        name="max_pool_count_pallas",
         grid=(B, nt_o),
         in_specs=[
             halo_spec((halo_o, C), 1, lambda b, i: (b, i * to, 0)),
@@ -247,6 +249,7 @@ def max_pool_bwd_pallas(
     )
     out = pl.pallas_call(
         kernel,
+        name="max_pool_bwd_pallas",
         grid=(B, n_tiles),
         in_specs=[
             pl.BlockSpec((1, tile_l, C), lambda b, i: (b, i, 0)),

@@ -87,6 +87,7 @@ def ssm_scan_pallas(
     kernel = functools.partial(_kernel, chunk_l=chunk_l, n_chunks=nl)
     y, h_last = pl.pallas_call(
         kernel,
+        name="ssm_scan_pallas",
         grid=(B, nd, nl),  # L innermost: scratch carries state sequentially
         in_specs=[
             pl.BlockSpec((1, chunk_l, tile_d, N), lambda b, d, l: (b, l, d, 0)),

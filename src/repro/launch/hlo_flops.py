@@ -369,11 +369,8 @@ def est_hbm_bytes(*arrays) -> int:
     flattened so the f32 scale siblings of int8 operands count — the
     fused int8-KV decode kernel reads one per-(pos, head) scale row per
     code row, and the quantized conv kernels read their weight/act scale
-    arrays; skipping them made ``dispatch.est_hbm_bytes_total``
-    undercount int8 paths. The obs dispatch counters feed this next to
-    measured wall time so per-key arithmetic intensity is readable
-    straight off the metrics snapshot. Leaves without a shape/dtype
-    (None biases, plain Python scalars) are skipped."""
+    arrays; skipping them undercounts int8 paths. Leaves without a
+    shape/dtype (None biases, plain Python scalars) are skipped."""
     total = 0
     for a in _array_leaves(arrays):
         shape = getattr(a, "shape", None)

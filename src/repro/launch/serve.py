@@ -51,6 +51,7 @@ requests to bit-identical greedy tokens after a restart.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -281,13 +282,15 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
     batch = serve_batch(model, B, P, prompts)
     prefill, _ = _jitted(model)
     t_p = time.perf_counter()
-    logits, cache = prefill(params, batch)
-    # sync the compiled call's DIRECT outputs: an in-compiled-call failure
-    # (guest trap, device fault) is only guaranteed to surface as
-    # XlaRuntimeError on these arrays — a dependent computation enqueued
-    # before the error lands can read garbage instead (DESIGN.md §15).
-    # Free in practice: the argmax below syncs on logits anyway.
-    jax.block_until_ready((logits, cache))
+    with obs.span("serve.prefill.forward"):
+        logits, cache = prefill(params, batch)
+        # sync the compiled call's DIRECT outputs: an in-compiled-call
+        # failure (guest trap, device fault) is only guaranteed to surface
+        # as XlaRuntimeError on these arrays — a dependent computation
+        # enqueued before the error lands can read garbage instead
+        # (DESIGN.md §15). Free in practice: the argmax below syncs on
+        # logits anyway.
+        jax.block_until_ready((logits, cache))
     global _RETRACE_PENDING
     if _RETRACE_PENDING:
         # first prefill after a runtime demotion dropped the jit cache:
@@ -296,16 +299,18 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
         dt_ms = (time.perf_counter() - t_p) * 1000.0
         obs.REGISTRY.counter("runtime.retrace_ms").inc(dt_ms, arch=cfg.name)
         obs.info("serve", f"retrace after runtime demotion: {dt_ms:.0f}ms")
-    full = init_cache_concrete(model, B, cache_len)
-    defs = model.cache_defs(B, cache_len)
-    if cfg.kv_quant == "int8":
-        cache = quantize_cache_to_defs(cache, defs)
-    # metadata-only gauge (no device sync): the decode-cache footprint
-    # this request serves from
-    obs.REGISTRY.gauge("serve.kv_cache_bytes").set(
-        float(cache_nbytes(defs, cfg.param_dtype)), kind="served"
-    )
-    return logits, pad_cache_to_defs(cache, full, defs)
+    with obs.span("serve.prefill.cache"):
+        full = init_cache_concrete(model, B, cache_len)
+        defs = model.cache_defs(B, cache_len)
+        if cfg.kv_quant == "int8":
+            cache = quantize_cache_to_defs(cache, defs)
+        # metadata-only gauge (no device sync): the decode-cache footprint
+        # this request serves from
+        obs.REGISTRY.gauge("serve.kv_cache_bytes").set(
+            float(cache_nbytes(defs, cfg.param_dtype)), kind="served"
+        )
+        cache = pad_cache_to_defs(cache, full, defs)
+    return logits, cache
 
 
 def _screen_logits(logits, step: int):
@@ -357,21 +362,22 @@ def _generate_once(model, params, prompts, *, gen_len, cache_len,
     # false straggler or deadline events. The wall clock remains only
     # where an absolute timestamp is recorded (the heartbeat file).
     t_start = time.perf_counter()
+    # spans name every stretch of host time in the loop, so a profiler's
+    # device trace says which line the chip waited on (obs.names.SPANS)
     with obs.span("serve.prefill", arch=cfg.name):
         logits, cache = prefill_cache(
             model, params, prompts, cache_len=cache_len, gen_len=gen_len
         )
-    _, decode = _jitted(model)
-
-    bad = None
-    if nan_guard:
-        logits, bad = _screen_logits(logits, -1)
-    key = jax.random.key(seed)
-    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    # TTFT: prefill through the argmax that yields the first token
-    t_first = time.perf_counter() - t_start
-    reg.histogram("serve.prefill_s").observe(t_first, arch=cfg.name)
-    reg.histogram("serve.ttft_s").observe(t_first, arch=cfg.name)
+        _, decode = _jitted(model)
+        with obs.span("serve.prefill.sample"):
+            bad = None
+            if nan_guard:
+                logits, bad = _screen_logits(logits, -1)
+            key = jax.random.key(seed)
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        # TTFT: prefill through the argmax that yields the first token
+        t_first = time.perf_counter() - t_start
+        reg.histogram("serve.prefill_s").observe(t_first, arch=cfg.name)
     done = tok[:, 0] == eos
     if bad is not None:
         done = _quarantine(bad, done, -1, cfg.name)
@@ -380,54 +386,63 @@ def _generate_once(model, params, prompts, *, gen_len, cache_len,
     step_hist = reg.histogram("serve.decode_step_s")
     for i in range(gen_len - 1):
         t_step = time.perf_counter()
-        faults.sleep_point("slow_step", "serve")
         with obs.span("serve.decode_step", arch=cfg.name, step=P + i):
-            logits, cache = decode(params, cache, tok, jnp.int32(P + i))
-            # direct-output sync: guarantees an in-compiled-call failure
-            # surfaces HERE as XlaRuntimeError instead of feeding garbage
-            # to the sampler (the loop is host-synchronous per step
-            # regardless — the sampled token feeds the next step)
-            jax.block_until_ready(logits)
-            bad = None
-            if nan_guard:
-                logits, bad = _screen_logits(logits, i)
-            if bad is not None:
-                done = _quarantine(bad, done, i, cfg.name)
-            if temperature > 0:
-                key, sub = jax.random.split(key)
-                tok = jax.random.categorical(
-                    sub, logits[:, -1] / temperature
-                ).astype(jnp.int32)[:, None]
-            else:
-                tok = jnp.argmax(
-                    logits[:, -1], axis=-1
-                ).astype(jnp.int32)[:, None]
-            tok = jnp.where(done[:, None], eos, tok)  # finished: masked
-            out.append(tok)
-            done = done | (tok[:, 0] == eos)
-        dt_step = time.perf_counter() - t_step
-        step_hist.observe(dt_step, arch=cfg.name)
-        # clean-call credit toward demoted rungs' probation cooldowns —
-        # jitted decode never re-dispatches, so loop steps are the clock
-        HEALTH.tick()
-        if watchdog is not None:
-            watchdog.observe(P + i, dt_step)
-        if run_dir is not None:
-            beat(run_dir, host_id)
-        if (
-            deadline_s is not None
-            and time.perf_counter() - t_start > deadline_s
-        ):
-            # deadline: truncate the request — remaining positions pad
-            # with eos and every slot is marked recyclable
-            HEALTH.record(
-                "serve/generate", "deadline_exceeded", "truncate",
-                detail=f"{len(out)}/{gen_len} tokens in {deadline_s}s",
-            )
-            reg.counter("serve.deadline_exceeded").inc(1.0, arch=cfg.name)
-            out.append(jnp.full((B, gen_len - len(out)), eos, jnp.int32))
-            done = jnp.ones_like(done)
-            break
+            faults.sleep_point("slow_step", "serve")
+            with obs.span("serve.decode.dispatch"):
+                logits, cache = decode(params, cache, tok, jnp.int32(P + i))
+            with obs.span("serve.decode.wait"):
+                # direct-output sync: guarantees an in-compiled-call
+                # failure surfaces HERE as XlaRuntimeError instead of
+                # feeding garbage to the sampler (the loop is
+                # host-synchronous per step regardless — the sampled
+                # token feeds the next step)
+                jax.block_until_ready(logits)
+            with obs.span("serve.decode.screen"):
+                bad = None
+                if nan_guard:
+                    logits, bad = _screen_logits(logits, i)
+                if bad is not None:
+                    done = _quarantine(bad, done, i, cfg.name)
+            with obs.span("serve.decode.sample"):
+                if temperature > 0:
+                    key, sub = jax.random.split(key)
+                    tok = jax.random.categorical(
+                        sub, logits[:, -1] / temperature
+                    ).astype(jnp.int32)[:, None]
+                else:
+                    tok = jnp.argmax(
+                        logits[:, -1], axis=-1
+                    ).astype(jnp.int32)[:, None]
+                tok = jnp.where(done[:, None], eos, tok)  # finished: masked
+                out.append(tok)
+                done = done | (tok[:, 0] == eos)
+            dt_step = time.perf_counter() - t_step
+            step_hist.observe(dt_step, arch=cfg.name)
+            # clean-call credit toward demoted rungs' probation cooldowns —
+            # jitted decode never re-dispatches, so loop steps are the clock
+            HEALTH.tick()
+            if watchdog is not None:
+                watchdog.observe(P + i, dt_step)
+            if run_dir is not None:
+                beat(run_dir, host_id)
+            if (
+                deadline_s is not None
+                and time.perf_counter() - t_start > deadline_s
+            ):
+                # deadline: truncate the request — remaining positions pad
+                # with eos and every slot is marked recyclable
+                HEALTH.record(
+                    "serve/generate", "deadline_exceeded", "truncate",
+                    detail=f"{len(out)}/{gen_len} tokens in {deadline_s}s",
+                )
+                reg.counter("serve.deadline_exceeded").inc(
+                    1.0, arch=cfg.name
+                )
+                out.append(
+                    jnp.full((B, gen_len - len(out)), eos, jnp.int32)
+                )
+                done = jnp.ones_like(done)
+                break
     n_done = int(done.sum())
     reg.counter("serve.tokens_generated").inc(
         float(B * gen_len), arch=cfg.name
@@ -675,11 +690,11 @@ def main():
     ap.add_argument("--run-dir", default=None,
                     help="heartbeat/watchdog directory for the decode "
                          "loop; obs artifacts (metrics.json [+ "
-                         "trace.json]) are written here at exit")
+                         "profile/]) are written here at exit")
     ap.add_argument("--trace", action="store_true",
-                    help="arm span tracing (same as REPRO_TRACE=1); "
-                         "export as Chrome/Perfetto trace.json under "
-                         "--run-dir")
+                    help="record a JAX profiler trace, the serve loop's "
+                         "spans with the device's ops, into "
+                         "<run-dir>/profile")
     ap.add_argument("--deadline-s", type=float, default=None,
                     help="per-request wall-clock budget; expiry truncates "
                          "the batch with eos padding")
@@ -691,10 +706,19 @@ def main():
                          "is what lets chaos CI prove a repromoted rung "
                          "reproduces the clean tokens)")
     args = ap.parse_args()
+    if args.trace and not args.run_dir:
+        ap.error("--trace writes its profile under --run-dir")
 
     compile_cache.enable()
-    if args.trace:
-        obs.enable()
+    if args.run_dir:
+        os.makedirs(args.run_dir, exist_ok=True)  # the journal writes first
+    # the profile is written when the block ends, however it ends
+    with (jax.profiler.trace(os.path.join(args.run_dir, "profile"))
+          if args.trace else contextlib.nullcontext()):
+        _serve(args)
+
+
+def _serve(args):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

@@ -12,6 +12,8 @@ over the DP axes (``--grad-compress``, multi-device meshes).
 from __future__ import annotations
 
 import argparse
+import contextlib
+import os
 import time
 from pathlib import Path
 
@@ -267,14 +269,19 @@ def main():
     ap.add_argument("--max-restarts", type=int, default=0,
                     help="auto-restart budget after crashes")
     ap.add_argument("--trace", action="store_true",
-                    help="arm span tracing (same as REPRO_TRACE=1); "
-                         "export as Chrome/Perfetto trace.json under "
-                         "--run-dir")
+                    help="record a JAX profiler trace, the train loop's "
+                         "spans with the device's ops, into "
+                         "<run-dir>/profile")
     args = ap.parse_args()
 
     compile_cache.enable()
-    if args.trace:
-        obs.enable()
+    # the profile is written when the block ends, however it ends
+    with (jax.profiler.trace(os.path.join(args.run_dir, "profile"))
+          if args.trace else contextlib.nullcontext()):
+        _train(args)
+
+
+def _train(args):
     policy = RestartPolicy(max_restarts=args.max_restarts)
     while True:
         try:

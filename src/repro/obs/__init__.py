@@ -1,53 +1,40 @@
-"""``repro.obs`` — zero-dependency observability (DESIGN.md §12).
+"""``repro.obs`` — observability (DESIGN.md §12).
 
 Two surfaces behind frozen name vocabularies (``obs.names``):
 
-  * **tracing** (``obs.span`` / ``obs.traced`` / ``obs.instant``) into a
-    bounded ring buffer, exported as Chrome/Perfetto ``trace.json``.
-    Armed via ``REPRO_TRACE=1`` or ``--trace``; a single flag check and
-    a shared null context manager when off.
+  * **tracing** (``obs.span``): named ``jax.profiler.TraceAnnotation``
+    spans, live only while a JAX profiler records (``--trace`` on
+    serve/train writes ``<run_dir>/profile``); one ``is_enabled()`` check
+    and a shared null context manager otherwise.
   * **metrics** (``obs.metrics.REGISTRY``: counters, gauges,
     fixed-bucket latency histograms with deterministic quantiles, facts
     tables) snapshotted to ``metrics.json`` + a Prometheus text
     exposition under ``run_dir``.
 
 ``python -m repro.obs report <run_dir>`` renders the human summary from
-the persisted artifacts. The package is stdlib-only and imports nothing
-from the rest of ``repro`` — ``health`` (itself import-light) mirrors
-into it without cycles.
+the persisted artifacts. The package imports nothing from the rest of
+``repro``, and JAX only when a span is first asked for — ``health``
+(itself import-light) mirrors into it without cycles.
 """
 from __future__ import annotations
 
 from repro.obs import logs, metrics, names, trace
 from repro.obs.logs import debug, info, log, set_level, warn
-from repro.obs.metrics import (
-    BOUNDS,
-    REGISTRY,
-    DispatchLog,
-    dispatch_enabled,
-    enable_dispatch,
-    hist_quantile,
-)
-from repro.obs.trace import enable, enabled, instant, span, traced
+from repro.obs.metrics import BOUNDS, REGISTRY, DispatchLog, hist_quantile
+from repro.obs.trace import span
 
 __all__ = [
-    "BOUNDS", "REGISTRY", "DispatchLog", "debug", "dispatch_enabled",
-    "enable", "enable_dispatch", "enabled", "hist_quantile", "info",
-    "instant", "log", "logs", "metrics", "names", "set_level", "span",
-    "trace", "traced", "warn", "write_artifacts",
+    "BOUNDS", "REGISTRY", "DispatchLog", "debug", "hist_quantile", "info",
+    "log", "logs", "metrics", "names", "set_level", "span", "trace",
+    "warn", "write_artifacts",
 ]
 
 
 def write_artifacts(run_dir) -> list[str]:
-    """Persist the run's observability artifacts under ``run_dir``:
-    ``metrics.json`` + ``metrics.prom`` always, ``trace.json`` when
-    tracing is armed. Returns the written paths."""
+    """Persist the run's metrics under ``run_dir``: ``metrics.json`` +
+    ``metrics.prom``. Returns the written paths."""
     import os
 
     paths = [REGISTRY.write(run_dir)]
     paths.append(os.path.join(os.fspath(run_dir), "metrics.prom"))
-    if trace.enabled():
-        paths.append(trace.export(
-            os.path.join(os.fspath(run_dir), "trace.json")
-        ))
     return paths

@@ -1,9 +1,9 @@
 """CLI: ``python -m repro.obs report <run_dir>``.
 
 Renders the human summary of a finished run from its persisted
-observability artifacts (``metrics.json`` [+ ``trace.json``]) — the
-same ``[serve]`` / ``[train]`` lines the live drivers print, now
-reconstructable offline.
+observability artifacts (``metrics.json`` [+ the ``profile/`` of a
+``--trace`` run]) — the same ``[serve]`` / ``[train]`` lines the live
+drivers print, now reconstructable offline.
 """
 from __future__ import annotations
 

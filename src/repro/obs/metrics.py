@@ -32,11 +32,6 @@ import threading
 
 from repro.obs import names
 
-#: arm flag for the dispatch-layer instrumentation in ``ops._ladder``
-#: (separate from tracing: benchmarks want the per-key dispatch counters
-#: for provenance without paying for span buffering)
-DISPATCH_ON: bool = os.environ.get("REPRO_METRICS", "") not in ("", "0")
-
 #: snapshot schema version (bump on incompatible layout changes)
 SCHEMA = 1
 
@@ -47,16 +42,6 @@ BOUNDS: tuple[float, ...] = tuple(
 )
 
 _LOCK = threading.RLock()
-
-
-def enable_dispatch(on: bool = True) -> None:
-    """Arm the ``ops._ladder`` dispatch counters for this process."""
-    global DISPATCH_ON
-    DISPATCH_ON = bool(on)
-
-
-def dispatch_enabled() -> bool:
-    return DISPATCH_ON
 
 
 def _lkey(labels: dict) -> tuple:

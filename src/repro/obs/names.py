@@ -21,8 +21,6 @@ from __future__ import annotations
 METRICS = frozenset({
     # kernel dispatch (ops._ladder) — per autotune shape key
     "dispatch.calls",
-    "dispatch.seconds_total",
-    "dispatch.est_hbm_bytes_total",
     "dispatch.log_calls",          # named DispatchLog mirrors (key hits)
     # autotune searches
     "autotune.searches",
@@ -42,7 +40,6 @@ METRICS = frozenset({
     "serve.stragglers",
     "serve.tokens_generated",
     "serve.prefill_s",
-    "serve.ttft_s",
     "serve.decode_step_s",
     "serve.request_s",
     "serve.slots_total",
@@ -67,14 +64,23 @@ METRICS = frozenset({
     "dispatch.quant_fallback",
 })
 
-#: trace span / instant names (obs.span / obs.traced / obs.instant)
+#: span names (obs.span); the serve loop's nest as the code runs them:
+#: serve.generate > serve.prefill > serve.prefill.{forward,cache,sample},
+#: then per token serve.decode_step > serve.decode.{dispatch,wait,screen,
+#: sample} (the benchmark's serve-loop metrics read these)
 SPANS = frozenset({
-    "kernel.dispatch",
     "autotune.search",
     "autotune.candidate",
     "serve.generate",
     "serve.prefill",
+    "serve.prefill.forward",
+    "serve.prefill.cache",
+    "serve.prefill.sample",
     "serve.decode_step",
+    "serve.decode.dispatch",
+    "serve.decode.wait",
+    "serve.decode.screen",
+    "serve.decode.sample",
     "serve.quantize",
     "train.step",
     "train.ckpt_save",

@@ -1,7 +1,7 @@
 """Render the human run summary from persisted obs artifacts.
 
 ``python -m repro.obs report <run_dir>`` reads ``metrics.json`` (and
-notes ``trace.json`` when present) and reprints the ``[serve]`` /
+notes the ``--trace`` profile directory when present) and reprints the ``[serve]`` /
 ``[train]`` summary the live drivers emit — same line formats, so the
 summary of a finished run is reconstructable from artifacts alone
 (the acceptance contract of DESIGN.md §12). Sections render only when
@@ -93,7 +93,7 @@ def _serve_lines(snap: dict) -> list[str]:
             f"[serve] health: site={lb.get('site')} "
             f"reason={lb.get('reason')} action={lb.get('action')}{extra}"
         )
-    for ln in _hist_lines(snap, "serve.ttft_s", "ttft"):
+    for ln in _hist_lines(snap, "serve.prefill_s", "ttft"):
         lines.append(f"[serve] {ln}")
     for ln in _hist_lines(snap, "serve.decode_step_s", "decode-step"):
         lines.append(f"[serve] {ln}")
@@ -101,32 +101,18 @@ def _serve_lines(snap: dict) -> list[str]:
 
 
 def _dispatch_lines(snap: dict, top: int = 10) -> list[str]:
-    """Per-autotune-key dispatch table, heaviest wall time first."""
-    calls = {
-        tuple(sorted(s["labels"].items())): s["value"]
-        for s in _counter(snap, "dispatch.calls")
-    }
-    secs = {
-        tuple(sorted(s["labels"].items())): s["value"]
-        for s in _counter(snap, "dispatch.seconds_total")
-    }
-    hbm = {
-        tuple(sorted(s["labels"].items())): s["value"]
-        for s in _counter(snap, "dispatch.est_hbm_bytes_total")
-    }
-    rows = sorted(secs.items(), key=lambda kv: -kv[1])[:top]
+    """Per-autotune-key dispatch table, most calls first. Dispatch runs
+    at trace time, so a jitted loop counts once per shape."""
+    series = _counter(snap, "dispatch.calls")
+    rows = sorted(series, key=lambda s: -s["value"])[:top]
     lines = []
-    for lkey, total in rows:
-        lb = dict(lkey)
-        extra = ""
-        if lkey in hbm:
-            extra = f" est-hbm={int(hbm[lkey]):,}B"
+    for s in rows:
+        lb = s["labels"]
         lines.append(
             f"[dispatch] key={lb.get('key')} rung={lb.get('rung')} "
-            f"calls={int(calls.get(lkey, 0))} "
-            f"total={_fmt_s(total)}{extra}"
+            f"calls={int(s['value'])}"
         )
-    dropped = len(secs) - len(rows)
+    dropped = len(series) - len(rows)
     if dropped > 0:
         lines.append(f"[dispatch] ({dropped} more key(s) not shown)")
     return lines
@@ -161,13 +147,14 @@ def render(run_dir) -> list[str]:
         raise FileNotFoundError(f"no metrics.json under {run_dir!r}")
     snap = Registry.load(path)
     lines = [f"[obs] report for {run_dir} (schema {snap['schema']})"]
-    trace_path = os.path.join(run_dir, "trace.json")
-    if os.path.exists(trace_path):
-        import json
+    prof = os.path.join(run_dir, "profile")
+    if os.path.isdir(prof):
+        import glob
 
-        with open(trace_path) as f:
-            n = len(json.load(f).get("traceEvents", []))
-        lines.append(f"[obs] trace.json: {n} events (Perfetto-loadable)")
+        n = len(glob.glob(os.path.join(prof, "**", "*.xplane.pb"),
+                          recursive=True))
+        lines.append(f"[obs] profile: {n} .xplane.pb under {prof} "
+                     f"(TensorBoard / Perfetto)")
     lines += _serve_lines(snap)
     lines += _dispatch_lines(snap)
     lines += _train_lines(snap)

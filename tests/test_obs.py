@@ -1,7 +1,7 @@
 """Observability layer (`repro.obs`, DESIGN.md §12): deterministic
-histogram quantiles, snapshot round-trips, Chrome-trace validity, the
-near-zero disabled path, and the instrumented serve/train/dispatch
-surfaces.
+histogram quantiles, snapshot round-trips, spans on the JAX profiler's
+timeline, the near-zero disabled path, and the instrumented
+serve/train/dispatch surfaces.
 
 The contracts under test:
 
@@ -9,13 +9,18 @@ The contracts under test:
     machines aggregating the same snapshot can never disagree;
   * metric and span names come from the frozen ``obs.names``
     vocabularies (the lint enforces literals, the registry everything);
-  * with tracing AND dispatch metrics off, instrumented sites do one
-    flag check — no allocation, no clock read, no registry writes;
-  * `HEALTH.record` mirrors into the ``health.events`` counter and (when
-    armed) a trace instant, so demotions land on the kernel timeline;
+  * with no profiler recording, a span site does one ``is_enabled()``
+    check and gets a shared null context manager — nothing recorded;
+  * while a profiler records, spans land in its ``/host:CPU`` plane
+    under their bare names, nested as the code runs them — the serve
+    loop's spans cover every decode step and its four parts;
+  * `HEALTH.record` mirrors into the ``health.events`` counter and, while
+    a profiler records, a ``health.event`` span on the timeline;
   * serve/train smokes populate the metric names the report CLI and the
     CI obs job assert on.
 """
+import contextlib
+import glob
 import inspect
 import json
 import time
@@ -34,20 +39,47 @@ from repro.obs.metrics import BOUNDS, REGISTRY, hist_quantile
 
 @pytest.fixture(autouse=True)
 def _clean_obs():
-    """Each test sees a fresh registry, an empty trace ring, and both
-    arm flags off; process-global state is restored afterwards."""
+    """Each test sees a fresh registry and no profiler recording;
+    process-global state is restored afterwards."""
     REGISTRY.reset()
-    obs_trace.clear()
-    was_tracing = obs_trace.TRACING
-    was_dispatch = obs_metrics.DISPATCH_ON
-    obs_trace.disable()
-    obs_metrics.enable_dispatch(False)
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     yield
     REGISTRY.reset()
-    obs_trace.clear()
-    obs_trace.enable(was_tracing)
-    obs_metrics.enable_dispatch(was_dispatch)
     HEALTH.reset()
+
+
+@contextlib.contextmanager
+def _profiled(log_dir):
+    """Record a JAX profile into ``log_dir``, host spans only (no Python
+    function tracer), as the benchmark's traced runs do."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_spans(log_dir, prefixes=("serve.", "health.", "autotune.")):
+    """``(name, start_ns, end_ns, stats)`` of the program's spans in the
+    ``/host:CPU`` plane of the profile under ``log_dir``, by start."""
+    from jax.profiler import ProfileData
+
+    files = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    assert len(files) == 1, files
+    data = ProfileData.from_file(files[0])
+    out = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefixes):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
 
 
 # -- histogram quantile determinism -------------------------------------------
@@ -109,14 +141,12 @@ def test_registry_rejects_kind_collisions():
         REGISTRY.gauge("serve.requests")
 
 
-def test_span_rejects_unknown_names_when_armed():
-    obs_trace.enable()
-    with pytest.raises(ValueError, match="unknown span name"):
-        obs.span("serve.not_a_span")
-    # traced() validates at decoration time even while disarmed
-    obs_trace.disable()
-    with pytest.raises(ValueError, match="unknown span name"):
-        obs.traced("nope.nope")
+def test_span_rejects_unknown_names_when_armed(tmp_path):
+    with _profiled(tmp_path):
+        with pytest.raises(ValueError, match="unknown span name"):
+            obs.span("serve.not_a_span")
+    # off, the name is not looked at: the null span costs one check
+    assert obs.span("serve.not_a_span") is obs_trace._NULL
 
 
 # -- snapshot round-trip ------------------------------------------------------
@@ -124,7 +154,7 @@ def test_span_rejects_unknown_names_when_armed():
 def test_snapshot_write_load_roundtrip(tmp_path):
     REGISTRY.counter("dispatch.calls").inc(3.0, site="conv1d", rung="pallas")
     REGISTRY.gauge("serve.kv_cache_bytes").set(1024.0, kind="served")
-    h = REGISTRY.histogram("serve.ttft_s")
+    h = REGISTRY.histogram("serve.prefill_s")
     h.observe(0.12, arch="whisper-medium")
     REGISTRY.facts("serve.run").set("arch", "whisper-medium")
 
@@ -137,7 +167,7 @@ def test_snapshot_write_load_roundtrip(tmp_path):
                   "value": 3.0}]
     g = snap["gauges"]["serve.kv_cache_bytes"][0]
     assert g["labels"] == {"kind": "served"} and g["value"] == 1024.0
-    hs = snap["histograms"]["serve.ttft_s"][0]
+    hs = snap["histograms"]["serve.prefill_s"][0]
     assert hs["count"] == 1 and hs["sum"] == pytest.approx(0.12)
     # the quantile recomputed from the LOADED buckets matches the live one
     assert hist_quantile(snap["bounds"], hs["buckets"], 0.5) == pytest.approx(
@@ -148,57 +178,55 @@ def test_snapshot_write_load_roundtrip(tmp_path):
 
 def test_prometheus_exposition_shape(tmp_path):
     REGISTRY.counter("serve.requests").inc(2.0, arch="a")
-    REGISTRY.histogram("serve.ttft_s").observe(0.0015, arch="a")
+    REGISTRY.histogram("serve.prefill_s").observe(0.0015, arch="a")
     text = REGISTRY.to_prometheus()
     assert '# TYPE repro_serve_requests counter' in text
     assert 'repro_serve_requests{arch="a"} 2' in text
-    assert '# TYPE repro_serve_ttft_s histogram' in text
+    assert '# TYPE repro_serve_prefill_s histogram' in text
     # cumulative buckets end at +Inf == _count
-    assert 'repro_serve_ttft_s_bucket{arch="a",le="+Inf"} 1' in text
-    assert 'repro_serve_ttft_s_count{arch="a"} 1' in text
+    assert 'repro_serve_prefill_s_bucket{arch="a",le="+Inf"} 1' in text
+    assert 'repro_serve_prefill_s_count{arch="a"} 1' in text
 
 
 # -- tracing ------------------------------------------------------------------
 
 def test_trace_spans_nest_and_export_valid_chrome_json(tmp_path):
-    obs_trace.enable()
-    with obs.span("serve.generate", arch="a"):
-        with obs.span("serve.prefill", arch="a"):
-            time.sleep(0.002)
-        obs.instant("health.event", site="conv1d", reason="pallas_error",
-                    action="demote:pallas->jax")
-    path = obs_trace.export(tmp_path / "trace.json")
-    doc = json.load(open(path))
-    evs = doc["traceEvents"]
-    assert [e["name"] for e in evs] == [
-        "serve.prefill", "health.event", "serve.generate",
-    ]  # spans record on EXIT: inner closes first
-    prefill, inst, gen = evs
-    assert prefill["ph"] == "X" and gen["ph"] == "X" and inst["ph"] == "i"
-    # the outer span must fully contain the inner one on the timeline
-    assert gen["ts"] <= prefill["ts"]
-    assert gen["ts"] + gen["dur"] >= prefill["ts"] + prefill["dur"]
-    assert prefill["dur"] >= 2_000  # slept 2 ms; µs units
-    assert inst["args"]["reason"] == "pallas_error"
-    for e in evs:
-        assert {"name", "ph", "pid", "tid", "ts"} <= set(e)
+    """While a profiler records, spans land in its ``/host:CPU`` plane
+    (the profile TensorBoard and Perfetto load) under their bare names,
+    attributes as stats, the outer span containing the inner one."""
+    with _profiled(tmp_path):
+        with obs.span("serve.generate", arch="a"):
+            with obs.span("serve.prefill", arch="a", step=3):
+                time.sleep(0.002)
+    spans = _host_spans(tmp_path)
+    assert [name for name, *_ in spans] == ["serve.generate", "serve.prefill"]
+    (_, g0, g1, gstats), (_, p0, p1, pstats) = spans
+    assert g0 <= p0 and p1 <= g1
+    assert p1 - p0 >= 2_000_000  # slept 2 ms; ns units
+    assert gstats == {"arch": "a"}
+    assert pstats == {"arch": "a", "step": 3}
 
 
-def test_disabled_span_is_shared_null_and_records_nothing():
+def test_disabled_span_is_shared_null_and_records_nothing(tmp_path):
     s1 = obs.span("serve.generate")
-    s2 = obs.span("kernel.dispatch", site="conv1d")
-    assert s1 is s2  # one shared null CM — no per-call allocation
+    s2 = obs.span("serve.decode.sample", site="conv1d")
+    assert s1 is s2 is obs_trace._NULL  # no per-call allocation
     with s1:
         pass
-    obs.instant("health.event", site="conv1d", reason="pallas_error",
-                action="demote")
-    assert obs_trace.events() == []
+    # a null span entered before the profiler starts stays null inside
+    # it: only spans opened while it records reach the timeline
+    with _profiled(tmp_path):
+        with s2:
+            pass
+        with obs.span("serve.prefill"):
+            pass
+    assert [name for name, *_ in _host_spans(tmp_path)] == ["serve.prefill"]
 
 
 def test_disabled_span_overhead_is_flag_check_cheap():
-    """The disabled path is a single module-global flag check; 200k calls
-    must land well under any instrumented site's real work (generous
-    bound so CI jitter can't flake it)."""
+    """The disabled path is one ``is_enabled()`` check; 200k calls must
+    land well under any instrumented site's real work (generous bound so
+    CI jitter can't flake it)."""
     t0 = time.perf_counter()
     for _ in range(200_000):
         obs.span("serve.decode_step")
@@ -208,17 +236,19 @@ def test_disabled_span_overhead_is_flag_check_cheap():
 
 # -- health mirror ------------------------------------------------------------
 
-def test_health_record_mirrors_counter_and_trace_instant():
-    obs_trace.enable()
+def test_health_record_mirrors_counter_and_trace_instant(tmp_path):
     HEALTH.reset()
-    HEALTH.record("conv1d", "pallas_error", "demote:pallas->jax", "boom")
+    with _profiled(tmp_path):
+        HEALTH.record("conv1d", "pallas_error", "demote:pallas->jax", "boom")
     HEALTH.record("conv1d", "pallas_error", "demote:pallas->jax")
     c = REGISTRY.counter("health.events")
     assert c.value(site="conv1d", reason="pallas_error",
                    action="demote:pallas->jax") == 2.0
-    insts = [e for e in obs_trace.events() if e["name"] == "health.event"]
-    assert len(insts) == 2
-    assert insts[0]["args"] == {
+    # the recorded event is a span on the timeline; the one after the
+    # profiler stopped is counted but not traced
+    spans = [e for e in _host_spans(tmp_path) if e[0] == "health.event"]
+    assert len(spans) == 1
+    assert spans[0][3] == {
         "site": "conv1d", "reason": "pallas_error",
         "action": "demote:pallas->jax",
     }
@@ -254,21 +284,14 @@ def test_named_dispatch_log_mirrors_into_registry():
 # -- kernel dispatch instrumentation ------------------------------------------
 
 def test_ladder_records_dispatch_metrics_and_spans(rng):
+    """Every dispatch counts under its autotune key, rung and site — at
+    trace time, so a jitted call counts once, not once per call."""
     from repro.kernels import ops
 
     x = jnp.asarray(rng.normal(size=(1, 32, 8)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(3, 8, 16)).astype(np.float32))
 
-    # fully off: the ladder takes the early-return path — nothing recorded
-    y_off = ops.conv1d(x, w, interpret=True)
-    assert REGISTRY.snapshot()["counters"] == {}
-    assert obs_trace.events() == []
-
-    obs_metrics.enable_dispatch()
-    obs_trace.enable()
-    y_on = ops.conv1d(x, w, interpret=True)
-    np.testing.assert_allclose(y_off, y_on)  # instrumentation is inert
-
+    y = ops.conv1d(x, w, interpret=True)
     calls = REGISTRY.counter("dispatch.calls").series()
     assert len(calls) == 1
     labels, n = calls[0]
@@ -276,22 +299,19 @@ def test_ladder_records_dispatch_metrics_and_spans(rng):
     assert labels["site"] == "conv1d"
     assert labels["key"].startswith("conv1d|B1|L32|Cin8|Cout16|K3|")
     assert labels["rung"] in ("pallas", "jax", "ref")
-    secs = REGISTRY.counter("dispatch.seconds_total").value(**labels)
-    assert secs > 0.0
-    hbm = REGISTRY.counter("dispatch.est_hbm_bytes_total").value(**labels)
-    # x + w + out, f32: (1*32*8 + 3*8*16 + 1*30*16) * 4
-    assert hbm == (32 * 8 + 3 * 8 * 16 + 30 * 16) * 4.0
-    spans = [e for e in obs_trace.events() if e["name"] == "kernel.dispatch"]
-    assert spans and spans[0]["args"]["site"] == "conv1d"
-    assert spans[0]["args"]["rung"] == labels["rung"]
+
+    jitted = jax.jit(lambda x, w: ops.conv1d(x, w, interpret=True))
+    for _ in range(3):
+        np.testing.assert_allclose(jitted(x, w), y, rtol=1e-5, atol=1e-5)
+    assert REGISTRY.counter("dispatch.calls").value(**labels) == 2.0
 
 
 # -- serve smoke --------------------------------------------------------------
 
-def test_serve_generate_populates_metrics(rng):
+def _smoke_qwen(rng):
+    """qwen3-1.7b at smoke size, its params, and a (2, 16) prompt batch."""
     from repro.configs import get_config, smoke_config
     from repro.distributed.sharding import Runtime
-    from repro.launch.serve import generate
     from repro.models import build_model
 
     cfg = smoke_config(get_config("qwen3-1.7b"))
@@ -300,13 +320,19 @@ def test_serve_generate_populates_metrics(rng):
     prompts = jnp.asarray(
         rng.integers(2, cfg.vocab_size, size=(2, 16)), jnp.int32
     )
+    return model, params, prompts
+
+
+def test_serve_generate_populates_metrics(rng):
+    from repro.launch.serve import generate
+
+    model, params, prompts = _smoke_qwen(rng)
     toks, done = generate(model, params, prompts, gen_len=4, cache_len=24)
     assert toks.shape == (2, 4)
 
-    arch = cfg.name
+    arch = model.cfg.name
     assert REGISTRY.counter("serve.requests").value(arch=arch) == 1.0
     assert REGISTRY.counter("serve.tokens_generated").value(arch=arch) == 8.0
-    assert REGISTRY.histogram("serve.ttft_s").count(arch=arch) == 1
     assert REGISTRY.histogram("serve.prefill_s").count(arch=arch) == 1
     # the first token falls out of prefill; gen_len-1 decode steps follow
     assert REGISTRY.histogram("serve.decode_step_s").count(arch=arch) == 3
@@ -316,6 +342,54 @@ def test_serve_generate_populates_metrics(rng):
     assert occ is not None and 0.0 <= occ <= 1.0
     kv = REGISTRY.gauge("serve.kv_cache_bytes").value(kind="served")
     assert kv is not None and kv > 0
+
+
+PREFILL_PARTS = ("serve.prefill.forward", "serve.prefill.cache",
+                 "serve.prefill.sample")
+DECODE_PARTS = ("serve.decode.dispatch", "serve.decode.wait",
+                "serve.decode.screen", "serve.decode.sample")
+
+
+def test_serve_spans_cover_every_decode_step(rng, tmp_path):
+    """Under a profiler, each batch's serve.generate holds one
+    serve.prefill with its three parts in order, then gen_len - 1
+    serve.decode_step spans, each holding its four parts in order; with
+    no profiler recording, the loop's spans are the shared null span."""
+    from repro.launch.serve import generate
+
+    model, params, prompts = _smoke_qwen(rng)
+    P, G = prompts.shape[1], 4
+
+    def serve():
+        return generate(model, params, prompts, gen_len=G, cache_len=24)
+
+    toks = serve()[0]  # compiles outside the profile
+    assert obs.span("serve.decode_step") is obs_trace._NULL
+    with _profiled(tmp_path):
+        for _ in range(2):
+            np.testing.assert_array_equal(serve()[0], toks)
+    serve()  # after the profile: recorded nowhere
+
+    spans = _host_spans(tmp_path)
+
+    def inside(outer):
+        _, s0, s1, _ = outer
+        return [e for e in spans if e is not outer and s0 <= e[1]
+                and e[2] <= s1]
+
+    batches = [e for e in spans if e[0] == "serve.generate"]
+    assert len(batches) == 2
+    for batch in batches:
+        held = inside(batch)
+        top = [e for e in held
+               if not any(e in inside(o) for o in held if o is not e)]
+        assert [e[0] for e in top] == (
+            ["serve.prefill"] + ["serve.decode_step"] * (G - 1))
+        prefill, *steps = top
+        assert tuple(e[0] for e in inside(prefill)) == PREFILL_PARTS
+        assert [e[3]["step"] for e in steps] == list(range(P, P + G - 1))
+        for step in steps:
+            assert tuple(e[0] for e in inside(step)) == DECODE_PARTS
 
 
 def test_generate_uses_monotonic_clock():
@@ -362,10 +436,10 @@ def test_train_loop_populates_metrics_and_artifacts(tmp_path):
     )
     tps = REGISTRY.gauge("train.tokens_per_s").value(arch=arch)
     assert tps is not None and tps > 0
-    # artifacts persisted under run_dir (no trace.json: tracing is off)
+    # artifacts persisted under run_dir (no profile: --trace is off)
     snap = json.load(open(tmp_path / "metrics.json"))
     assert "train.step_s" in snap["histograms"]
-    assert not (tmp_path / "trace.json").exists()
+    assert not (tmp_path / "profile").exists()
 
 
 # -- report CLI ---------------------------------------------------------------
@@ -421,6 +495,61 @@ def test_report_rebuilds_serve_summary_from_artifacts(tmp_path, capsys):
     )
     assert proc.returncode == 0, proc.stderr
     assert "kv-cache bytes: 1000" in proc.stdout
+
+
+def test_report_notes_the_trace_profile(tmp_path):
+    """A ``--trace`` run's profile sits in ``<run_dir>/profile`` beside
+    the metrics, which ``write_artifacts`` alone writes; the report notes
+    the profile and lists dispatch keys by calls, the most first."""
+    from repro.obs import report
+
+    prof = str(tmp_path / "profile")
+    with jax.profiler.trace(prof):
+        with obs.span("serve.generate", arch="a"):
+            pass
+    REGISTRY.counter("dispatch.calls").inc(3.0, site="pool1d", key="k1",
+                                           rung="pallas")
+    REGISTRY.counter("dispatch.calls").inc(7.0, site="conv1d", key="k2",
+                                           rung="jax")
+    paths = obs.write_artifacts(tmp_path)
+    assert paths == [str(tmp_path / "metrics.json"),
+                     str(tmp_path / "metrics.prom")]
+    assert [name for name, *_ in _host_spans(prof)] == ["serve.generate"]
+
+    lines = report.render(tmp_path)
+    assert f"[obs] profile: 1 .xplane.pb under {prof}" in "\n".join(lines)
+    assert [ln for ln in lines if ln.startswith("[dispatch]")] == [
+        "[dispatch] key=k2 rung=jax calls=7",
+        "[dispatch] key=k1 rung=pallas calls=3",
+    ]
+
+
+@pytest.mark.parametrize("launcher", ["serve", "train"])
+def test_trace_profile_is_written_when_the_run_fails(
+    tmp_path, monkeypatch, launcher
+):
+    """``--trace`` ends its profile however the run ends: a serve or
+    train run that raises still leaves the profiler stopped and its
+    spans in ``<run_dir>/profile``."""
+    import importlib
+    import sys
+
+    mod = importlib.import_module(f"repro.launch.{launcher}")
+
+    def fail(args):
+        with obs.span("serve.generate", arch="a"):
+            pass
+        raise RuntimeError("run failed")
+
+    monkeypatch.setattr(mod, f"_{launcher}", fail)
+    monkeypatch.setattr(mod.compile_cache, "enable", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        launcher, "--trace", "--run-dir", str(tmp_path)])
+    with pytest.raises(RuntimeError, match="run failed"):
+        mod.main()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    names = [name for name, *_ in _host_spans(tmp_path / "profile")]
+    assert names == ["serve.generate"]
 
 
 def _cli_env():
