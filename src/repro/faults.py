@@ -3,7 +3,7 @@
 The graceful-degradation machinery (DESIGN.md §10) is only trustworthy if
 every failure class it claims to survive can be *produced on demand*. This
 module is the single switchboard: production code calls tiny hooks at its
-failure points (``maybe_fail``, ``sleep_point``, ``corrupt_array``,
+failure points (``maybe_fail``, ``sleep_point``, ``poison_rows``,
 ``corrupt_scale``, ``take``) which are no-ops unless an injection is armed
 — either programmatically::
 
@@ -30,8 +30,8 @@ Fault kinds (each consumed by a specific hook site):
                         failure surfaces at RUN time to serve/train's
                         runtime catch layer (DESIGN.md §15)
   jax_runtime           ops dispatch ladder, compiled-JAX rung — raises
-  nan_activations       ``corrupt_array``: poisons a tensor with NaN;
-                        ``corrupt_rows``: poisons one batch row (slot);
+  nan_activations       ``poison_rows``: NaNs every batch row of the serve
+                        logits, or one row (slot);
                         ``guest_trap``: a kernel emitting NaN at run time
   quant_scale_zero      ``corrupt_scale``: calibration emits a 0.0 scale
   quant_scale_nan       ``corrupt_scale``: calibration emits a NaN scale
@@ -303,17 +303,22 @@ def guest_trap(site: str, rung: str, key: str | None, out):
     return out
 
 
-def corrupt_rows(kind: str, site_prefix: str, x):
-    """Per-row (slot) poison: an injection armed at ``{site_prefix}.{i}``
-    NaNs batch row ``i`` of ``x``; armed at ``site_prefix`` itself it
-    poisons every row. The serve decode loop calls this on the logits so
-    chaos runs can poison ONE request slot without touching siblings."""
-    rows = [i for i in range(x.shape[0]) if take(kind, f"{site_prefix}.{i}")]
-    if not rows:
-        return x
-    import jax.numpy as jnp
-
-    return x.at[jnp.asarray(rows)].set(jnp.nan)
+def poison_rows(kind: str, site: str, row_prefix: str, n: int):
+    """Host-side NaN-poison decision for ``n`` batch rows (slots): an
+    injection armed at ``site`` poisons every row, one armed at
+    ``{row_prefix}.{i}`` poisons row ``i`` (armed at ``row_prefix`` itself,
+    every row, one firing each). Returns the (n,) bool mask, or None when
+    nothing fires. The serve loop hands the mask to the compiled decode
+    step, which NaNs those rows of the logits, so chaos runs can poison ONE
+    request slot without touching siblings; with nothing of ``kind`` armed
+    this is one scan of an empty list."""
+    if active(kind) is None:
+        return None
+    every = take(kind, site)
+    rows = np.array([take(kind, f"{row_prefix}.{i}") for i in range(n)])
+    if not (every or rows.any()):
+        return None
+    return rows | every
 
 
 def sleep_point(kind: str, site: str | None = None) -> float:
@@ -324,16 +329,6 @@ def sleep_point(kind: str, site: str | None = None) -> float:
         time.sleep(inj.delay_s)
         return inj.delay_s
     return 0.0
-
-
-def corrupt_array(kind: str, site: str | None, x):
-    """Poison a tensor with NaN when armed (``nan_activations``). Imports
-    jax lazily so this module stays importable anywhere."""
-    if take(kind, site):
-        import jax.numpy as jnp
-
-        return jnp.full_like(x, jnp.nan)
-    return x
 
 
 def corrupt_scale(site: str, scale):

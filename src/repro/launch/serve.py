@@ -288,8 +288,8 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
         # failure (guest trap, device fault) is only guaranteed to surface
         # as XlaRuntimeError on these arrays — a dependent computation
         # enqueued before the error lands can read garbage instead
-        # (DESIGN.md §15). Free in practice: the argmax below syncs on
-        # logits anyway.
+        # (DESIGN.md §15). Free in practice: the first token's screen
+        # syncs on logits anyway.
         jax.block_until_ready((logits, cache))
     global _RETRACE_PENDING
     if _RETRACE_PENDING:
@@ -313,48 +313,120 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
     return logits, cache
 
 
-def _screen_logits(logits, step: int):
-    """Per-step numeric guard with slot-level blast radius (DESIGN.md
-    §15): NaN/Inf logits would silently argmax to token 0 and poison the
-    continuation. Every slot bad → fail fast, the retry wrapper re-runs
+def _sample(logits, done, temperature, key, poison, *, eos: int,
+            nan_guard: bool):
+    """The per-token screen, choice and masking, traced into a compiled
+    program (the fused decode step; alone, the prefill's first token).
+
+    ``bad`` (B,) marks slots whose logits are non-finite (NaN/Inf would
+    silently argmax to token 0 and poison the continuation); under
+    ``nan_guard`` they join ``done`` (quarantine), so their tokens pin to
+    eos like any finished slot. The token is the argmax, or with a ``key``
+    a categorical draw at ``temperature`` from a split of it (the key is
+    threaded through, split inside the program). Then, in this order:
+    finished slots pin to eos, and a slot that emits eos is done. ``done``
+    None means no slot has finished yet; ``poison`` (B,) NaNs those rows
+    of the logits first (fault injection, ``faults.poison_rows``).
+    Returns ``(tok (B, 1) int32, done, bad, key)``."""
+    B = logits.shape[0]
+    if done is None:
+        done = jnp.zeros((B,), bool)
+    rows = (B,) + (1,) * (logits.ndim - 1)
+    if poison is not None:
+        logits = jnp.where(poison.reshape(rows), jnp.nan, logits)
+    bad = None
+    if nan_guard:
+        bad = ~jnp.isfinite(logits).all(axis=tuple(range(1, logits.ndim)))
+        done = done | bad
+    last = logits[:, -1]
+    if key is None:
+        tok = jnp.argmax(last, axis=-1)
+    else:
+        key, sub = jax.random.split(key)
+        tok = jax.random.categorical(
+            sub, last / temperature.astype(last.dtype)
+        )
+    tok = jnp.where(done, eos, tok.astype(jnp.int32))[:, None]
+    return tok, done | (tok[:, 0] == eos), bad, key
+
+
+# the prefill's first token: the same sampler, one compiled program per
+# batch instead of an eager op per line
+_sample_first = jax.jit(_sample, static_argnames=("eos", "nan_guard"))
+
+# per-model fused decode step, held beside the decode callable it was
+# built from: a runtime demotion or probation drops _JITTED[model] (and a
+# test may replace it), and the next request must trace the fused step
+# again around the new callable
+_FUSED = weakref.WeakKeyDictionary()
+
+
+def _fused_decode(model):
+    """One compiled program per decode step: the decode callable in
+    ``_JITTED[model]``, then :func:`_sample` on its logits. Called as
+    ``step(params, cache, tok, pos, done, temperature, key, poison,
+    nan_guard=...)`` and returns ``(tok, done, bad, cache, key)``, no
+    logits. ``temperature`` is an operand (None with ``key`` None when
+    greedy), so a new temperature compiles nothing; ``poison`` is None
+    unless a fault fires, so the poisoned variant compiles only under
+    chaos runs."""
+    decode = _jitted(model)[1]
+    held = _FUSED.get(model)
+    if held is None or held[0] is not decode:
+        eos = model.cfg.eos_id
+
+        def step(params, cache, tok, pos, done, temperature, key, poison, *,
+                 nan_guard):
+            logits, cache = decode(params, cache, tok, pos)
+            tok, done, bad, key = _sample(logits, done, temperature, key,
+                                          poison, eos=eos,
+                                          nan_guard=nan_guard)
+            return tok, done, bad, cache, key
+
+        held = (decode, jax.jit(step, static_argnames="nan_guard"))
+        _FUSED[model] = held
+    return held[1]
+
+
+def _screen(bad, done, step: int, arch: str) -> None:
+    """Host half of the per-step numeric guard, with slot-level blast
+    radius (DESIGN.md §15): one read-back of the compiled step's (B,)
+    ``bad`` mask. Every slot bad → fail fast, the retry wrapper re-runs
     the request (the batch-wide failure class: a broken kernel). SOME
-    slots bad → return the (B,) bad mask so the decode loop quarantines
-    just those slots (eos-mask + recycle) — one poisoned request must not
-    kill its siblings. One reduction per step; the decode loop is already
-    host-synchronous (the sampled token feeds the next step), so this
-    adds no extra device sync."""
-    logits = faults.corrupt_array("nan_activations", "serve/logits", logits)
-    logits = faults.corrupt_rows("nan_activations", "serve/slot", logits)
-    ok = jnp.isfinite(logits).all(axis=tuple(range(1, logits.ndim)))
-    bad = ~ok
-    if not bool(bad.any()):
-        return logits, None
-    if bool(bad.all()):
+    slots bad → the step already quarantined them (eos-masked, marked
+    done, recyclable) — one poisoned request must not kill its siblings;
+    record the slots newly bad against ``done`` as it was before the step
+    (None: none was done)."""
+    bad = np.asarray(bad)
+    if not bad.any():
+        return
+    if bad.all():
         raise FloatingPointError(f"non-finite logits at decode step {step}")
-    return logits, bad
-
-
-def _quarantine(bad, done, step: int, arch: str):
-    """Fold a bad-slot mask into ``done``: the slots' remaining tokens pin
-    to eos (the decode loop's existing finished-slot masking) and they are
-    reported recyclable. Counts only newly-poisoned slots."""
-    newly = bad & ~done
+    newly = bad if done is None else bad & ~np.asarray(done)
     n = int(newly.sum())
     if n:
         HEALTH.record(
             "serve/slot", "nan_logits", "quarantine",
             detail=f"step {step}: {n} slot(s) "
-                   f"{np.flatnonzero(np.asarray(newly)).tolist()}",
+                   f"{np.flatnonzero(newly).tolist()}",
         )
         obs.REGISTRY.counter("serve.quarantined").inc(float(n), arch=arch)
-    return done | bad
+
+
+def _poison(nan_guard: bool, B: int):
+    """This step's injected NaN rows (decided on the host, as a fault
+    fires), as a device mask, or None: the clean path moves nothing."""
+    if not nan_guard:
+        return None
+    rows = faults.poison_rows("nan_activations", "serve/logits",
+                              "serve/slot", B)
+    return None if rows is None else jnp.asarray(rows)
 
 
 def _generate_once(model, params, prompts, *, gen_len, cache_len,
                    temperature, seed, deadline_s, nan_guard, run_dir,
                    host_id, watchdog):
     cfg = model.cfg
-    eos = jnp.int32(cfg.eos_id)
     B, P = prompts.shape
     reg = obs.REGISTRY
     # perf_counter, NOT the wall clock: steps/deadlines/watchdog measure
@@ -368,54 +440,51 @@ def _generate_once(model, params, prompts, *, gen_len, cache_len,
         logits, cache = prefill_cache(
             model, params, prompts, cache_len=cache_len, gen_len=gen_len
         )
-        _, decode = _jitted(model)
+        step = _fused_decode(model)
         with obs.span("serve.prefill.sample"):
-            bad = None
+            # the first token is greedy whatever the temperature
+            poison = _poison(nan_guard, B)
+            tok, done, bad, _ = _sample_first(
+                logits, None, None, None, poison, eos=cfg.eos_id,
+                nan_guard=nan_guard,
+            )
             if nan_guard:
-                logits, bad = _screen_logits(logits, -1)
-            key = jax.random.key(seed)
-            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        # TTFT: prefill through the argmax that yields the first token
+                _screen(bad, None, -1, cfg.name)
+            key = temp = None
+            if temperature > 0:
+                key = jax.random.key(seed)
+                temp = jnp.float32(temperature)
+        # TTFT: prefill through the sampler that yields the first token
         t_first = time.perf_counter() - t_start
         reg.histogram("serve.prefill_s").observe(t_first, arch=cfg.name)
-    done = tok[:, 0] == eos
-    if bad is not None:
-        done = _quarantine(bad, done, -1, cfg.name)
-        tok = jnp.where(done[:, None], eos, tok)
     out = [tok]
     step_hist = reg.histogram("serve.decode_step_s")
+    fused_steps = reg.counter("serve.decode.fused_steps")
     for i in range(gen_len - 1):
         t_step = time.perf_counter()
         with obs.span("serve.decode_step", arch=cfg.name, step=P + i):
             faults.sleep_point("slow_step", "serve")
             with obs.span("serve.decode.dispatch"):
-                logits, cache = decode(params, cache, tok, jnp.int32(P + i))
+                poison = _poison(nan_guard, B)
+                was_done = done
+                tok, done, bad, cache, key = step(
+                    params, cache, tok, jnp.int32(P + i), done, temp, key,
+                    poison, nan_guard=nan_guard,
+                )
             with obs.span("serve.decode.wait"):
                 # direct-output sync: guarantees an in-compiled-call
                 # failure surfaces HERE as XlaRuntimeError instead of
-                # feeding garbage to the sampler (the loop is
-                # host-synchronous per step regardless — the sampled
-                # token feeds the next step)
-                jax.block_until_ready(logits)
+                # feeding garbage to the next step
+                jax.block_until_ready((tok, done, bad, cache))
             with obs.span("serve.decode.screen"):
-                bad = None
                 if nan_guard:
-                    logits, bad = _screen_logits(logits, i)
-                if bad is not None:
-                    done = _quarantine(bad, done, i, cfg.name)
+                    _screen(bad, was_done, i, cfg.name)
             with obs.span("serve.decode.sample"):
-                if temperature > 0:
-                    key, sub = jax.random.split(key)
-                    tok = jax.random.categorical(
-                        sub, logits[:, -1] / temperature
-                    ).astype(jnp.int32)[:, None]
-                else:
-                    tok = jnp.argmax(
-                        logits[:, -1], axis=-1
-                    ).astype(jnp.int32)[:, None]
-                tok = jnp.where(done[:, None], eos, tok)  # finished: masked
                 out.append(tok)
-                done = done | (tok[:, 0] == eos)
+            if poison is None:
+                fused_steps.inc(1.0, arch=cfg.name)
+            else:
+                fused_steps.inc(1.0, arch=cfg.name, poisoned="true")
             dt_step = time.perf_counter() - t_step
             step_hist.observe(dt_step, arch=cfg.name)
             # clean-call credit toward demoted rungs' probation cooldowns —
@@ -439,7 +508,7 @@ def _generate_once(model, params, prompts, *, gen_len, cache_len,
                     1.0, arch=cfg.name
                 )
                 out.append(
-                    jnp.full((B, gen_len - len(out)), eos, jnp.int32)
+                    jnp.full((B, gen_len - len(out)), cfg.eos_id, jnp.int32)
                 )
                 done = jnp.ones_like(done)
                 break

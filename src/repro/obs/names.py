@@ -41,6 +41,7 @@ METRICS = frozenset({
     "serve.tokens_generated",
     "serve.prefill_s",
     "serve.decode_step_s",
+    "serve.decode.fused_steps",    # decode steps served by the fused program
     "serve.request_s",
     "serve.slots_total",
     "serve.slots_recyclable",

@@ -15,6 +15,7 @@ import pytest
 
 from repro.configs import get_config, smoke_config
 from repro.distributed.sharding import Runtime
+from repro.launch import serve
 from repro.launch.serve import (
     cache_nbytes,
     generate,
@@ -58,6 +59,97 @@ def test_generate_done_mask_slot_recycling():
     toks2, done2 = generate(model2, params, prompts, gen_len=6, cache_len=24)
     assert bool(done2[0])
     assert bool((toks2[0] == eos).all())
+
+
+# -- the fused decode step ---------------------------------------------------
+
+def _eager_loop(model, params, prompts, *, gen_len, cache_len, temperature,
+                seed):
+    """The decode loop as eager ops: the plain jitted decode, then argmax
+    (or a categorical draw from a split key), eos pinning and the done
+    update dispatched one by one from Python."""
+    logits, cache = serve.prefill_cache(model, params, prompts,
+                                        cache_len=cache_len, gen_len=gen_len)
+    _, decode = serve._jitted(model)
+    eos = jnp.int32(model.cfg.eos_id)
+    key = jax.random.key(seed)
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+    done = tok[:, 0] == eos
+    out = [tok]
+    P = prompts.shape[1]
+    for i in range(gen_len - 1):
+        logits, cache = decode(params, cache, tok, jnp.int32(P + i))
+        if temperature > 0:
+            key, sub = jax.random.split(key)
+            tok = jax.random.categorical(
+                sub, logits[:, -1] / temperature
+            ).astype(jnp.int32)[:, None]
+        else:
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+        tok = jnp.where(done[:, None], eos, tok)
+        out.append(tok)
+        done = done | (tok[:, 0] == eos)
+    return jnp.concatenate(out, axis=1), done
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_fused_step_matches_the_eager_loop(temperature):
+    """The screen, choice and masking inside the compiled decode step give
+    the eager loop's tokens and done mask bit for bit, greedy and sampled,
+    with a slot finishing mid-request (its tail pinned to eos); every
+    decode step is served by the fused program, and a new temperature
+    compiles nothing."""
+    from repro.obs import REGISTRY
+
+    cfg, _, params = _smoke_model()
+    prompts = _prompts(cfg, B=4, P=8)
+    kw = dict(gen_len=8, cache_len=16, temperature=temperature, seed=5)
+    # an eos that slot 0 emits at its third token
+    probe = build_model(cfg.replace(eos_id=-1), Runtime())
+    eos = int(_eager_loop(probe, params, prompts, **kw)[0][0, 2])
+    model = build_model(cfg.replace(eos_id=eos), Runtime())
+    want, want_done = _eager_loop(model, params, prompts, **kw)
+    assert bool(want_done[0]) and bool((want[0, 2:] == eos).all())
+
+    fused = REGISTRY.counter("serve.decode.fused_steps")
+    before = fused.value(arch=cfg.name)
+    toks, done = generate(model, params, prompts, **kw)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(done), np.asarray(want_done))
+    assert fused.value(arch=cfg.name) - before == kw["gen_len"] - 1
+
+    step = serve._fused_decode(model)
+    programs = step._cache_size()
+    generate(model, params, prompts, **{**kw, "temperature": 2 * temperature})
+    assert step._cache_size() == programs
+    assert fused.value(arch=cfg.name) - before == 2 * (kw["gen_len"] - 1)
+
+
+@pytest.mark.parametrize("swap", ["patch", "demotion"])
+def test_fused_step_follows_the_jitted_decode(swap):
+    """The fused step is built around the decode callable in
+    ``_JITTED[model]``: replacing that callable makes the next request
+    run the replacement, and dropping it (as a runtime demotion or a
+    probation re-jit does) makes the next request trace a fresh one."""
+    cfg, model, params = _smoke_model()
+    prompts = _prompts(cfg, P=8)
+    kw = dict(gen_len=5, cache_len=16)
+    clean, _ = generate(model, params, prompts, **kw)
+    forced = 7 if int(clean[0, 1]) != 7 else 8
+    prefill, decode = serve._jitted(model)
+
+    def wrapped(params, cache, tok, pos):
+        logits, cache = decode(params, cache, tok, pos)
+        return logits.at[..., forced].set(1e9), cache
+
+    serve._JITTED[model] = (prefill, wrapped)
+    toks, _ = generate(model, params, prompts, **kw)
+    assert bool((toks[:, 1:] == forced).all())
+    if swap == "demotion":
+        serve._JITTED.pop(model)
+        toks, _ = generate(model, params, prompts, **kw)
+        np.testing.assert_array_equal(np.asarray(toks), np.asarray(clean))
+        assert serve._FUSED[model][0] is serve._JITTED[model][1]
 
 
 # -- enc-dec cache clamp ------------------------------------------------------
