@@ -35,8 +35,9 @@ def program_fields(c: dict) -> dict:
     )
 
 
-def extra_inputs(c: dict, batch: int, prompt: int):
-    return None
+def request_inputs(c: dict, rng, clients: int) -> dict:
+    """Each request's inputs besides its prompt tokens: none."""
+    return {}
 
 
 @functools.partial(jax.jit,

@@ -11,6 +11,9 @@ else a first call costs is paid. The window
 then sends whole batches through ``repro.launch.serve.generate`` until
 ``--seconds`` have passed (the program returns a batch's tokens only when
 the batch ends, so a window cut inside a batch would count half a batch).
+Each batch is its prompts and the configuration's other inputs for it
+(``batch_inputs``), both drawn from the seed before the batch is sent and
+passed to ``generate`` as keywords; the reference is given the same rows.
 A request fails when its batch raised a ``HEALTH`` event (a demoted
 kernel, a retry, truncation, shed or quarantine), or when any decode
 attention read was served by anything but the Pallas kernel.
@@ -121,6 +124,38 @@ def build(cell: manifest.Cell):
     return build_model(cfg, Runtime())
 
 
+def make_weights(cell: manifest.Cell, model, seed: int):
+    """The seeded weights, by the rule the configuration's optional
+    ``seeded_weights`` key adjusts (``weights.make``)."""
+    return weights.make(model, seed, **cell.config.get("seeded_weights", {}))
+
+
+def batch_inputs(cell: manifest.Cell, mix: traffic.Mix, seed: int,
+                 batch: int) -> dict[str, np.ndarray]:
+    """The ``batch``-th batch's non-token inputs: the configuration's
+    ``request_inputs``, drawn from the batch's own stream of the seed, one
+    row per client, keyed by ``generate``'s keyword names."""
+    got = cell.reference.request_inputs(
+        cell.config, traffic.inputs_rng(seed, batch), mix.clients)
+    for name, v in got.items():
+        if np.shape(v)[:1] != (mix.clients,):
+            raise ValueError(f"{cell.config_name}: input {name!r} has shape "
+                             f"{np.shape(v)}, not {mix.clients} rows")
+    return got
+
+
+def slot_inputs(inputs: dict[str, np.ndarray], slot: int) -> dict | None:
+    """One request's rows of its batch's inputs, or None where there are
+    none: what the reference's ``extra`` receives."""
+    return {name: v[slot] for name, v in inputs.items()} or None
+
+
+def _on_device(inputs: dict[str, np.ndarray]) -> dict:
+    import jax.numpy as jnp
+
+    return {name: jnp.asarray(v) for name, v in inputs.items()}
+
+
 def serve_fn(model, params, mix: traffic.Mix):
     from repro.launch import serve
 
@@ -131,17 +166,16 @@ def serve_fn(model, params, mix: traffic.Mix):
 
 
 def served_requests(cell, mix, model, batches, seed, pick):
-    """(prompt, served tokens, extra input) of each picked (batch, slot)."""
+    """(prompt, served tokens, other inputs) of each picked (batch, slot),
+    the prompt and inputs drawn again from the seed."""
     eos = model.cfg.eos_id
-    extra = cell.reference.extra_inputs(cell.config, mix.clients,
-                                        mix.prompt_tokens)
     by_index = {b.index: b for b in batches}
     out = []
     for bi, slot in pick:
         prompt = traffic.prompts(mix, model.cfg.vocab_size, seed, bi)[slot]
+        extra = slot_inputs(batch_inputs(cell, mix, seed, bi), slot)
         toks = by_index[bi].tokens[slot]
-        out.append((prompt, toks[: check.served_len(toks, eos)],
-                    None if extra is None else extra[slot]))
+        out.append((prompt, toks[: check.served_len(toks, eos)], extra))
     return out
 
 
@@ -151,14 +185,15 @@ def readings(cell, model, mix: traffic.Mix, seed: int, *, control=False):
     comparison as a run; with ``control`` each control's too."""
     import jax.numpy as jnp
 
-    params = weights.make(model, seed)
+    params = make_weights(cell, model, seed)
     generate = serve_fn(model, params, mix)
     n_req = max(check.MIN_REQUESTS,
                 math.ceil(check.SAMPLE_TOKENS / mix.output_tokens))
     batches = []
     for i in range(math.ceil(n_req / mix.clients)):
         p = traffic.prompts(mix, model.cfg.vocab_size, seed, i)
-        toks = np.asarray(generate(jnp.asarray(p))[0])
+        inputs = batch_inputs(cell, mix, seed, i)
+        toks = np.asarray(generate(jnp.asarray(p), **_on_device(inputs))[0])
         batches.append(Batch(i, 0.0, 0.0, toks, False, False))
     finished = [(b.index, s) for b in batches for s in range(mix.clients)]
     pick = check.sample(seed, finished, mix.output_tokens)
@@ -183,15 +218,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     log(f"compile cache: {compile_cache.enable()}")
     compiles = CompileCounter()
     model = build(cell)
-    params = weights.make(model, seed)
+    params = make_weights(cell, model, seed)
     jax.block_until_ready(params)
     generate = serve_fn(model, params, mix)
     V = model.cfg.vocab_size
     warm = traffic.prompts(mix, V, seed, traffic.WARMUP)
+    warm_inputs = batch_inputs(cell, mix, seed, traffic.WARMUP)
     # the first batch compiles (or reads the cache); the second takes
     # whatever else a first call costs out of the window
     for _ in range(WARMUP_BATCHES):
-        np.asarray(generate(jnp.asarray(warm))[0])
+        np.asarray(generate(jnp.asarray(warm), **_on_device(warm_inputs))[0])
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f}s ({WARMUP_BATCHES} warm-up batches)")
 
@@ -216,12 +252,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     while not batches or time.perf_counter() - t0 < seconds:
         i = len(batches)
         prompts = traffic.prompts(mix, V, seed, i)
+        inputs = batch_inputs(cell, mix, seed, i)
         ann = (jax.profiler.TraceAnnotation(ANNOTATION) if tracing
                else contextlib.nullcontext())
         sums = [h.sum(**lab) for h in hists.values()]
         t_sub = time.perf_counter()
         with ann:
-            toks = np.asarray(generate(jnp.asarray(prompts))[0])
+            toks = np.asarray(generate(jnp.asarray(prompts),
+                                       **_on_device(inputs))[0])
         t_done = time.perf_counter()
         per_batch.append([h.sum(**lab) - s
                           for h, s in zip(hists.values(), sums)])

@@ -2,7 +2,8 @@
 
 After the window a sample of the finished requests, drawn from the seed,
 is run through the configuration's float32 reference, teacher-forced over
-each prompt and its served tokens. For each served token the reference's
+each prompt and its served tokens, given the same other inputs (audio,
+say) as the served request. For each served token the reference's
 logits give its *gap*: how far the token's logit lies below the
 reference's best at that position. A greedy server that computes what the
 reference computes serves gaps of rounding size; one that computes
@@ -56,8 +57,11 @@ def gaps(ref_logits: np.ndarray, chosen: np.ndarray) -> np.ndarray:
 
 
 def compare(ref_module, weights, config: dict, requests, *, control=False):
-    """``requests``: (prompt, served tokens, extra input) triples, the
-    served tokens already cut at eos. Returns the widest gap of the served
+    """``requests``: (prompt, served tokens, other inputs) triples, the
+    served tokens already cut at eos, the other inputs the request's own
+    row of each of its batch's non-token inputs (``{name: row}``, or None
+    where the configuration has none), handed to the reference's
+    ``logits`` as its ``extra``. Returns the widest gap of the served
     tokens and, with ``control``, each control's widest gap under
     ``"controls"``."""
     widest, n = 0.0, 0

@@ -3,14 +3,36 @@
 Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric sits in a file of its own under the benchmark directory:
 
-    configs/<config>.json        sizes as run, program overrides, source
-    configs/<config>.py          plain float32 reference + work counts
+    configs/<config>.json        sizes as run, program overrides, source,
+                                 optionally ``seeded_weights`` (weights.py)
+    configs/<config>.py          plain float32 reference, work counts and
+                                 each request's inputs besides its tokens
     traffic/<traffic>.json       the mix's parameters
     cells/<workload>.json        the limits the output check holds the cell to
     layer_metrics/<metric>.py    one reader per per-layer metric
 
 so a later change adds a configuration, mix, cell or metric by adding
 files and manifest entries, never by editing the harness.
+
+A configuration's module gives:
+
+    program_fields(c)            the program's model fields it fixes
+    request_inputs(c, rng, clients)
+                                 {name: array with ``clients`` rows}, drawn
+                                 from ``rng`` alone: a batch's own stream of
+                                 the seed (``traffic.inputs_rng``). Each name
+                                 is a keyword input of
+                                 ``repro.launch.serve.generate``, which is
+                                 given the batch's arrays; ``{}`` for none
+    logits(w, c, extra, prompt, served, *, lower=None)
+                                 the reference; ``extra`` is the request's
+                                 own row of each input, or None for none
+    work(c, batch, prompt, gen)  operations and bytes of one batch
+
+``c`` is the configuration's JSON. The harness draws a batch's inputs
+next to its prompts, between batches, where the window counts the host's
+time: draw large inputs as uniform floats (``rng.random(shape,
+dtype=np.float32)``), which take about a quarter of the time of normals.
 """
 from __future__ import annotations
 

@@ -8,8 +8,12 @@ A mix (``traffic/<name>.json``) is data:
 ``closed`` means the ``clients`` wait for their replies: the program's
 serving entry takes one static batch, so the clients form one batch of
 ``clients`` requests, and the next batch is sent when the last one returns.
-Every seed gives the same sizes; the seed picks the prompt tokens only,
-so runs of different seeds do the same work.
+Every seed gives the same sizes; the seed picks the prompt tokens and
+each request's other inputs only, so runs of different seeds do the same
+work. A batch's other inputs (audio, say) come from the configuration's
+``request_inputs``, drawn from ``inputs_rng``: a stream of the seed apart
+from the prompts', so a configuration that adds inputs leaves every
+prompt as it was.
 """
 from __future__ import annotations
 
@@ -51,3 +55,10 @@ def prompts(mix: Mix, vocab: int, seed: int, batch: int) -> np.ndarray:
     rng = np.random.default_rng(ss)
     return rng.integers(0, vocab, size=(mix.clients, mix.prompt_tokens),
                         dtype=np.int32)
+
+
+def inputs_rng(seed: int, batch: int) -> np.random.Generator:
+    """The generator the ``batch``-th batch's non-token inputs are drawn
+    from: a stream of its own beside the prompts' ``(batch + 1,)``."""
+    return np.random.default_rng(
+        np.random.SeedSequence(int(seed), spawn_key=(batch + 1, 1)))

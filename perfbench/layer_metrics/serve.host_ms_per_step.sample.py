@@ -1,6 +1,6 @@
 """Device idle time per decode step inside the program's
-``serve.decode.sample`` spans: the eager argmax (or categorical draw),
-the eos masking and the done-mask update, each dispatched from Python
+``serve.decode.sample`` spans: the host's append of the step's token,
+which the compiled decode step already chose, masked and marked done
 (from the profiler trace, over the decode steps of the traced batches,
 as ``serve.host_ms_per_step`` counts them). Layer: the serve loop
 (``launch/serve.py`` ``_generate_once``). Should move

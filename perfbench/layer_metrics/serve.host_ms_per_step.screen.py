@@ -1,9 +1,10 @@
 """Device idle time per decode step inside the program's
-``serve.decode.screen`` spans: the logits screen's eager finiteness
-reduction and its read-back to the host (from the profiler trace, over
-the decode steps of the traced batches, as ``serve.host_ms_per_step``
-counts them). Layer: the serve loop (``launch/serve.py``
-``_screen_logits``). Should move ``tokens_per_s``."""
+``serve.decode.screen`` spans: the read-back to the host of the (B,)
+``bad`` mask that the compiled decode step computed, and the host's
+check of it (from the profiler trace, over the decode steps of the
+traced batches, as ``serve.host_ms_per_step`` counts them). Layer: the
+serve loop (``launch/serve.py`` ``_screen``). Should move
+``tokens_per_s``."""
 
 SPAN = "serve.decode.screen"
 

@@ -32,7 +32,7 @@ def test_every_cell_resolves_by_name(workload):
     assert "setup_s" in names and len(names) >= 2
     assert cell.per_layer and all(callable(m.reader.read)
                                   for m in cell.per_layer)
-    for fn in ("program_fields", "extra_inputs", "logits", "work"):
+    for fn in ("program_fields", "request_inputs", "logits", "work"):
         assert callable(getattr(cell.reference, fn))
 
 

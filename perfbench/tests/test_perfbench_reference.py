@@ -50,10 +50,11 @@ def test_reference_matches_program_logits(tmp_path, workload):
         toks.append(tok)
     got = np.stack(got, 1)  # (B, G, V)
     served = np.asarray(jnp.concatenate(toks, 1))
-    extra = cell.reference.extra_inputs(cell.config, B, P)
+    inputs = cell.reference.request_inputs(
+        cell.config, traffic.inputs_rng(5, 0), B)
     for b in range(B):
         want = cell.reference.logits(
-            params, cell.config, None if extra is None else extra[b],
+            params, cell.config, bench.slot_inputs(inputs, b),
             prompts[b], served[b])
         err = np.abs(got[b] - want).max() / np.abs(want).max()
         assert err < TOL, (workload, b, err)
