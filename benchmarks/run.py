@@ -317,6 +317,13 @@ def serve_rows(quick: bool) -> list[str]:
     rows = []
     B, P, G = 2, 16, 8
 
+    def audio_for(cfg):
+        """Each request's mels for an audio model (4P frames), else None."""
+        if cfg.family != "audio":
+            return None
+        rng = np.random.default_rng(1)
+        return jnp.asarray(rng.uniform(-1, 1, (B, 4 * P, 80)), jnp.float32)
+
     def kv_read_bytes(model, cfg, cache_len, view: bool) -> int:
         """Bytes the per-step attention read moves: the kv_seq-axis cache
         leaves as stored, plus — on the dequant-view path — the float
@@ -325,7 +332,7 @@ def serve_rows(quick: bool) -> list[str]:
 
         total = 0
         for d in jax.tree.leaves(
-            model.cache_defs(B, cache_len),
+            model.cache_defs(B, cache_len, **S.cache_kw(audio_for(cfg))),
             is_leaf=lambda x: isinstance(x, ParamDef),
         ):
             if "kv_seq" not in d.axes:
@@ -347,11 +354,14 @@ def serve_rows(quick: bool) -> list[str]:
         prompts = jnp.asarray(
             rng.integers(2, cfg.vocab_size, size=(B, P)), jnp.int32
         )
+        audio = audio_for(cfg)
         toks, _ = S.generate(
-            model, params, prompts, gen_len=G, cache_len=cache_len
+            model, params, prompts, gen_len=G, cache_len=cache_len,
+            audio=audio,
         )
         logits, cache = S.prefill_cache(
-            model, params, prompts, cache_len=cache_len, gen_len=G
+            model, params, prompts, cache_len=cache_len, gen_len=G,
+            audio=audio,
         )
         decode = S._jitted(model)[1]
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
@@ -388,7 +398,8 @@ def serve_rows(quick: bool) -> list[str]:
         ):
             clen = S.resolve_cache_len(cfg, cache_len, P, G)
             nbytes[tag] = S.cache_nbytes(
-                model.cache_defs(B, clen), cfg.param_dtype
+                model.cache_defs(B, clen, **S.cache_kw(audio_for(cfg))),
+                cfg.param_dtype,
             )
             rbytes[tag] = kv_read_bytes(model, cfg, clen, attn == "view")
         rows.append(row(

@@ -19,11 +19,11 @@ Runs in one process and starts none. Phases, in order:
 3. **serve** — whisper-medium at its published widths, random weights from
    ``SEED``, served through ``repro.launch.serve.generate`` (the path
    ``serve.main`` takes) with the Pallas conv frontend, an int8 KV cache and
-   the fused decode kernel: batch 4, a 128-token prompt (256 mel frames),
-   32 generated tokens, 3 requests. The prefill logits must be finite and
-   agree with the same model on the pure-JAX conv path within a bf16
-   bound; the tokens must lie in the vocabulary, and the greedy requests
-   must be bit-identical.
+   the fused decode kernel: batch 4, each request its own 30 s of mels
+   (3000 frames), a 128-token prompt, 32 generated tokens, 3 requests.
+   The prefill logits must be finite and agree with the same model on
+   the pure-JAX conv path within a bf16 bound; the tokens must lie in the
+   vocabulary, and the greedy requests must be bit-identical.
 4. **fail loudly** — any ``HEALTH`` event (a demoted kernel, a fallback, a
    retry) or a decode-attention read served by anything but the Pallas
    kernel fails the run: on this path a demotion is a bring-up failure.
@@ -68,7 +68,8 @@ ATTN_SHAPES = (
     ("qwen3-1.7b", dict(B=8, S=4096, KV=8, G=2, D=128)),
     ("whisper-medium", dict(B=4, S=1500, KV=16, G=1, D=64)),
 )
-SERVE = dict(arch="whisper-medium", batch=4, prompt=128, gen=32, requests=3)
+SERVE = dict(arch="whisper-medium", batch=4, frames=3000, prompt=128, gen=32,
+             requests=3)
 
 
 class Smoke:
@@ -158,7 +159,7 @@ def kernel_phase(smoke: Smoke, *, convs=CONVS, depthwise=DEPTHWISE,
         w = normal((3, cin, 1024), cin ** -0.5)
         b = normal((1024,), 0.1)
         ct = normal((1, frames // stride, 1024), dtype=f32)
-        kw = dict(stride=stride, padding="SAME", activation="gelu")
+        kw = dict(stride=stride, padding="SAME", activation="gelu_erf")
 
         def loss(x, w, b, backend="sliding"):
             y = ops.conv1d(x, w, bias=b, backend=backend, **kw)
@@ -177,7 +178,8 @@ def kernel_phase(smoke: Smoke, *, convs=CONVS, depthwise=DEPTHWISE,
 
         qw = qconv.quantize_weight(w)
         xs = qconv.act_scale(x)
-        qkw = dict(bias=b, stride=stride, padding="SAME", activation="gelu")
+        qkw = dict(bias=b, stride=stride, padding="SAME",
+                   activation="gelu_erf")
         yq = jax.jit(lambda x, q, s, xs: ops.conv1d(
             x, q, precision="w8a8", w_scale=s, x_scale=xs, **qkw,
         ))(x, qw.q, qw.scale, xs)
@@ -230,8 +232,9 @@ def kernel_phase(smoke: Smoke, *, convs=CONVS, depthwise=DEPTHWISE,
 
 
 def serve_phase(smoke: Smoke, *, arch=SERVE["arch"], cfg_overrides=None,
-                batch=SERVE["batch"], prompt=SERVE["prompt"],
-                gen=SERVE["gen"], requests=SERVE["requests"]):
+                batch=SERVE["batch"], frames=SERVE["frames"],
+                prompt=SERVE["prompt"], gen=SERVE["gen"],
+                requests=SERVE["requests"]):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -240,6 +243,7 @@ def serve_phase(smoke: Smoke, *, arch=SERVE["arch"], cfg_overrides=None,
     from repro.configs import get_config
     from repro.distributed.sharding import Runtime
     from repro.launch import serve
+    from repro.launch.steps import make_prefill_step
     from repro.models import build_model
 
     cfg = get_config(arch).replace(
@@ -259,15 +263,17 @@ def serve_phase(smoke: Smoke, *, arch=SERVE["arch"], cfg_overrides=None,
     prompts = jnp.asarray(
         rng.integers(2, cfg.vocab_size, size=(batch, prompt)), jnp.int32
     )
+    audio = jnp.asarray(rng.uniform(-1, 1, (batch, frames, 80)), jnp.float32)
     cache_len = serve.resolve_cache_len(cfg, prompt + gen, prompt, gen)
 
     logits, _ = serve.prefill_cache(model, params, prompts,
-                                    cache_len=cache_len, gen_len=gen)
+                                    cache_len=cache_len, gen_len=gen,
+                                    audio=audio)
     smoke.check("serve prefill logits finite",
                 bool(jnp.isfinite(logits).all()), str(tuple(logits.shape)))
     ref_model = build_model(cfg.replace(conv_backend="sliding"), Runtime())
-    ref_prefill = jax.jit(ref_model.prefill)
-    batch_in = serve.serve_batch(ref_model, batch, prompt, prompts)
+    ref_prefill = jax.jit(make_prefill_step(ref_model))
+    batch_in = serve.serve_batch(ref_model, prompts, audio)
     ref_logits = ref_prefill(params, batch_in)[0]
     frames = batch_in["frames"]
     floor = max(
@@ -292,7 +298,7 @@ def serve_phase(smoke: Smoke, *, arch=SERVE["arch"], cfg_overrides=None,
             step_h.count(**label)
         t_req = time.perf_counter()
         toks, _done = serve.generate(model, params, prompts, gen_len=gen,
-                                     cache_len=cache_len)
+                                     cache_len=cache_len, audio=audio)
         toks = np.asarray(toks)
         wall = time.perf_counter() - t_req
         steps = step_h.count(**label) - n0

@@ -48,9 +48,11 @@ class ModelConfig:
     rwkv_wkv_mode: str = "scan"  # "scan" (faithful baseline) | "chunked" (MXU)
     rwkv_wkv_chunk: int = 32
     # multimodal / enc-dec
-    frontend: str | None = None  # "vision_stub" | "audio_stub"
+    frontend: str | None = None  # "vision_stub" | "audio"
     encoder_layers: int = 0  # whisper: encoder depth (num_layers = decoder)
     cross_attention: bool = False
+    # learned decoder positions (whisper: 448); 0 = none, no such limit
+    max_target_positions: int = 0
     num_patches: int = 1024  # vlm: patch positions inside the sequence
     # numerics & technique
     param_dtype: str = "bfloat16"
@@ -150,6 +152,10 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
     """(runs?, reason-if-skipped) for an (arch × shape) cell."""
     if shape.name == "long_500k" and cfg.family not in LONG_CONTEXT_FAMILIES:
         return False, "full-attention arch: 500k ctx needs sub-quadratic attention"
+    if (cfg.max_target_positions and shape.kind != "decode"
+            and shape.seq_len // 2 > cfg.max_target_positions):
+        return False, (f"decoder takes at most {cfg.max_target_positions} "
+                       "learned positions")
     return True, ""
 
 

@@ -1,13 +1,12 @@
-"""whisper-medium [audio] — enc-dec, conv frontend (stub).
+"""whisper-medium [audio] — encoder-decoder over 80-bin log-mels.
 
-[arXiv:2212.04356; unverified] 24L d_model=1024 16H (GQA kv=16 = MHA)
-d_ff=4096 vocab=51865
-
-The conv frontend (two k=3 conv1d, second strided 2) is a STUB per the
-assignment: ``input_specs()`` provides precomputed frame embeddings. The
-non-stub frontend is implemented with the paper's custom k=3 sliding kernel
-(``repro.models.whisper.conv_frontend``). Shapes split seq_len between the
-encoder (frames) and decoder (tokens) halves.
+huggingface.co/openai/whisper-medium (config.json), arXiv:2212.04356:
+24 encoder and 24 decoder layers, d_model 1024, 16 heads of 64, FFN 4096
+with the exact GELU, vocabulary 51865 with the output head tied to the
+token embedding, 448 learned decoder positions, LayerNorm eps 1e-5,
+end-of-text 50257. The conv frontend (two k=3 conv1d, the second at
+stride 2) runs the paper's sliding conv kernels; 3000 mel frames (30 s)
+give 1500 encoder positions (``repro.models.whisper``).
 """
 from repro.configs.base import ModelConfig
 
@@ -21,8 +20,11 @@ CONFIG = ModelConfig(
     num_kv_heads=16,
     d_ff=4096,
     vocab_size=51_865,
-    activation="gelu_plain",  # whisper MLP is plain GELU (not gated)
+    activation="gelu_plain",  # ungated MLP, exact GELU
+    tie_embeddings=True,
+    norm_eps=1e-5,
     cross_attention=True,
-    frontend="audio_stub",
-    rope_theta=10_000.0,  # decoder uses learned pos in HF; we use RoPE-free sinusoidal
+    frontend="audio",
+    max_target_positions=448,
+    eos_id=50_257,
 )

@@ -154,8 +154,12 @@ def head_block(h_block: int | None, KV: int, D: int) -> int:
     return KV
 
 
+#: the kernel's ``pallas_call`` name, which the device trace shows
+NAME = "decode_attention_pallas"
+
+
 @functools.partial(
-    jax.jit, static_argnames=("block_s", "h_block", "interpret")
+    jax.jit, static_argnames=("block_s", "h_block", "interpret", "name")
 )
 def decode_attention_pallas(
     q: jax.Array,
@@ -168,6 +172,7 @@ def decode_attention_pallas(
     block_s: int = DEFAULT_BLOCK_S,
     h_block: int | None = None,
     interpret: bool = False,
+    name: str = NAME,
 ) -> jax.Array:
     """Fused decode attention. q: (B, KV, G, D) grouped queries (any float
     dtype); k/v: (B, S, KV, D) cache leaves — int8 codes WITH their
@@ -177,7 +182,9 @@ def decode_attention_pallas(
 
     ``block_s`` tiles kv_seq (the reduction grid dim); ``h_block`` groups
     KV heads per grid step (``head_block``). Both are tuned under the
-    ``attn_dec|…`` autotune key.
+    ``attn_dec|…`` autotune key. ``name`` is the ``pallas_call``'s name,
+    under which the device trace shows the kernel: a caller names the
+    reads it wants told apart (whisper's cross-attention).
 
     Layout: k/v travel as (B, S, KV·D) — a free reshape — so each grid
     step reads one (block_s, hb·D) tile whose last two dims meet the
@@ -220,7 +227,7 @@ def decode_attention_pallas(
         args += [ks3.transpose(0, 2, 1), vs3.transpose(0, 2, 1)]
     return pl.pallas_call(
         kernel,
-        name="decode_attention_pallas",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, n_h, n_s),

@@ -426,8 +426,9 @@ def conv1d(
 ) -> jax.Array:
     """Multi-channel 1-D convolution. x: (B,L,Cin), w: (K,Cin,Cout).
 
-    ``bias`` (Cout,) + ``activation`` (none/relu/gelu/silu) are fused into
-    the sliding kernel's epilogue; baseline backends apply them unfused.
+    ``bias`` (Cout,) + ``activation`` (none/relu/gelu/gelu_erf/silu) are
+    fused into the sliding kernel's epilogue; baseline backends apply them
+    unfused.
     The sliding path is differentiable (custom VJP with Pallas backward
     kernels); ``bwd_tile_l`` overrides the backward dw-kernel tile.
 
@@ -979,6 +980,7 @@ def attention_decode(
     block_s: int | None = None,
     h_block: int | None = None,
     interpret: bool | None = None,
+    name: str = attn_dec.NAME,
 ) -> jax.Array:
     """Fused flash-style decode attention against the (possibly int8) KV
     cache — the dequant folds into the online softmax, so the cache's
@@ -996,7 +998,8 @@ def attention_decode(
     blocked scan — same algebra, the CPU serving path), "ref" (dequant-view
     oracle). None → pallas on TPU, jax otherwise. ``block_s``/``h_block``
     resolve explicit → ``attn_dec|…`` autotune cache entry → default.
-    Returns (B, H, D) f32.
+    ``name`` names the Pallas kernel in the device trace. Returns (B, H, D)
+    f32.
     """
     B, H, D = q.shape
     S, KV = k.shape[1], k.shape[2]
@@ -1032,6 +1035,7 @@ def attention_decode(
             return attn_dec.decode_attention_pallas(
                 q4, k, v, k_scale, v_scale, lengths,
                 block_s=block_s, h_block=h_block, interpret=interpret,
+                name=name,
             )
         if im == "jax":
             return attn_dec.attention_decode_jax(

@@ -28,9 +28,9 @@ f32 VMEM scratch across output-block revisits (the reduction dimension is
 innermost in the grid, so each output block's reduction completes before the
 block is flushed).
 
-Fused epilogue: ``bias`` (Cout,) and ``activation`` (none/relu/gelu/silu)
-are applied inside the kernel on the final reduction visit — conv→bias→act
-is one kernel launch, not three HBM round-trips.
+Fused epilogue: ``bias`` (Cout,) and ``activation`` (none/relu/gelu/
+gelu_erf/silu) are applied inside the kernel on the final reduction visit —
+conv→bias→act is one kernel launch, not three HBM round-trips.
 
 Training residuals: with ``save_preact=True`` the kernels emit a SECOND
 output ``z = acc + bias`` (the post-bias, pre-activation value, cast to the
@@ -61,14 +61,41 @@ DEFAULT_TILE_L = 256
 TAP_CHUNK = 16  # taps per compound chunk ~= one "hardware vector" of taps
 
 
+# erf as x·P(x²)/Q(x²) on [-4, 4], beyond which float32 erf is ±1: the
+# rational approximation XLA and Eigen evaluate float32 erf with. Mosaic
+# lowers no erf, and this takes multiplies, adds and one divide. Largest
+# error against float64 erf over [-10, 10]: 4.5e-7 (CPU reading).
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04,
+          -2.95459980854025e-03, -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def erf(x: jax.Array) -> jax.Array:
+    """float32 erf from elementwise arithmetic alone (kernel epilogues)."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p, q = _ERF_P[0], _ERF_Q[0]
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return x * p / q
+
+
 def apply_activation(x: jax.Array, activation: str) -> jax.Array:
-    """Epilogue activation on the f32 accumulator (static dispatch)."""
+    """Epilogue activation on the f32 accumulator (static dispatch):
+    ``gelu`` is the tanh approximation, ``gelu_erf`` the exact GELU."""
     if activation in (None, "none"):
         return x
     if activation == "relu":
         return jax.nn.relu(x)
     if activation == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if activation == "gelu_erf":
+        return 0.5 * x * (1.0 + erf(x * 0.7071067811865476))
     if activation == "silu":
         return jax.nn.silu(x)
     raise ValueError(f"unknown activation {activation!r}")

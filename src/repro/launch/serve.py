@@ -67,13 +67,14 @@ from repro.configs import get_config, smoke_config
 from repro.distributed.ft import RestartPolicy, StepWatchdog, beat
 from repro.distributed.sharding import ParamDef, Runtime
 from repro.health import HEALTH, Reason, canon_reason
+from repro.launch.steps import make_prefill_step
 from repro.models import build_model
 
 
-def init_cache_concrete(model, B, S):
+def init_cache_concrete(model, B, S, **kw):
     return jax.tree.map(
         lambda d: jnp.zeros(d.shape, jnp.dtype(d.dtype or model.cfg.param_dtype)),
-        model.cache_defs(B, S),
+        model.cache_defs(B, S, **kw),
         is_leaf=lambda x: isinstance(x, ParamDef),
     )
 
@@ -139,6 +140,13 @@ def pad_cache_to_defs(cache, full, defs):
     return jax.tree.map(pad, cache, full, defs)
 
 
+def cache_kw(audio) -> dict:
+    """``cache_defs``' keywords for a batch's inputs: the cross cache of
+    an encoder-decoder holds the encoder's positions of the audio, one
+    per two mel frames."""
+    return {} if audio is None else {"enc_len": audio.shape[1] // 2}
+
+
 # per-model jitted entry points: jax.jit caches trace/compile per wrapper,
 # and a fresh wrapper per generate() call would re-trace every time — a
 # repeat generate() on the same model (benchmarks, tests) must pay compile
@@ -159,6 +167,23 @@ def _jitted(model):
         )
         _JITTED[model] = fns
     return fns
+
+
+# per-model encoder program (enc-dec), held beside the prefill callable it
+# was built with: a runtime demotion drops _JITTED[model], and the next
+# request must trace the encoder again without the demoted rung
+_ENCODER = weakref.WeakKeyDictionary()
+
+
+def _encoder(model):
+    prefill = _jitted(model)[0]
+    held = _ENCODER.get(model)
+    if held is None or held[0] is not prefill:
+        mref = weakref.ref(model)
+        held = (prefill, jax.jit(
+            lambda params, frames: mref().encode_cross(params, frames)))
+        _ENCODER[model] = held
+    return held[1]
 
 
 class LoadShedError(RuntimeError):
@@ -200,6 +225,8 @@ class RequestJournal:
 
     def begin(self, req_id: str, prompts, *, gen_len: int, cache_len: int,
               temperature: float, seed: int) -> None:
+        """Record a request: its prompts and decode parameters, not its
+        other inputs (audio), which :func:`replay_pending` then refuses."""
         self._append({
             "id": req_id, "event": "begin",
             "prompts": np.asarray(prompts).tolist(),
@@ -235,37 +262,51 @@ class RequestJournal:
         return [r for rid, r in begun.items() if rid not in ended]
 
 
-def serve_batch(model, B, P, prompts):
-    batch = {"tokens": prompts}
-    cfg = model.cfg
-    if cfg.family == "audio":
-        # real mels (not precomputed frame embeddings) so serving exercises
-        # the conv frontend — the site `--quant int8` calibrates and chains.
-        # 2P mel frames → P encoder positions after the stride-2 conv2.
-        from repro.models.whisper import N_MELS
+def _check_audio(cfg, prompts, audio) -> None:
+    """An audio model needs each request's own mels, ``audio`` (B, T, 80),
+    one row per prompt; any other model refuses audio."""
+    if cfg.family != "audio":
+        if audio is not None:
+            raise ValueError(f"{cfg.name} takes no audio")
+        return
+    if audio is None:
+        raise ValueError(f"{cfg.name} serves audio: pass each request's "
+                         "mels as audio=(B, T, 80)")
+    if audio.shape[0] != prompts.shape[0]:
+        raise ValueError(f"audio for {audio.shape[0]} requests, prompts "
+                         f"for {prompts.shape[0]}")
 
-        rng = np.random.default_rng(0)
-        batch["frames"] = jnp.asarray(
-            rng.normal(size=(B, 2 * P, N_MELS)).astype(np.float32)
-        )
+
+def serve_batch(model, prompts, audio=None):
+    """The prefill's batch: the prompts and each request's other inputs
+    (an audio model's mels, which its conv frontend takes)."""
+    cfg = model.cfg
+    _check_audio(cfg, prompts, audio)
+    batch = {"tokens": prompts}
+    if audio is not None:
+        batch["frames"] = audio
     if cfg.family == "vlm":
-        batch["patches"] = jnp.zeros((B, cfg.num_patches, 1152), jnp.float32)
+        batch["patches"] = jnp.zeros((prompts.shape[0], cfg.num_patches, 1152),
+                                     jnp.float32)
     return batch
 
 
 def resolve_cache_len(cfg, cache_len: int, P: int, gen_len: int) -> int:
-    """Clamp an undersized cache request. Enc-dec cache defs split `seq`
-    evenly between encoder frames and decoder tokens — the decoder half
-    alone must hold prompt + gen (the seed crashed whisper serving on a
-    negative cache pad). One helper so generate() and the CLI's byte
-    reporting can never disagree about the effective length."""
+    """Clamp an undersized cache request. An enc-dec cache's ``seq`` rows
+    are the decoder's self-attention positions, which must hold prompt +
+    gen (its cross rows come from the audio, ``cache_kw``), and no more
+    than the decoder has learned positions for. One helper so generate()
+    and the CLI's byte reporting can never disagree about the length."""
     if cfg.encoder_layers:
-        return max(cache_len, 2 * (P + gen_len))
+        if cfg.max_target_positions and P + gen_len > cfg.max_target_positions:
+            raise ValueError(f"{cfg.name}: prompt {P} + {gen_len} tokens "
+                             f"exceed {cfg.max_target_positions} positions")
+        return max(cache_len, P + gen_len)
     return cache_len
 
 
 def prefill_cache(model, params, prompts, *, cache_len: int,
-                  gen_len: int = 0):
+                  gen_len: int = 0, audio=None):
     """Prefill + decode-ready cache: run the model's prefill, then pad
     (and, under ``cfg.kv_quant``, quantize) the emitted cache up to
     ``cache_len`` along each leaf's kv_seq axis. Returns (last-token
@@ -273,16 +314,25 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
     benchmarks (``benchmarks.run --serve``), so both time/drive the exact
     serving cache layout.
 
+    An encoder-decoder first runs its encoder program over ``audio``
+    (each request's mels), synced, under the span ``serve.prefill.encode``;
+    the prefill then reads the encoder's cross K/V.
+
     With kv_quant the float prefill leaves quantize FIRST so the
     (q, scale) pair pads coherently.
     """
     cfg = model.cfg
     B, P = prompts.shape
     cache_len = resolve_cache_len(cfg, cache_len, P, gen_len)
-    batch = serve_batch(model, B, P, prompts)
+    batch = serve_batch(model, prompts, audio)
     prefill, _ = _jitted(model)
     t_p = time.perf_counter()
     with obs.span("serve.prefill.forward"):
+        if cfg.encoder_layers:
+            with obs.span("serve.prefill.encode"):
+                batch["cross"] = _encoder(model)(params, batch.pop("frames"))
+                # a direct-output sync, as the prefill's below
+                jax.block_until_ready(batch["cross"])
         logits, cache = prefill(params, batch)
         # sync the compiled call's DIRECT outputs: an in-compiled-call
         # failure (guest trap, device fault) is only guaranteed to surface
@@ -300,8 +350,9 @@ def prefill_cache(model, params, prompts, *, cache_len: int,
         obs.REGISTRY.counter("runtime.retrace_ms").inc(dt_ms, arch=cfg.name)
         obs.info("serve", f"retrace after runtime demotion: {dt_ms:.0f}ms")
     with obs.span("serve.prefill.cache"):
-        full = init_cache_concrete(model, B, cache_len)
-        defs = model.cache_defs(B, cache_len)
+        kw = cache_kw(audio)
+        full = init_cache_concrete(model, B, cache_len, **kw)
+        defs = model.cache_defs(B, cache_len, **kw)
         if cfg.kv_quant == "int8":
             cache = quantize_cache_to_defs(cache, defs)
         # metadata-only gauge (no device sync): the decode-cache footprint
@@ -423,7 +474,7 @@ def _poison(nan_guard: bool, B: int):
     return None if rows is None else jnp.asarray(rows)
 
 
-def _generate_once(model, params, prompts, *, gen_len, cache_len,
+def _generate_once(model, params, prompts, *, audio, gen_len, cache_len,
                    temperature, seed, deadline_s, nan_guard, run_dir,
                    host_id, watchdog):
     cfg = model.cfg
@@ -438,7 +489,8 @@ def _generate_once(model, params, prompts, *, gen_len, cache_len,
     # device trace says which line the chip waited on (obs.names.SPANS)
     with obs.span("serve.prefill", arch=cfg.name):
         logits, cache = prefill_cache(
-            model, params, prompts, cache_len=cache_len, gen_len=gen_len
+            model, params, prompts, cache_len=cache_len, gen_len=gen_len,
+            audio=audio,
         )
         step = _fused_decode(model)
         with obs.span("serve.prefill.sample"):
@@ -555,13 +607,17 @@ def _admission_check(model, gen_len: int, deadline_s: float | None) -> None:
 
 
 def generate(model, params, prompts, *, gen_len: int, cache_len: int,
-             temperature: float = 0.0, seed: int = 0,
+             audio=None, temperature: float = 0.0, seed: int = 0,
              deadline_s: float | None = None, max_retries: int = 2,
              nan_guard: bool = True, run_dir=None, host_id: int = 0,
              watchdog: StepWatchdog | None = None,
              journal: RequestJournal | None = None,
              request_id: str | None = None):
     """prompts: (B, P) int32 -> ((B, gen_len) int32, done mask (B,) bool).
+
+    ``audio`` (B, T, 80) float32: each request's own log-mel frames, which
+    an audio model (whisper) must be given and any other refuses. The
+    decoder prompt's length does not depend on the audio's.
 
     Slots whose sequence hit ``cfg.eos_id`` are finished: they keep
     decoding into masked positions (their tokens pinned to eos) so the
@@ -591,6 +647,7 @@ def generate(model, params, prompts, *, gen_len: int, cache_len: int,
     crash replay (``request_id`` names it).
     """
     reg = obs.REGISTRY
+    _check_audio(model.cfg, prompts, audio)
     _admission_check(model, gen_len, deadline_s)
     if journal is not None:
         journal.begin(
@@ -631,7 +688,7 @@ def generate(model, params, prompts, *, gen_len: int, cache_len: int,
             t_req = time.perf_counter()
             with obs.span("serve.generate", arch=model.cfg.name):
                 result = _generate_once(
-                    model, params, prompts, gen_len=gen_len,
+                    model, params, prompts, audio=audio, gen_len=gen_len,
                     cache_len=cache_len, temperature=temperature,
                     seed=seed, deadline_s=deadline_s, nan_guard=nan_guard,
                     run_dir=run_dir, host_id=host_id, watchdog=watchdog,
@@ -693,9 +750,16 @@ def replay_pending(model, params, journal: RequestJournal, **kw):
     """Replay journaled in-flight requests after a restart. Greedy decode
     is deterministic, so each replayed request reproduces bit-identical
     tokens; completion writes the journal ``end`` record the crash never
-    did. Returns ``[(request_id, tokens, done), ...]``."""
+    did. Returns ``[(request_id, tokens, done), ...]``.
+
+    The journal holds no audio, so an audio model's records cannot be
+    replayed: they are refused, not served without their audio."""
     out = []
     for rec in journal.pending():
+        if model.cfg.family == "audio":
+            raise ValueError(
+                f"journal request {rec['id']!r}: {model.cfg.name} needs the "
+                "request's audio, which the journal does not hold")
         prompts = jnp.asarray(rec["prompts"], jnp.int32)
         toks, done = generate(
             model, params, prompts, gen_len=rec["gen_len"],
@@ -708,18 +772,18 @@ def replay_pending(model, params, journal: RequestJournal, **kw):
     return out
 
 
-def quantize_for_serving(model, params, prompts):
+def quantize_for_serving(model, params, prompts, audio=None):
     """int8 PTQ of the model's conv path: eager calibration prefill →
     activation scales (+ inter-layer chain scales, ``quant.CHAINS``) →
     int8 weight leaves. Returns (cfg', params')."""
     from repro import quant
 
     cfg = model.cfg
-    B, P = prompts.shape
     calib = quant.Calibration()
     with obs.span("serve.quantize", arch=cfg.name):
         with quant.collecting(calib):
-            model.prefill(params, serve_batch(model, B, P, prompts))  # eager
+            prefill = make_prefill_step(model)
+            prefill(params, serve_batch(model, prompts, audio))  # eager
         spec = calib.spec(chains=quant.CHAINS)
         qparams = quant.quantize_params(params, spec=spec)
     n = quant.quantized_site_count(qparams)
@@ -804,10 +868,18 @@ def _serve(args):
         rng.integers(2, cfg.vocab_size, size=(args.batch, args.prompt_len)),
         jnp.int32,
     )
+    audio = None
+    if cfg.family == "audio":
+        from repro.models.whisper import N_AUDIO_CTX, N_MELS
+
+        # each request's own 30 s of mels, in the range of whisper's
+        # normalised log-mel
+        audio = jnp.asarray(rng.uniform(
+            -1, 1, (args.batch, 2 * N_AUDIO_CTX, N_MELS)), jnp.float32)
     if args.quant:
-        cfg, params = quantize_for_serving(model, params, prompts)
+        cfg, params = quantize_for_serving(model, params, prompts, audio)
         model = build_model(cfg, rt)
-    cache_len = args.prompt_len + args.gen + (args.prompt_len + args.gen) % 2
+    cache_len = args.prompt_len + args.gen
     cache_len = resolve_cache_len(cfg, cache_len, args.prompt_len, args.gen)
     journal = RequestJournal(args.run_dir) if args.run_dir else None
     t0 = time.perf_counter()
@@ -822,7 +894,7 @@ def _serve(args):
     for r in range(args.requests):
         toks, done = generate(
             model, params, prompts, gen_len=args.gen,
-            cache_len=cache_len, temperature=args.temperature,
+            cache_len=cache_len, audio=audio, temperature=args.temperature,
             seed=args.seed, deadline_s=args.deadline_s,
             max_retries=args.retries, run_dir=args.run_dir,
             journal=journal, request_id=f"req{r}",
@@ -857,10 +929,11 @@ def _serve(args):
         obs.info("serve",
                  f"attn-decode: impl={impl} key={akey} "
                  f"calls={kops.ATTN_DECODE_DISPATCH.count(akey)}")
-    bytes_now = cache_nbytes(model.cache_defs(args.batch, cache_len),
+    kw = cache_kw(audio)
+    bytes_now = cache_nbytes(model.cache_defs(args.batch, cache_len, **kw),
                              cfg.param_dtype)
     fp_model = build_model(cfg.replace(kv_quant="fp"), rt)
-    bytes_fp = cache_nbytes(fp_model.cache_defs(args.batch, cache_len),
+    bytes_fp = cache_nbytes(fp_model.cache_defs(args.batch, cache_len, **kw),
                             cfg.param_dtype)
     reg.gauge("serve.kv_cache_bytes").set(float(bytes_now), kind="served")
     reg.gauge("serve.kv_cache_bytes").set(float(bytes_fp), kind="fp")

@@ -71,7 +71,12 @@ def make_eval_step(model) -> Callable:
 
 
 def make_prefill_step(model) -> Callable:
+    """The whole prefill as one step: an encoder-decoder's encoder program
+    over ``batch["frames"]``, then its prefill over the cross K/V."""
     def prefill_step(params, batch):
+        if "frames" in batch:
+            batch = dict(batch)
+            batch["cross"] = model.encode_cross(params, batch.pop("frames"))
         return model.prefill(params, batch)
 
     return prefill_step

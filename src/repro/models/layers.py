@@ -38,11 +38,21 @@ def rms_norm(x: Array, scale: Array, eps: float = 1e-6) -> Array:
     return out.astype(x.dtype)
 
 
+def layer_norm(x: Array, scale: Array, bias: Array, eps: float) -> Array:
+    """LayerNorm with a bias, in f32 (whisper's blocks)."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    out = (xf - mu) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    return (out + bias.astype(jnp.float32)).astype(x.dtype)
+
+
 def act_fn(name: str):
     return {
         "silu": jax.nn.silu,
         "gelu": functools.partial(jax.nn.gelu, approximate=True),
-        "gelu_plain": functools.partial(jax.nn.gelu, approximate=True),
+        # whisper's ungated MLP: the exact (erf) GELU
+        "gelu_plain": functools.partial(jax.nn.gelu, approximate=False),
         "relu_sq": lambda x: jnp.square(jax.nn.relu(x)),
     }[name]
 
@@ -53,7 +63,8 @@ def act_fn(name: str):
 # On the Pallas path (backend "sliding_pallas") the bias add and activation
 # run inside the conv kernel's epilogue — one launch, no extra HBM round
 # trips. Pure-JAX / XLA backends apply them unfused with identical
-# semantics (activations are the kernel-epilogue set: none/relu/gelu/silu).
+# semantics (activations are the kernel-epilogue set: none/relu/gelu/
+# gelu_erf/silu).
 # Every backend is differentiable: the Pallas ops carry a custom VJP with
 # sliding-window backward kernels (DESIGN.md §6), so whisper's frontend,
 # mamba's conv and llava's patch_embed train unchanged under any backend.
@@ -218,13 +229,6 @@ def apply_rope(x: Array, positions: Array, theta: float) -> Array:
     return out.astype(x.dtype)
 
 
-def sinusoidal_positions(length: int, d_model: int) -> Array:
-    pos = jnp.arange(length, dtype=jnp.float32)[:, None]
-    dim = jnp.arange(0, d_model, 2, dtype=jnp.float32)[None, :]
-    ang = pos / jnp.power(10_000.0, dim / d_model)
-    return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-
-
 # ---------------------------------------------------------------------------
 # Attention parameter defs
 # ---------------------------------------------------------------------------
@@ -259,11 +263,23 @@ def mlp_defs(cfg: ModelConfig, d_ff: int | None = None) -> dict[str, ParamDef]:
     }
 
 
+def add_bias(y: Array, p, name: str) -> Array:
+    """``y`` (B, L, ...) plus the parameter tree's flat bias ``name`` laid
+    out over ``y``'s trailing axes, where the tree holds one (whisper's
+    projections). A tree without it (qwen3's) adds no operation."""
+    b = p.get(name)
+    if b is None:
+        return y
+    return y + b.reshape(y.shape[2:]).astype(y.dtype)
+
+
 def mlp_apply(p, x: Array, cfg: ModelConfig) -> Array:
     f = act_fn(cfg.activation)
     if cfg.activation == "gelu_plain":
-        h = f(jnp.einsum("bld,df->blf", x, p["wi"].astype(x.dtype)))
-        return jnp.einsum("blf,fd->bld", h, p["wo"].astype(x.dtype))
+        h = f(add_bias(jnp.einsum("bld,df->blf", x, p["wi"].astype(x.dtype)),
+                       p, "bi"))
+        return add_bias(jnp.einsum("blf,fd->bld", h, p["wo"].astype(x.dtype)),
+                        p, "bo")
     g = jnp.einsum("bld,df->blf", x, p["wg"].astype(x.dtype))
     u = jnp.einsum("bld,df->blf", x, p["wu"].astype(x.dtype))
     return jnp.einsum("blf,fd->bld", f(g) * u, p["wd"].astype(x.dtype))
@@ -275,9 +291,9 @@ def mlp_apply(p, x: Array, cfg: ModelConfig) -> Array:
 
 def _qkv(p, x: Array, cfg: ModelConfig, positions: Array, rope: bool = True):
     dt = x.dtype
-    q = jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt))
-    k = jnp.einsum("bld,dhk->blhk", x, p["wk"].astype(dt))
-    v = jnp.einsum("bld,dhk->blhk", x, p["wv"].astype(dt))
+    q = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt)), p, "bq")
+    k = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wk"].astype(dt)), p, "bk")
+    v = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wv"].astype(dt)), p, "bv")
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
@@ -432,7 +448,14 @@ def attention_train(
     else:
         out = full_attention(q, k, v, causal=True)
     out = rt.constrain(out, "batch", None, "heads", "head_dim")
-    return jnp.einsum("blhk,hkd->bld", out, p["wo"].astype(x.dtype))
+    return attn_out(p, out)
+
+
+def attn_out(p, o: Array) -> Array:
+    """The attention output projection of ``o`` (B, L, H, hd), with its
+    bias where the tree holds one."""
+    return add_bias(jnp.einsum("blhk,hkd->bld", o, p["wo"].astype(o.dtype)),
+                    p, "bo")
 
 
 def dequant_cache_leaf(cache: dict, name: str, dtype) -> Array:
@@ -498,8 +521,12 @@ def attention_decode(
         s = jnp.where(mask[None, None, None], s, -jnp.inf)
         w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
         out = jnp.einsum("bkglm,bmkd->blkgd", w, v).reshape(*q.shape)
-    y = jnp.einsum("blhk,hkd->bld", out, p["wo"].astype(x.dtype))
-    return y, new
+    return attn_out(p, out), new
+
+
+#: the decode kernel's name on cross-attention reads, so the device trace
+#: tells them from self-attention reads
+CROSS_DECODE_KERNEL = "cross_attention_decode_pallas"
 
 
 def cross_attention_defs(cfg: ModelConfig) -> dict[str, ParamDef]:
@@ -511,13 +538,13 @@ def cross_attention(
 ) -> Array:
     """Decoder cross-attention; enc_kv = precomputed (k, v) of encoder output."""
     dt = x.dtype
-    q = jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt))
+    q = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt)), p, "bq")
     k, v = enc_kv
     if x.shape[1] > cfg.attn_chunk:
         out = chunked_attention(q, k, v, causal=False, chunk=cfg.attn_chunk)
     else:
         out = full_attention(q, k, v, causal=False)
-    return jnp.einsum("blhk,hkd->bld", out, p["wo"].astype(dt))
+    return attn_out(p, out.astype(dt))
 
 
 def cross_attention_decode(
@@ -536,7 +563,7 @@ def cross_attention_decode(
     0 — the same result as softmax over zero values.
     """
     dt = x.dtype
-    q = jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt))
+    q = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt)), p, "bq")
     if cfg.attn_decode == "fused":
         from repro.kernels import ops
 
@@ -546,6 +573,7 @@ def cross_attention_decode(
         out = ops.attention_decode(
             q[:, 0], cache["xk"], cache["xv"], lengths=lengths,
             k_scale=cache.get("xk_scale"), v_scale=cache.get("xv_scale"),
+            name=CROSS_DECODE_KERNEL,
         ).astype(dt)[:, None]
     else:
         xk = dequant_cache_leaf(cache, "xk", dt)
@@ -560,13 +588,15 @@ def cross_attention_decode(
         # softmax stays finite — output 0, same as the fused path's guard
         valid = valid | ~valid.any(axis=1, keepdims=True)
         out = full_attention(q, xk, xv, causal=False, kv_mask=valid)
-    return jnp.einsum("blhk,hkd->bld", out, p["wo"].astype(dt))
+    return attn_out(p, out)
 
 
 def encode_kv(p, enc_out: Array, cfg: ModelConfig) -> tuple[Array, Array]:
     dt = enc_out.dtype
-    k = jnp.einsum("bld,dhk->blhk", enc_out, p["wk"].astype(dt))
-    v = jnp.einsum("bld,dhk->blhk", enc_out, p["wv"].astype(dt))
+    k = add_bias(jnp.einsum("bld,dhk->blhk", enc_out, p["wk"].astype(dt)),
+                 p, "bk")
+    v = add_bias(jnp.einsum("bld,dhk->blhk", enc_out, p["wv"].astype(dt)),
+                 p, "bv")
     return k, v
 
 

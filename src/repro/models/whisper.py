@@ -1,26 +1,40 @@
-"""Whisper-medium (arXiv:2212.04356): encoder-decoder with conv frontend.
+"""Whisper (arXiv:2212.04356; huggingface.co/openai/whisper-medium): an
+encoder-decoder transformer over 80-bin log-mel frames.
 
-The conv frontend (conv1d k=3 GELU, conv1d k=3 stride-2 GELU over 80-dim
-mels) is the paper-technique site: both convs route through the sliding
-conv1d path (custom k=3 regime). Per the assignment the frontend is a STUB
-for the dry-run shapes — ``input_specs`` provides precomputed frame
-embeddings (B, S_enc, d_model) — but ``conv_frontend`` is fully implemented
-and exercised by tests/benchmarks with ``frontend="audio"``.
+Encoder: the conv frontend — conv1d k=3, then conv1d k=3 at stride 2, each
+padded 1, with a bias and the exact (erf) GELU, through the paper's
+sliding conv path (``cfg.conv_backend``) — so 3000 mel frames (30 s) give
+1500 positions; whisper's fixed sinusoids; pre-LayerNorm blocks of
+bidirectional self-attention and a GELU MLP; a final LayerNorm.
 
-Encoder: bidirectional self-attention + plain-GELU MLP, sinusoidal
-positions. Decoder: causal self-attention + cross-attention + MLP. Shapes
-split ``seq_len`` evenly between encoder frames and decoder tokens.
+Decoder: the token embedding plus learned positions
+(``cfg.max_target_positions`` of them), pre-LayerNorm blocks of causal
+self-attention, cross-attention over the encoder output and a GELU MLP, a
+final LayerNorm, and an output head tied to the token embedding.
+
+Every LayerNorm has a bias (eps ``cfg.norm_eps``). The q, v and output
+projections of each attention and both MLP projections have biases; the
+k projections have none. Biases are flat vectors (``layers.add_bias``).
+
+Serving (``launch/serve.py``) runs :meth:`Whisper.encode_cross` — the
+frontend, the encoder and each decoder layer's cross K/V — as a program of
+its own, once per batch, then :meth:`Whisper.prefill` over the decoder prompt
+against those cross K/V (``launch/steps.make_prefill_step`` chains the
+two for callers that want one step), then :meth:`Whisper.decode_step`,
+which reads them from the cache. The decoder prompt's length does not
+depend on the audio's. Training (``loss``) takes mels, or precomputed
+frame embeddings (B, S, d_model) in the frontend's place
+(``train --audio-frontend stub``).
 """
 from __future__ import annotations
 
 import functools
-from typing import Any
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.conv import conv1d_sliding
 from repro.distributed.sharding import ParamDef, Runtime, abstract_params, init_params
 from repro.models import layers as L
 from repro.models.common import kv_cache_defs, scan_blocks, stack_defs
@@ -28,6 +42,18 @@ from repro.models.common import kv_cache_defs, scan_blocks, stack_defs
 Array = jax.Array
 
 N_MELS = 80
+#: encoder positions of a 30 s window: 3000 mel frames after the stride-2 conv
+N_AUDIO_CTX = 1500
+
+
+def sinusoids(length: int, channels: int) -> Array:
+    """Whisper's encoder positions: position p, channel i < channels/2
+    gets sin(p·e^(-i·ln(10000)/(channels/2 - 1))), the second half the
+    cos of the same angles."""
+    inc = math.log(10_000.0) / (channels // 2 - 1)
+    inv = jnp.exp(-inc * jnp.arange(channels // 2, dtype=jnp.float32))
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
 def frontend_defs(cfg: ModelConfig) -> dict[str, ParamDef]:
@@ -54,15 +80,48 @@ def conv_frontend(p, mels: Array, cfg: ModelConfig) -> Array:
     precision = cfg.conv_precision
     x = L.conv1d_bias_act(
         mels, p["conv1_w"], p["conv1_b"],
-        activation="gelu", padding="SAME", backend=cfg.conv_backend,
+        activation="gelu_erf", padding="SAME", backend=cfg.conv_backend,
         precision=precision, site="whisper/conv1",
     )
     x = L.conv1d_bias_act(
         x, p["conv2_w"], p["conv2_b"],
-        activation="gelu", stride=2, padding="SAME",
+        activation="gelu_erf", stride=2, padding="SAME",
         backend=cfg.conv_backend, precision=precision, site="whisper/conv2",
     )
     return x
+
+
+def _norm_defs(d: int) -> dict[str, ParamDef]:
+    return {"scale": ParamDef((d,), ("embed",), init="ones"),
+            "bias": ParamDef((d,), ("embed",), init="zeros")}
+
+
+def _norm(x: Array, p, cfg: ModelConfig) -> Array:
+    return L.layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
+
+
+def _attn_defs(cfg: ModelConfig) -> dict[str, ParamDef]:
+    """Attention projections with whisper's biases: q, v and out, no k."""
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+    defs = L.cross_attention_defs(cfg)
+    defs["bq"] = ParamDef((h * hd,), (None,), init="zeros")
+    defs["bv"] = ParamDef((kv * hd,), (None,), init="zeros")
+    defs["bo"] = ParamDef((cfg.d_model,), ("embed",), init="zeros")
+    return defs
+
+
+def _mlp_defs(cfg: ModelConfig) -> dict[str, ParamDef]:
+    defs = L.mlp_defs(cfg)
+    defs["bi"] = ParamDef((cfg.d_ff,), ("mlp",), init="zeros")
+    defs["bo"] = ParamDef((cfg.d_model,), ("embed",), init="zeros")
+    return defs
+
+
+def _attention(q, k, v, cfg: ModelConfig, *, causal: bool) -> Array:
+    if k.shape[1] > cfg.attn_chunk:
+        return L.chunked_attention(q, k, v, causal=causal,
+                                   chunk=cfg.attn_chunk)
+    return L.full_attention(q, k, v, causal=causal)
 
 
 class Whisper:
@@ -74,32 +133,34 @@ class Whisper:
     def _enc_block_defs(self):
         cfg = self.cfg
         return {
-            "attn_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-            "attn": L.attention_defs(cfg),
-            "mlp_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-            "mlp": L.mlp_defs(cfg),
+            "attn_norm": _norm_defs(cfg.d_model),
+            "attn": _attn_defs(cfg),
+            "mlp_norm": _norm_defs(cfg.d_model),
+            "mlp": _mlp_defs(cfg),
         }
 
     def _dec_block_defs(self):
         cfg = self.cfg
         return {
-            "attn_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-            "attn": L.attention_defs(cfg),
-            "xattn_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-            "xattn": L.cross_attention_defs(cfg),
-            "mlp_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
-            "mlp": L.mlp_defs(cfg),
+            "attn_norm": _norm_defs(cfg.d_model),
+            "attn": _attn_defs(cfg),
+            "xattn_norm": _norm_defs(cfg.d_model),
+            "xattn": _attn_defs(cfg),
+            "mlp_norm": _norm_defs(cfg.d_model),
+            "mlp": _mlp_defs(cfg),
         }
 
     def param_defs(self):
         cfg = self.cfg
         return {
             "embed": L.embed_defs(cfg),
+            "dec_pos": ParamDef((cfg.max_target_positions, cfg.d_model),
+                                (None, "embed"), init="normal"),
             "frontend": frontend_defs(cfg),
             "encoder": stack_defs(self._enc_block_defs(), cfg.encoder_layers),
-            "enc_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
+            "enc_norm": _norm_defs(cfg.d_model),
             "decoder": stack_defs(self._dec_block_defs(), cfg.num_layers),
-            "final_norm": ParamDef((cfg.d_model,), ("embed",), init="ones"),
+            "final_norm": _norm_defs(cfg.d_model),
         }
 
     def init(self, rng):
@@ -110,27 +171,23 @@ class Whisper:
 
     # -- encoder --------------------------------------------------------------
     def encode(self, params, frames: Array) -> Array:
-        """frames: precomputed embeddings (B, S_enc, d) [stub] or mels
-        (B, T, 80) [conv frontend]."""
+        """The encoder's output (B, S, d) of mels (B, 2S, 80), through the
+        conv frontend, or of precomputed frame embeddings (B, S, d)."""
         cfg, rt = self.cfg, self.rt
-        if frames.shape[-1] == N_MELS:
-            frames = conv_frontend(params["frontend"], frames, cfg)
         x = frames.astype(L.cdtype(cfg))
-        x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model).astype(x.dtype)
+        if x.shape[-1] == N_MELS:
+            x = conv_frontend(params["frontend"], x, cfg)
+        x = x + sinusoids(x.shape[1], cfg.d_model).astype(x.dtype)
         x = rt.constrain(x, "batch", "seq", None)
 
         def body(carry, lp):
             xc, aux = carry
             xc = rt.constrain(xc, "batch", "seq", None)
-            h = L.rms_norm(xc, lp["attn_norm"], cfg.norm_eps)
-            positions = jnp.arange(xc.shape[1])[None, :]
-            q, k, v = L._qkv(lp["attn"], h, cfg, positions, rope=False)
-            if xc.shape[1] > cfg.attn_chunk:
-                o = L.chunked_attention(q, k, v, causal=False, chunk=cfg.attn_chunk)
-            else:
-                o = L.full_attention(q, k, v, causal=False)
-            xc = xc + jnp.einsum("blhk,hkd->bld", o, lp["attn"]["wo"].astype(xc.dtype))
-            h = L.rms_norm(xc, lp["mlp_norm"], cfg.norm_eps)
+            h = _norm(xc, lp["attn_norm"], cfg)
+            q, k, v = L._qkv(lp["attn"], h, cfg, None, rope=False)
+            o = _attention(q, k, v, cfg, causal=False)
+            xc = xc + L.attn_out(lp["attn"], o)
+            h = _norm(xc, lp["mlp_norm"], cfg)
             xc = rt.constrain(xc + L.mlp_apply(lp["mlp"], h, cfg),
                               "batch", "seq", None)
             return (xc, aux)
@@ -139,19 +196,43 @@ class Whisper:
             (x, jnp.zeros((), jnp.float32)), params["encoder"], body,
             remat=cfg.remat != "none",
         )
-        return L.rms_norm(x, params["enc_norm"], cfg.norm_eps)
+        return _norm(x, params["enc_norm"], cfg)
+
+    def encode_cross(self, params, frames: Array) -> dict[str, Array]:
+        """The serving encoder program: :meth:`encode`, then each decoder
+        layer's cross-attention K/V of its output, stacked over the layers
+        as ``xk``/``xv`` (layers, B, S, KV, hd) in the parameter dtype."""
+        enc = self.encode(params, frames)
+        pd = jnp.dtype(self.cfg.param_dtype)
+
+        def layer(_, lp):
+            k, v = L.encode_kv(lp["xattn"], enc, self.cfg)
+            return None, {"xk": k.astype(pd), "xv": v.astype(pd)}
+
+        return jax.lax.scan(layer, None, params["decoder"])[1]
 
     # -- decoder --------------------------------------------------------------
+    def _embed(self, params, tokens: Array, pos) -> Array:
+        """Token embeddings plus the learned positions from ``pos`` on."""
+        cfg, L_ = self.cfg, tokens.shape[1]
+        if L_ > cfg.max_target_positions:
+            raise ValueError(f"{cfg.name}'s decoder takes at most "
+                             f"{cfg.max_target_positions} positions, not {L_}")
+        x = L.embed_tokens(params["embed"], tokens, cfg)
+        pe = jax.lax.dynamic_slice_in_dim(params["dec_pos"], pos, L_, axis=0)
+        x = x + pe[None].astype(x.dtype)
+        return self.rt.constrain(x, "batch", "seq", None)
+
     def _dec_block(self, carry, lp, enc_out):
         cfg, rt = self.cfg, self.rt
         x, aux = carry
         x = rt.constrain(x, "batch", "seq", None)
-        h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        h = _norm(x, lp["attn_norm"], cfg)
         x = x + L.attention_train(lp["attn"], h, cfg, rt, rope=False)
-        h = L.rms_norm(x, lp["xattn_norm"], cfg.norm_eps)
+        h = _norm(x, lp["xattn_norm"], cfg)
         kv = L.encode_kv(lp["xattn"], enc_out, cfg)
         x = x + L.cross_attention(lp["xattn"], h, kv, cfg, rt)
-        h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        h = _norm(x, lp["mlp_norm"], cfg)
         x = rt.constrain(x + L.mlp_apply(lp["mlp"], h, cfg),
                          "batch", "seq", None)
         return (x, aux)
@@ -159,37 +240,32 @@ class Whisper:
     def loss(self, params, batch):
         cfg, rt = self.cfg, self.rt
         enc_out = self.encode(params, batch["frames"])
-        x = L.embed_tokens(params["embed"], batch["tokens"], cfg)
-        x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model).astype(x.dtype)
-        x = rt.constrain(x, "batch", "seq", None)
+        x = self._embed(params, batch["tokens"], 0)
         body = functools.partial(self._dec_block, enc_out=enc_out)
         x, _ = scan_blocks(
             (x, jnp.zeros((), jnp.float32)), params["decoder"], body,
             remat=cfg.remat != "none",
         )
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params["final_norm"], cfg)
         return L.chunked_ce_loss(params["embed"], x, batch["labels"], cfg, rt)
 
     # -- serving ----------------------------------------------------------------
-    def cache_defs(self, batch: int, seq: int):
-        """Decoder self-attn cache (seq//2) + cross KV (seq//2 enc frames).
+    def cache_defs(self, batch: int, seq: int, enc_len: int = N_AUDIO_CTX):
+        """Decoder self-attention K/V of ``seq`` positions, and the cross
+        K/V of ``enc_len`` encoder positions (a 30 s window unless told).
         With ``cfg.kv_quant == "int8"`` every sequence-proportional leaf
         (self-attn k/v AND the cross xk/xv) stores int8 + per-row scale."""
         from repro.models.common import kv_scale_defs
 
         cfg = self.cfg
-        s_dec, s_enc = seq // 2, seq // 2
-        d = kv_cache_defs(cfg, cfg.num_layers, batch, s_dec)
+        d = kv_cache_defs(cfg, cfg.num_layers, batch, seq)
         kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         dt = "int8" if cfg.kv_quant == "int8" else None
-        d["xk"] = ParamDef(
-            (cfg.num_layers, batch, s_enc, kv, hd),
-            ("layers", "batch", "kv_seq", "kv_heads", None), init="zeros",
-            dtype=dt)
-        d["xv"] = ParamDef(
-            (cfg.num_layers, batch, s_enc, kv, hd),
-            ("layers", "batch", "kv_seq", "kv_heads", None), init="zeros",
-            dtype=dt)
+        for name in ("xk", "xv"):
+            d[name] = ParamDef(
+                (cfg.num_layers, batch, enc_len, kv, hd),
+                ("layers", "batch", "kv_seq", "kv_heads", None), init="zeros",
+                dtype=dt)
         # per-slot REAL encoder length (the cross cache is zero-padded past
         # it): written once at prefill, read by the fused ragged attention
         # every decode step — recomputing it would re-scan the whole cache
@@ -201,67 +277,59 @@ class Whisper:
         return d
 
     def prefill(self, params, batch):
-        """Encode frames + decoder prompt forward: last-token logits, decoder
-        self-attn KV cache, and per-layer cross KV of the encoder output."""
+        """The decoder prompt's forward against the encoder's cross K/V,
+        ``batch["cross"]`` (:meth:`encode_cross`'s output). Returns the last
+        token's logits and the decode cache's pieces: each layer's self K/V
+        of the prompt, the cross K/V, and each slot's encoder length."""
         cfg, rt = self.cfg, self.rt
-        enc_out = self.encode(params, batch["frames"])
-        x = L.embed_tokens(params["embed"], batch["tokens"], cfg)
-        x = x + L.sinusoidal_positions(x.shape[1], cfg.d_model).astype(x.dtype)
-        x = rt.constrain(x, "batch", "seq", None)
-        Ltot = x.shape[1]
+        cross = batch["cross"]
+        x = self._embed(params, batch["tokens"], 0)
+        pd = jnp.dtype(cfg.param_dtype)
 
-        def body(carry, lp):
+        def body(carry, inp):
             xc, aux = carry
-            h = L.rms_norm(xc, lp["attn_norm"], cfg.norm_eps)
-            positions = jnp.arange(Ltot)[None, :]
-            q, k, v = L._qkv(lp["attn"], h, cfg, positions, rope=False)
-            if Ltot > cfg.attn_chunk:
-                o = L.chunked_attention(q, k, v, causal=True, chunk=cfg.attn_chunk)
-            else:
-                o = L.full_attention(q, k, v, causal=True)
-            xc = xc + jnp.einsum("blhk,hkd->bld", o,
-                                 lp["attn"]["wo"].astype(xc.dtype))
-            h = L.rms_norm(xc, lp["xattn_norm"], cfg.norm_eps)
-            xk, xv = L.encode_kv(lp["xattn"], enc_out, cfg)
-            xc = xc + L.cross_attention(lp["xattn"], h, (xk, xv), cfg, rt)
-            h = L.rms_norm(xc, lp["mlp_norm"], cfg.norm_eps)
+            lp, xkv = inp
+            h = _norm(xc, lp["attn_norm"], cfg)
+            q, k, v = L._qkv(lp["attn"], h, cfg, None, rope=False)
+            o = _attention(q, k, v, cfg, causal=True)
+            xc = xc + L.attn_out(lp["attn"], o)
+            h = _norm(xc, lp["xattn_norm"], cfg)
+            xc = xc + L.cross_attention(lp["xattn"], h,
+                                        (xkv["xk"], xkv["xv"]), cfg, rt)
+            h = _norm(xc, lp["mlp_norm"], cfg)
             xc = xc + L.mlp_apply(lp["mlp"], h, cfg)
-            pd = jnp.dtype(cfg.param_dtype)
-            enc_len = jnp.full((xc.shape[0],), enc_out.shape[1], jnp.int32)
-            return (xc, aux), {"k": k.astype(pd), "v": v.astype(pd),
-                               "xk": xk.astype(pd), "xv": xv.astype(pd),
-                               "enc_len": enc_len}
+            return (xc, aux), {"k": k.astype(pd), "v": v.astype(pd)}
 
         (x, _), cache = scan_blocks(
-            (x, jnp.zeros((), jnp.float32)), params["decoder"], body,
+            (x, jnp.zeros((), jnp.float32)), (params["decoder"], cross), body,
             remat=cfg.remat != "none", collect=True,
         )
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params["final_norm"], cfg)
         logits = L.lm_logits(params["embed"], x[:, -1:], cfg)
+        B, S = cross["xk"].shape[1:3]
+        cache.update(cross,
+                     enc_len=jnp.full((cfg.num_layers, B), S, jnp.int32))
         return logits, cache
 
     def decode_step(self, params, cache, tokens, pos):
         cfg, rt = self.cfg, self.rt
-        x = L.embed_tokens(params["embed"], tokens, cfg)
-        B = x.shape[0]
-        pe = L.sinusoidal_positions(cache["k"].shape[2], cfg.d_model)
-        x = x + jax.lax.dynamic_slice_in_dim(pe, pos, 1, axis=0)[None].astype(x.dtype)
+        x = self._embed(params, tokens, pos)
 
         def body(carry, inp):
             xc, _ = carry
             lp, cl = inp
-            h = L.rms_norm(xc, lp["attn_norm"], cfg.norm_eps)
+            h = _norm(xc, lp["attn_norm"], cfg)
             sub = {n: cl[n] for n in ("k", "v", "k_scale", "v_scale")
                    if n in cl}
             y, kv_new = L.attention_decode(
                 lp["attn"], h, sub, pos, cfg, rt, rope=False)
             xc = xc + y
-            h = L.rms_norm(xc, lp["xattn_norm"], cfg.norm_eps)
+            h = _norm(xc, lp["xattn_norm"], cfg)
             # ragged fused read over the padded (possibly int8) encoder
             # cache: the valid-prefix masking, zero-cache fallback, and
             # int8 code handling live in cross_attention_decode
             xc = xc + L.cross_attention_decode(lp["xattn"], h, cl, cfg)
-            h = L.rms_norm(xc, lp["mlp_norm"], cfg.norm_eps)
+            h = _norm(xc, lp["mlp_norm"], cfg)
             xc = xc + L.mlp_apply(lp["mlp"], h, cfg)
             new = dict(cl)
             new.update(kv_new)
@@ -269,5 +337,5 @@ class Whisper:
 
         (x, _), new_cache = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], cache))
-        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        x = _norm(x, params["final_norm"], cfg)
         return L.lm_logits(params["embed"], x, cfg), new_cache

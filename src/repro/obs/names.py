@@ -66,15 +66,18 @@ METRICS = frozenset({
 })
 
 #: span names (obs.span); the serve loop's nest as the code runs them:
-#: serve.generate > serve.prefill > serve.prefill.{forward,cache,sample},
-#: then per token serve.decode_step > serve.decode.{dispatch,wait,screen,
-#: sample} (the benchmark's serve-loop metrics read these)
+#: serve.generate > serve.prefill > serve.prefill.{forward,cache,sample}
+#: (an encoder-decoder's encoder program in serve.prefill.forward >
+#: serve.prefill.encode), then per token serve.decode_step >
+#: serve.decode.{dispatch,wait,screen,sample} (the benchmark's serve-loop
+#: metrics read these)
 SPANS = frozenset({
     "autotune.search",
     "autotune.candidate",
     "serve.generate",
     "serve.prefill",
     "serve.prefill.forward",
+    "serve.prefill.encode",
     "serve.prefill.cache",
     "serve.prefill.sample",
     "serve.decode_step",

@@ -7,7 +7,7 @@ import pytest
 
 from repro.configs import ARCH_IDS, get_config, smoke_config
 from repro.distributed.sharding import ParamDef
-from repro.launch.steps import make_train_step
+from repro.launch.steps import make_prefill_step, make_train_step
 from repro.models import build_model
 from repro.optim import OptConfig, init_opt_state
 
@@ -82,7 +82,7 @@ def test_prefill(arch, rng):
     params = model.init(jax.random.key(0))
     batch = make_batch(cfg, rng)
     batch.pop("labels")
-    logits, cache = jax.jit(model.prefill)(params, batch)
+    logits, cache = jax.jit(make_prefill_step(model))(params, batch)
     assert logits.shape == (B, 1, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
 

@@ -456,11 +456,13 @@ def test_serve_pallas_fault_token_exact():
     rng = np.random.default_rng(0)
     prompts = jnp.asarray(rng.integers(2, cfg.vocab_size, size=(1, 6)),
                           jnp.int32)
+    mels = jnp.asarray(rng.uniform(-1, 1, (1, 32, 80)), jnp.float32)
 
     def run(backend):
         model = build_model(cfg.replace(conv_backend=backend), Runtime())
         params = model.init(jax.random.key(0))
-        toks, _ = generate(model, params, prompts, gen_len=4, cache_len=16)
+        toks, _ = generate(model, params, prompts, gen_len=4, cache_len=16,
+                           audio=mels)
         return np.asarray(toks)
 
     want = run("sliding")  # the jax twin is this exact code path
@@ -576,11 +578,13 @@ def test_serve_runtime_fault_demotes_rejits_token_exact():
     rng = np.random.default_rng(0)
     prompts = jnp.asarray(rng.integers(2, cfg.vocab_size, size=(1, 6)),
                           jnp.int32)
+    mels = jnp.asarray(rng.uniform(-1, 1, (1, 32, 80)), jnp.float32)
 
     def run(backend):
         model = build_model(cfg.replace(conv_backend=backend), Runtime())
         params = model.init(jax.random.key(0))
-        toks, _ = generate(model, params, prompts, gen_len=4, cache_len=16)
+        toks, _ = generate(model, params, prompts, gen_len=4, cache_len=16,
+                           audio=mels)
         return np.asarray(toks)
 
     want = run("sliding")
@@ -607,21 +611,24 @@ def test_serve_probation_repromotes_across_requests(monkeypatch):
     rng = np.random.default_rng(0)
     prompts = jnp.asarray(rng.integers(2, cfg.vocab_size, size=(1, 6)),
                           jnp.int32)
+    mels = jnp.asarray(rng.uniform(-1, 1, (1, 32, 80)), jnp.float32)
 
     clean_model = build_model(cfg.replace(conv_backend="sliding"), Runtime())
     clean_params = clean_model.init(jax.random.key(0))
     want, _ = generate(clean_model, clean_params, prompts, gen_len=4,
-                       cache_len=16)
+                       cache_len=16, audio=mels)
 
     model = build_model(cfg.replace(conv_backend="sliding_pallas"), Runtime())
     params = model.init(jax.random.key(0))
     with faults.inject("pallas_runtime", site="conv1d", times=1):
-        got1, _ = generate(model, params, prompts, gen_len=4, cache_len=16)
+        got1, _ = generate(model, params, prompts, gen_len=4, cache_len=16,
+                           audio=mels)
         np.testing.assert_array_equal(np.asarray(got1), np.asarray(want))
         # non-mutating check: is_demoted would consume the probe grant
         br = HEALTH.breaker("conv1d", "pallas")
         assert br is not None and br.state == "open" and br.trips == 1
-        got2, _ = generate(model, params, prompts, gen_len=4, cache_len=16)
+        got2, _ = generate(model, params, prompts, gen_len=4, cache_len=16,
+                           audio=mels)
     np.testing.assert_array_equal(np.asarray(got2), np.asarray(want))
     acts = {e.action for e in HEALTH.events_for("conv1d")}
     assert "probe:pallas" in acts and "repromote:pallas" in acts

@@ -39,7 +39,7 @@ def _epi(y, b, act):
 @pytest.mark.parametrize(
     "K,stride,act",
     [(3, 1, "gelu"), (5, 1, "relu"), (7, 2, "silu"), (20, 1, "none"),
-     (3, 2, "none"), (9, 3, "gelu")],
+     (3, 2, "none"), (9, 3, "gelu"), (3, 1, "gelu_erf"), (3, 2, "gelu_erf")],
 )
 def test_conv1d_grad_regimes(rng, K, stride, act):
     """custom/generic/compound regimes × stride × fused epilogue: grads of
@@ -96,12 +96,13 @@ def test_conv1d_grad_channel_blocked(rng):
 
     def f(x, w, b):
         y = ops.conv1d(
-            x, w, bias=b, activation="gelu", tile_l=16, cin_block=10,
+            x, w, bias=b, activation="gelu_erf", tile_l=16, cin_block=10,
             cout_block=16, interpret=True,
         )
         return jnp.sum(y ** 2)
 
-    f_ref = lambda x, w, b: jnp.sum(_epi(ref.conv1d_ref(x, w), b, "gelu") ** 2)
+    f_ref = lambda x, w, b: jnp.sum(
+        _epi(ref.conv1d_ref(x, w), b, "gelu_erf") ** 2)
     got = jax.grad(f, (0, 1, 2))(x, w, b)
     want = jax.grad(f_ref, (0, 1, 2))(x, w, b)
     for g, r in zip(got, want):
@@ -121,7 +122,7 @@ def test_conv1d_grad_512ch_auto_blocked(rng):
         _close_scaled(g, r, rtol=1e-4, atol_frac=1e-5)
 
 
-@pytest.mark.parametrize("act", ["gelu", "none"])
+@pytest.mark.parametrize("act", ["gelu", "none", "gelu_erf"])
 def test_conv1d_grad_bf16(rng, act):
     x = jnp.asarray(rng.normal(size=(2, 100, 16))).astype(jnp.bfloat16)
     w = jnp.asarray(rng.normal(size=(3, 16, 16))).astype(jnp.bfloat16)
@@ -296,11 +297,11 @@ def test_layers_conv_bias_act_trainable(rng):
     b = jnp.asarray(rng.normal(size=(16,)).astype(np.float32))
     for backend in ["sliding_pallas"]:
         f = lambda x, w, b: jnp.sum(
-            conv1d_bias_act(x, w, b, activation="gelu", padding="SAME",
+            conv1d_bias_act(x, w, b, activation="gelu_erf", padding="SAME",
                             backend=backend) ** 2
         )
         f_ref = lambda x, w, b: jnp.sum(
-            conv1d_bias_act(x, w, b, activation="gelu", padding="SAME",
+            conv1d_bias_act(x, w, b, activation="gelu_erf", padding="SAME",
                             backend="xla") ** 2
         )
         got = jax.grad(f, (0, 1, 2))(x, w, b)

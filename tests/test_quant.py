@@ -565,12 +565,12 @@ def test_chained_frontend_bit_exact_vs_oracle_composition(rng):
     qw1, qw2 = fr["conv1_w"], fr["conv2_w"]
     y1 = qconv.conv1d_q(
         mels, qw1, fr["conv1_b"], mode="w8a8", x_scale=qw1.x_scale,
-        out_scale=qw1.out_scale, padding="SAME", activation="gelu",
+        out_scale=qw1.out_scale, padding="SAME", activation="gelu_erf",
     )
     assert y1.dtype == jnp.int8  # the inter-conv activation IS int8
     y2 = qconv.conv1d_q(
         y1, qw2, fr["conv2_b"], mode="w8a8", x_scale=qw2.x_scale,
-        padding="SAME", stride=2, activation="gelu",
+        padding="SAME", stride=2, activation="gelu_erf",
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(y2),
                                rtol=1e-5, atol=1e-6)

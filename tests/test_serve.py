@@ -1,5 +1,5 @@
 """Serving driver (`repro.launch.serve`): generate(), slot recycling,
-enc-dec cache clamping, and the int8 KV cache.
+enc-dec cache sizing and per-request audio, and the int8 KV cache.
 
 The int8 KV contract (DESIGN.md §8): cache leaves with a ``kv_seq`` axis
 store int8 codes + a per-(position, head) f32 scale over the head_dim row;
@@ -38,6 +38,14 @@ def _prompts(cfg, B=2, P=16, seed=0):
     return jnp.asarray(
         rng.integers(2, cfg.vocab_size, size=(B, P)), jnp.int32
     )
+
+
+def _audio(cfg, B=2, T=32, seed=0):
+    """Each request's own mels for an audio model, else None."""
+    if cfg.family != "audio":
+        return None
+    rng = np.random.default_rng(seed + 100)
+    return jnp.asarray(rng.uniform(-1, 1, (B, T, 80)), jnp.float32)
 
 
 # -- done-mask slot recycling -------------------------------------------------
@@ -152,16 +160,121 @@ def test_fused_step_follows_the_jitted_decode(swap):
         assert serve._FUSED[model][0] is serve._JITTED[model][1]
 
 
-# -- enc-dec cache clamp ------------------------------------------------------
+# -- enc-dec cache sizing and per-request audio ----------------------------
 
 def test_whisper_generate_clamps_encdec_cache():
-    """Whisper splits the cache between encoder frames and decoder tokens;
-    generate() must clamp an undersized cache_len instead of crashing on a
-    negative pad (the seed bug)."""
+    """The cross cache takes one row per encoder position of the audio
+    (two mel frames each); the decoder's self-attention rows are prompt +
+    gen, whatever the audio, with an undersized cache_len clamped up."""
     cfg, model, params = _smoke_model("whisper-medium")
-    prompts = _prompts(cfg, B=1, P=8)
-    toks, _ = generate(model, params, prompts, gen_len=4, cache_len=4)
-    assert toks.shape == (1, 4)
+    P, G = 8, 4
+    prompts = _prompts(cfg, B=1, P=P)
+    assert serve.resolve_cache_len(cfg, 4, P, G) == P + G
+    for frames in (32, 64):
+        audio = _audio(cfg, 1, frames)
+        _, cache = serve.prefill_cache(model, params, prompts, cache_len=4,
+                                       gen_len=G, audio=audio)
+        assert cache["xk"].shape[2] == cache["xv"].shape[2] == frames // 2
+        assert cache["k"].shape[2] == cache["v"].shape[2] == P + G
+        assert bool((cache["enc_len"] == frames // 2).all())
+        toks, _ = generate(model, params, prompts, gen_len=G, cache_len=4,
+                           audio=audio)
+        assert toks.shape == (1, G)
+
+
+def test_whisper_decoder_positions_bound_the_request():
+    """The decoder has 448 learned positions: a prompt + gen beyond them is
+    refused, not served on clamped positions."""
+    cfg = get_config("whisper-medium")
+    assert serve.resolve_cache_len(cfg, 0, 4, 444) == 448
+    with pytest.raises(ValueError, match="448"):
+        serve.resolve_cache_len(cfg, 0, 4, 445)
+
+
+def test_audio_family_needs_audio_and_others_refuse_it():
+    cfg, model, params = _smoke_model("whisper-medium")
+    prompts = _prompts(cfg, B=2, P=4)
+    with pytest.raises(ValueError, match="audio"):
+        generate(model, params, prompts, gen_len=3, cache_len=8)
+    with pytest.raises(ValueError, match="audio"):
+        generate(model, params, prompts, gen_len=3, cache_len=8,
+                 audio=_audio(cfg, B=3))
+    qcfg, qmodel, qparams = _smoke_model()
+    with pytest.raises(ValueError, match="no audio"):
+        generate(qmodel, qparams, _prompts(qcfg), gen_len=3, cache_len=24,
+                 audio=_audio(cfg))
+
+
+def test_whisper_slot_in_a_batch_equals_the_request_alone():
+    """Each slot uses its own audio: a request's first-token logits in a
+    batch of two (the other slot with other audio and prompt) equal its
+    logits served alone, and the two slots' differ. Float32 smoke model,
+    so the batch's and the single request's programs agree to float32
+    rounding of logits of order 1 (atol 1e-4 leaves a wide margin)."""
+    cfg, model, params = _smoke_model("whisper-medium")
+    prompts, audio = _prompts(cfg, B=2, P=4), _audio(cfg, B=2)
+    both, _ = serve.prefill_cache(model, params, prompts, cache_len=8,
+                                  audio=audio)
+    alone, _ = serve.prefill_cache(model, params, prompts[:1], cache_len=8,
+                                   audio=audio[:1])
+    np.testing.assert_allclose(np.asarray(both[0]), np.asarray(alone[0]),
+                               rtol=1e-4, atol=1e-4)
+    other, _ = serve.prefill_cache(
+        model, params, prompts, cache_len=8,
+        audio=audio.at[1].set(audio[0]))
+    # slot 1's prompt over slot 0's audio moves slot 1's logits
+    assert np.abs(np.asarray(other[1]) - np.asarray(both[1])).max() > 1e-3
+
+
+def test_replay_refuses_an_audio_journal_record(tmp_path):
+    """The journal records prompts, not audio: an audio model's in-flight
+    record is refused with a clear error, not replayed without audio."""
+    cfg, model, params = _smoke_model("whisper-medium")
+    journal = serve.RequestJournal(tmp_path)
+    journal.begin("req0", _prompts(cfg, B=1, P=4), gen_len=3, cache_len=8,
+                  temperature=0.0, seed=0)
+    with pytest.raises(ValueError, match="audio"):
+        serve.replay_pending(model, params, journal)
+    assert journal.pending()  # still in flight, not ended
+
+
+#: sha256 of qwen3-1.7b's smoke prefill and int8-cache decode programs as
+#: lowered by the tree before whisper's biases entered the shared layers
+QWEN3_SMOKE_LOWERED = (
+    "cea7068eb583c3b4e48413cd906936b45bc1cc6fdfb7e3965a3ba77264b34625",
+    "51c2d4735b27306639ce351d97b2b33dafbbc1b9c7d7201f50a24460d078f77f",
+)
+
+
+def test_qwen3_programs_take_no_bias_ops(monkeypatch):
+    """qwen3-1.7b's tree holds no bias leaves, so the shared layers' bias
+    hooks (``layers.add_bias``, whisper's biases) add no operation: its
+    smoke prefill and int8-cache decode programs lower to the same text
+    with the hooks taken out, and to the text the layers lowered to
+    before the hooks (pinned by hash; it moves with the JAX version)."""
+    import hashlib
+
+    from repro.models import layers
+
+    cfg, model, params = _smoke_model(kv_quant="int8")
+    leaves = jax.tree_util.tree_flatten_with_path(params)[0]
+    names = {getattr(k, "key", None) for path, _ in leaves for k in path}
+    assert not names & {"bq", "bk", "bv", "bo", "bi"}
+    prompts = _prompts(cfg)
+    cache = init_cache_concrete(model, 2, 24)
+    tok = prompts[:, :1]
+
+    def lowered():
+        return (jax.jit(model.prefill).lower(params, {"tokens": prompts})
+                .as_text(),
+                jax.jit(model.decode_step).lower(params, cache, tok,
+                                                 jnp.int32(16)).as_text())
+
+    with_hooks = lowered()
+    assert tuple(hashlib.sha256(t.encode()).hexdigest()
+                 for t in with_hooks) == QWEN3_SMOKE_LOWERED
+    monkeypatch.setattr(layers, "add_bias", lambda y, p, name: y)
+    assert lowered() == with_hooks
 
 
 # -- int8 KV cache ------------------------------------------------------------
@@ -217,12 +330,14 @@ def test_kv_cache_int8_decode_runs_other_families(arch):
     """Hybrid (jamba: KV + recurrent states) and enc-dec (whisper: xk/xv
     cross leaves) decode end to end with the int8 cache."""
     cfg, model, params = _smoke_model(arch, kv_quant="int8")
-    prompts = _prompts(cfg, B=1, P=8)
-    toks, _ = generate(model, params, prompts, gen_len=4, cache_len=24)
+    prompts, audio = _prompts(cfg, B=1, P=8), _audio(cfg, B=1)
+    toks, _ = generate(model, params, prompts, gen_len=4, cache_len=24,
+                       audio=audio)
     assert toks.shape == (1, 4)
 
     fp = build_model(cfg.replace(kv_quant="fp"), Runtime())
-    toks_fp, _ = generate(fp, params, prompts, gen_len=4, cache_len=24)
+    toks_fp, _ = generate(fp, params, prompts, gen_len=4, cache_len=24,
+                          audio=audio)
     np.testing.assert_array_equal(np.asarray(toks), np.asarray(toks_fp))
 
 
@@ -238,11 +353,13 @@ def test_fused_decode_read_matches_view_path(arch, kvq):
     on the fp cache too (the fp variant shares the kernel)."""
     cfg, model, params = _smoke_model(arch, kv_quant=kvq)  # fused default
     assert cfg.attn_decode == "fused"
-    prompts = _prompts(cfg)
-    toks_fused, _ = generate(model, params, prompts, gen_len=6, cache_len=24)
+    prompts, audio = _prompts(cfg), _audio(cfg)
+    toks_fused, _ = generate(model, params, prompts, gen_len=6, cache_len=24,
+                             audio=audio)
 
     view = build_model(cfg.replace(attn_decode="view"), Runtime())
-    toks_view, _ = generate(view, params, prompts, gen_len=6, cache_len=24)
+    toks_view, _ = generate(view, params, prompts, gen_len=6, cache_len=24,
+                            audio=audio)
     np.testing.assert_array_equal(
         np.asarray(toks_fused), np.asarray(toks_view)
     )

@@ -62,7 +62,7 @@ def test_conv1d_fp(one_chip, name, cin, stride):
     _compile(
         one_chip,
         lambda x, w, b: ops.conv1d(
-            x, w, bias=b, stride=stride, padding="SAME", activation="gelu",
+            x, w, bias=b, stride=stride, padding="SAME", activation="gelu_erf",
             interpret=False,
         ),
         ((1, FRAMES, cin), BF16), ((3, cin, D_MODEL), BF16),
@@ -75,7 +75,7 @@ def test_conv1d_w8a8(one_chip, name, cin, stride):
     _compile(
         one_chip,
         lambda x, w, s, xs, b: ops.conv1d(
-            x, w, bias=b, stride=stride, padding="SAME", activation="gelu",
+            x, w, bias=b, stride=stride, padding="SAME", activation="gelu_erf",
             precision="w8a8", w_scale=s, x_scale=xs, interpret=False,
         ),
         ((1, FRAMES, cin), BF16), ((3, cin, D_MODEL), I8),
