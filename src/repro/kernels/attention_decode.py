@@ -50,6 +50,20 @@ DEFAULT_BLOCK_S = 128
 BLOCK_S_CANDIDATES = (32, 64, 128, 256, 512)
 
 
+def block_rows(rows: int) -> int:
+    """Cache rows to allocate for ``rows`` positions the kernel reads whole
+    every step (whisper's cross K/V). More rows than the smallest
+    candidate block round up to a multiple of the largest, which every
+    candidate divides: neither the default block nor a tuned one then
+    leaves a remainder for ``_pad_seq`` to copy on each call. Fewer are
+    one block as they stand. The rows past ``rows`` are zero and masked
+    by the caller's ``lengths``."""
+    if rows <= min(BLOCK_S_CANDIDATES):
+        return rows
+    block = max(BLOCK_S_CANDIDATES)
+    return -(-rows // block) * block
+
+
 def _pad_seq(a: jax.Array | None, to: int) -> jax.Array | None:
     """Zero-pad axis 1 (kv_seq) up to ``to`` rows. Zero codes AND zero
     scales on the pad — masked out by ``lengths`` anyway."""

@@ -126,7 +126,9 @@ def pad_cache_to_defs(cache, full, defs):
     ``ParamDef.axes`` — not by guessing which axis happens to equal the
     prompt length (a shape-coincidence heuristic misfires whenever another
     axis equals it). Leaves without a ``kv_seq`` axis (recurrent conv/ssm
-    states) pass through unchanged."""
+    states) pass through unchanged. A leaf whose defs merge its trailing
+    axes (whisper's lane-dense cross K/V, quantized per head first) is
+    then reshaped to them."""
 
     def pad(c, d, df):
         if "kv_seq" in df.axes:
@@ -135,7 +137,7 @@ def pad_cache_to_defs(cache, full, defs):
                 pads = [(0, 0)] * c.ndim
                 pads[ax] = (0, d.shape[ax] - c.shape[ax])
                 c = jnp.pad(c, pads)
-        return c.astype(d.dtype)
+        return c.reshape(d.shape).astype(d.dtype)
 
     return jax.tree.map(pad, cache, full, defs)
 
@@ -157,16 +159,33 @@ _JITTED = weakref.WeakKeyDictionary()
 
 
 def _jitted(model):
+    """(prefill, decode) of the model. The decode callable returns
+    ``(logits, cache)``: the jitted ``decode_step`` returns the leaves it
+    wrote, and the callable merges them into the cache it was given
+    outside the jit, so a leaf the step only read comes back as the very
+    array passed in (:func:`_fused_decode` keeps only the others)."""
     fns = _JITTED.get(model)
     if fns is None:
         mref = weakref.ref(model)
+        step = jax.jit(lambda params, cache, tok, pos: mref().decode_step(
+            params, cache, tok, pos))
+
+        def decode(params, cache, tok, pos):
+            logits, written = step(params, cache, tok, pos)
+            return logits, {**cache, **written}
+
         fns = (
             jax.jit(lambda params, batch: mref().prefill(params, batch)),
-            jax.jit(lambda params, cache, tok, pos: mref().decode_step(
-                params, cache, tok, pos)),
+            decode,
         )
         _JITTED[model] = fns
     return fns
+
+
+def held_nbytes(cache, written) -> int:
+    """Bytes of the cache leaves a decode step read but did not return."""
+    return sum(a.nbytes for n, v in cache.items() if n not in written
+               for a in jax.tree.leaves(v))
 
 
 # per-model encoder program (enc-dec), held beside the prefill callable it
@@ -416,11 +435,14 @@ def _fused_decode(model):
     """One compiled program per decode step: the decode callable in
     ``_JITTED[model]``, then :func:`_sample` on its logits. Called as
     ``step(params, cache, tok, pos, done, temperature, key, poison,
-    nan_guard=...)`` and returns ``(tok, done, bad, cache, key)``, no
-    logits. ``temperature`` is an operand (None with ``key`` None when
-    greedy), so a new temperature compiles nothing; ``poison`` is None
-    unless a fault fires, so the poisoned variant compiles only under
-    chaos runs."""
+    nan_guard=...)`` and returns ``(tok, done, bad, written, key)``, no
+    logits: ``written`` holds only the cache leaves the decode callable
+    returned anew, which the caller merges into its cache. A leaf the
+    step only reads (whisper's cross K/V) comes back as the input itself
+    and is not an output of the program, so no step copies it.
+    ``temperature`` is an operand (None with ``key`` None when greedy), so
+    a new temperature compiles nothing; ``poison`` is None unless a fault
+    fires, so the poisoned variant compiles only under chaos runs."""
     decode = _jitted(model)[1]
     held = _FUSED.get(model)
     if held is None or held[0] is not decode:
@@ -428,11 +450,14 @@ def _fused_decode(model):
 
         def step(params, cache, tok, pos, done, temperature, key, poison, *,
                  nan_guard):
-            logits, cache = decode(params, cache, tok, pos)
+            logits, new = decode(params, cache, tok, pos)
+            # the merge into the cache happens outside the decode jit
+            # (_jitted), so identity tells a written leaf from a read one
+            written = {n: a for n, a in new.items() if a is not cache.get(n)}
             tok, done, bad, key = _sample(logits, done, temperature, key,
                                           poison, eos=eos,
                                           nan_guard=nan_guard)
-            return tok, done, bad, cache, key
+            return tok, done, bad, written, key
 
         held = (decode, jax.jit(step, static_argnames="nan_guard"))
         _FUSED[model] = held
@@ -519,15 +544,21 @@ def _generate_once(model, params, prompts, *, audio, gen_len, cache_len,
             with obs.span("serve.decode.dispatch"):
                 poison = _poison(nan_guard, B)
                 was_done = done
-                tok, done, bad, cache, key = step(
+                tok, done, bad, written, key = step(
                     params, cache, tok, jnp.int32(P + i), done, temp, key,
                     poison, nan_guard=nan_guard,
                 )
+                if i == 0:
+                    # metadata only (no device sync): what the program
+                    # reads each step and leaves where it is
+                    reg.gauge("serve.decode.held_cache_bytes").set(
+                        float(held_nbytes(cache, written)), arch=cfg.name)
+                cache = {**cache, **written}
             with obs.span("serve.decode.wait"):
                 # direct-output sync: guarantees an in-compiled-call
                 # failure surfaces HERE as XlaRuntimeError instead of
                 # feeding garbage to the next step
-                jax.block_until_ready((tok, done, bad, cache))
+                jax.block_until_ready((tok, done, bad, written))
             with obs.span("serve.decode.screen"):
                 if nan_guard:
                     _screen(bad, was_done, i, cfg.name)

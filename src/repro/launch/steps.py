@@ -83,10 +83,12 @@ def make_prefill_step(model) -> Callable:
 
 
 def make_serve_step(model) -> Callable:
+    """One decode step over the whole cache: the leaves ``decode_step``
+    wrote, merged into the cache it was given."""
     def serve_step(params, cache, batch):
-        logits, new_cache = model.decode_step(
+        logits, written = model.decode_step(
             params, cache, batch["tokens"], batch["pos"]
         )
-        return logits, new_cache
+        return logits, {**cache, **written}
 
     return serve_step

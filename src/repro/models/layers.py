@@ -551,9 +551,11 @@ def cross_attention_decode(
     p, x: Array, cache: dict[str, Array], cfg: ModelConfig
 ) -> Array:
     """Single-token decoder cross-attention against the cached (padded,
-    possibly int8) encoder K/V. x: (B, 1, D); cache holds ``xk``/``xv``
-    (+ ``_scale`` siblings in int8 mode) and ``enc_len`` — the per-slot
-    REAL encoder length, written once at prefill.
+    possibly int8) encoder K/V. x: (B, 1, D); cache holds ``xk``/``xv``,
+    (B, S, KV·hd) as the serving cache stores them or (B, S, KV, hd) as
+    prefill emits them (+ per-(row, head) ``_scale`` siblings (B, S, KV,
+    1) in int8 mode), and ``enc_len`` — the per-slot REAL encoder length,
+    written once at prefill.
 
     The cross cache is padded past ``enc_len`` with zero rows (zero codes
     AND zero scales in int8 mode); a zero key scores logit 0, not -inf,
@@ -564,6 +566,12 @@ def cross_attention_decode(
     """
     dt = x.dtype
     q = add_bias(jnp.einsum("bld,dhk->blhk", x, p["wq"].astype(dt)), p, "bq")
+    # per-head view of the lane-dense leaves: the kernel wrapper merges
+    # the heads back, so the two reshapes cancel and the compiled read
+    # takes the stored slice as it is
+    B, S = cache["xk"].shape[:2]
+    heads = {n: cache[n].reshape(B, S, cfg.num_kv_heads, -1)
+             for n in ("xk", "xv")}
     if cfg.attn_decode == "fused":
         from repro.kernels import ops
 
@@ -571,18 +579,17 @@ def cross_attention_decode(
         # prefill — no per-step cache scan to recover a static number
         lengths = cache["enc_len"].astype(jnp.int32)
         out = ops.attention_decode(
-            q[:, 0], cache["xk"], cache["xv"], lengths=lengths,
+            q[:, 0], heads["xk"], heads["xv"], lengths=lengths,
             k_scale=cache.get("xk_scale"), v_scale=cache.get("xv_scale"),
             name=CROSS_DECODE_KERNEL,
         ).astype(dt)[:, None]
     else:
-        xk = dequant_cache_leaf(cache, "xk", dt)
-        xv = dequant_cache_leaf(cache, "xv", dt)
+        xk = dequant_cache_leaf({**cache, **heads}, "xk", dt)
+        xv = dequant_cache_leaf({**cache, **heads}, "xv", dt)
         # same validity definition as the fused path: positions past the
         # prefill-recorded encoder length are padding (an any-nonzero scan
         # heuristic here could diverge from the fused read on a real
         # all-zero K row)
-        S = xk.shape[1]
         valid = jnp.arange(S)[None, :] < cache["enc_len"][:, None]
         # enc_len 0 (structural zero cache): attend every (zero) row so the
         # softmax stays finite — output 0, same as the fused path's guard

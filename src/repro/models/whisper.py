@@ -21,7 +21,8 @@ frontend, the encoder and each decoder layer's cross K/V — as a program of
 its own, once per batch, then :meth:`Whisper.prefill` over the decoder prompt
 against those cross K/V (``launch/steps.make_prefill_step`` chains the
 two for callers that want one step), then :meth:`Whisper.decode_step`,
-which reads them from the cache. The decoder prompt's length does not
+which reads them from the cache and returns only the self-attention
+leaves it wrote. The decoder prompt's length does not
 depend on the audio's. Training (``loss``) takes mels, or precomputed
 frame embeddings (B, S, d_model) in the frontend's place
 (``train --audio-frontend stub``).
@@ -44,6 +45,8 @@ Array = jax.Array
 N_MELS = 80
 #: encoder positions of a 30 s window: 3000 mel frames after the stride-2 conv
 N_AUDIO_CTX = 1500
+#: the cache leaves a decode step writes: the decoder's self-attention K/V
+SELF_LEAVES = ("k", "v", "k_scale", "v_scale")
 
 
 def sinusoids(length: int, channels: int) -> Array:
@@ -252,20 +255,32 @@ class Whisper:
     # -- serving ----------------------------------------------------------------
     def cache_defs(self, batch: int, seq: int, enc_len: int = N_AUDIO_CTX):
         """Decoder self-attention K/V of ``seq`` positions, and the cross
-        K/V of ``enc_len`` encoder positions (a 30 s window unless told).
-        With ``cfg.kv_quant == "int8"`` every sequence-proportional leaf
-        (self-attn k/v AND the cross xk/xv) stores int8 + per-row scale."""
+        K/V of ``enc_len`` encoder positions (a 30 s window unless told),
+        allocated at the decode kernel's block multiple
+        (``attention_decode.block_rows``: 1500 → 1536) so that prefill pads
+        them once and no decode step pads them again; ``enc_len`` masks
+        the rows past the real length. The cross K/V are stored
+        lane-dense, (layers, B, rows, KV·hd), the layout the decode kernel
+        reads: stored as (…, KV, hd) the TPU keeps the rows minor-most (so
+        64-wide heads are not padded to 128 lanes), and each layer's slice
+        would be relaid out on every step. With ``cfg.kv_quant ==
+        "int8"`` every sequence-proportional leaf (self-attn k/v AND the
+        cross xk/xv) stores int8 + a per-(row, head) scale."""
+        from repro.kernels.attention_decode import block_rows
         from repro.models.common import kv_scale_defs
 
         cfg = self.cfg
         d = kv_cache_defs(cfg, cfg.num_layers, batch, seq)
         kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         dt = "int8" if cfg.kv_quant == "int8" else None
+        rows = (cfg.num_layers, batch, block_rows(enc_len))
+        heads = ParamDef((*rows, kv, hd),
+                         ("layers", "batch", "kv_seq", "kv_heads", None),
+                         init="zeros", dtype=dt)
         for name in ("xk", "xv"):
-            d[name] = ParamDef(
-                (cfg.num_layers, batch, enc_len, kv, hd),
-                ("layers", "batch", "kv_seq", "kv_heads", None), init="zeros",
-                dtype=dt)
+            d[name] = ParamDef((*rows, kv * hd),
+                               ("layers", "batch", "kv_seq", "kv_heads"),
+                               init="zeros", dtype=dt)
         # per-slot REAL encoder length (the cross cache is zero-padded past
         # it): written once at prefill, read by the fused ragged attention
         # every decode step — recomputing it would re-scan the whole cache
@@ -273,7 +288,7 @@ class Whisper:
             (cfg.num_layers, batch), ("layers", "batch"), init="zeros",
             dtype="int32")
         if dt:
-            d.update(kv_scale_defs({"xk": d["xk"], "xv": d["xv"]}))
+            d.update(kv_scale_defs({"xk": heads, "xv": heads}))
         return d
 
     def prefill(self, params, batch):
@@ -312,15 +327,21 @@ class Whisper:
         return logits, cache
 
     def decode_step(self, params, cache, tokens, pos):
+        """One token for every slot: its logits, and the cache leaves the
+        step wrote, the self-attention K/V (and their int8 scales). The
+        cross K/V and ``enc_len``, written once at prefill, reach each
+        layer as read-only scan inputs and are not returned, so a compiled
+        step neither restacks nor outputs (copies) them; the caller merges
+        what is returned into the cache it holds."""
         cfg, rt = self.cfg, self.rt
         x = self._embed(params, tokens, pos)
+        own = {n: cache[n] for n in SELF_LEAVES if n in cache}
+        held = {n: a for n, a in cache.items() if n not in own}
 
         def body(carry, inp):
             xc, _ = carry
-            lp, cl = inp
+            lp, sub, cl = inp
             h = _norm(xc, lp["attn_norm"], cfg)
-            sub = {n: cl[n] for n in ("k", "v", "k_scale", "v_scale")
-                   if n in cl}
             y, kv_new = L.attention_decode(
                 lp["attn"], h, sub, pos, cfg, rt, rope=False)
             xc = xc + y
@@ -331,11 +352,10 @@ class Whisper:
             xc = xc + L.cross_attention_decode(lp["xattn"], h, cl, cfg)
             h = _norm(xc, lp["mlp_norm"], cfg)
             xc = xc + L.mlp_apply(lp["mlp"], h, cfg)
-            new = dict(cl)
-            new.update(kv_new)
-            return (xc, jnp.zeros((), jnp.float32)), new
+            return (xc, jnp.zeros((), jnp.float32)), kv_new
 
-        (x, _), new_cache = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], cache))
+        (x, _), written = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)),
+            (params["decoder"], own, held))
         x = _norm(x, params["final_norm"], cfg)
-        return L.lm_logits(params["embed"], x, cfg), new_cache
+        return L.lm_logits(params["embed"], x, cfg), written

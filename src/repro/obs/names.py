@@ -47,6 +47,7 @@ METRICS = frozenset({
     "serve.slots_recyclable",
     "serve.slot_occupancy",
     "serve.kv_cache_bytes",
+    "serve.decode.held_cache_bytes",  # cache a decode step reads, not outputs
     "serve.quarantined",           # poisoned slots eos-masked + recycled
     "serve.shed",                  # requests rejected at admission
     "serve.journal_replayed",      # in-flight requests replayed on restart

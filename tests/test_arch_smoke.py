@@ -68,10 +68,14 @@ def test_decode_step(arch, rng):
         is_leaf=lambda x: isinstance(x, ParamDef),
     )
     tok = jnp.ones((B, 1), jnp.int32)
-    logits, new_cache = jax.jit(model.decode_step)(params, cache, tok, jnp.int32(0))
+    logits, written = jax.jit(model.decode_step)(params, cache, tok,
+                                                 jnp.int32(0))
     assert logits.shape == (B, 1, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all()), f"{arch} decode logits not finite"
-    # cache structurally unchanged
+    # the step returns cache leaves, which merge into a cache of the same
+    # structure
+    assert set(written) <= set(cache)
+    new_cache = {**cache, **written}
     assert jax.tree.structure(cache) == jax.tree.structure(new_cache)
 
 
@@ -105,7 +109,8 @@ def test_prefill_decode_consistency(arch, rng):
     decode = jax.jit(model.decode_step)
     lg = None
     for i in range(P):
-        lg, cache = decode(params, cache, prompt[:, i : i + 1], jnp.int32(i))
+        lg, written = decode(params, cache, prompt[:, i : i + 1], jnp.int32(i))
+        cache = {**cache, **written}
     np.testing.assert_allclose(
         np.asarray(lg[:, 0], np.float32),
         np.asarray(lg_pre[:, 0], np.float32),

@@ -111,6 +111,22 @@ def test_pallas_zero_length_slot_is_zero(rng):
     assert float(jnp.abs(out[1]).max()) > 0.0
 
 
+@pytest.mark.parametrize("rows", [1, 16, 32, 33, 200, 1500, 1536])
+def test_block_rows_leave_no_remainder_for_any_block(rows):
+    """A cache allocated at ``block_rows`` rows divides into whole blocks
+    of the default and of every tuned candidate, so ``_pad_seq`` has
+    nothing to copy; at most one block of the largest candidate is added,
+    and a cache no longer than the smallest block is read as one block as
+    it stands."""
+    got = A.block_rows(rows)
+    if rows <= min(A.BLOCK_S_CANDIDATES):
+        assert got == rows
+        return
+    assert rows <= got < rows + max(A.BLOCK_S_CANDIDATES)
+    for block in (A.DEFAULT_BLOCK_S, *A.BLOCK_S_CANDIDATES):
+        assert got % block == 0, block
+
+
 # -- compiled blocked-scan path (the CPU serving evaluation) ------------------
 
 @pytest.mark.parametrize("quant", [True, False])

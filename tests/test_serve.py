@@ -147,8 +147,8 @@ def test_fused_step_follows_the_jitted_decode(swap):
     prefill, decode = serve._jitted(model)
 
     def wrapped(params, cache, tok, pos):
-        logits, cache = decode(params, cache, tok, pos)
-        return logits.at[..., forced].set(1e9), cache
+        logits, new = decode(params, cache, tok, pos)
+        return logits.at[..., forced].set(1e9), new
 
     serve._JITTED[model] = (prefill, wrapped)
     toks, _ = generate(model, params, prompts, **kw)
@@ -275,6 +275,147 @@ def test_qwen3_programs_take_no_bias_ops(monkeypatch):
                  for t in with_hooks) == QWEN3_SMOKE_LOWERED
     monkeypatch.setattr(layers, "add_bias", lambda y, p, name: y)
     assert lowered() == with_hooks
+
+
+#: sha256 of qwen3-1.7b's smoke int8-cache fused decode step (greedy,
+#: nan_guard) as lowered by the tree before decode steps returned only the
+#: leaves they wrote
+QWEN3_SMOKE_FUSED_LOWERED = (
+    "537a88ff7d1d074b2466d8924006418f89aa6cfa4127527429dcfcf84ff6abc1")
+
+
+def _lower_fused(model, params, cache, P, *, key=None, temperature=None):
+    B = cache["k"].shape[1]
+    return serve._fused_decode(model).lower(
+        params, cache, jnp.ones((B, 1), jnp.int32), jnp.int32(P),
+        jnp.zeros((B,), bool), temperature, key, None, nan_guard=True)
+
+
+@pytest.mark.parametrize("arch", ["whisper-medium", "qwen3-1.7b"])
+def test_fused_decode_step_outputs_only_the_leaves_it_writes(arch):
+    """The compiled decode step's results are the token, the done and bad
+    masks, the key, and the cache leaves the model's step writes: for
+    whisper the self-attention K/V and their scales, never a cross leaf
+    (``xk``, ``xv``, their scales, ``enc_len``), which it only reads; for
+    qwen3 the whole cache, in a program that lowers to the same text as
+    before (pinned by hash; it moves with the JAX version)."""
+    import hashlib
+
+    cfg, model, params = _smoke_model(arch, kv_quant="int8")
+    B, P, G = 2, 8, 4
+    prompts = _prompts(cfg, B=B, P=P)
+    _, cache = serve.prefill_cache(model, params, prompts, cache_len=24,
+                                   gen_len=G, audio=_audio(cfg, B=B, T=64))
+    lowered = _lower_fused(model, params, cache, P,
+                           key=jax.random.key(0), temperature=jnp.float32(.7))
+    tok, done, bad, written, key = lowered.out_info
+    assert (tok.shape, done.shape, bad.shape) == ((B, 1), (B,), (B,))
+    assert key.shape == ()
+    if arch == "qwen3-1.7b":
+        assert set(written) == set(cache)
+        text = _lower_fused(model, params, cache, P).as_text()
+        assert (hashlib.sha256(text.encode()).hexdigest()
+                == QWEN3_SMOKE_FUSED_LOWERED)
+        return
+    assert set(written) == {"k", "v", "k_scale", "v_scale"}
+    cross = {(cache[n].shape, cache[n].dtype)
+             for n in ("xk", "xv", "xk_scale", "xv_scale", "enc_len")}
+    outs = {(o.shape, o.dtype) for o in jax.tree.leaves(lowered.out_info)}
+    assert cross.isdisjoint(outs)
+    for n, o in written.items():
+        assert (o.shape, o.dtype) == (cache[n].shape, cache[n].dtype)
+
+
+def _cross_pads(text, B, rows):
+    """The lowered program's pads of a (B, rows, ...) per-layer cross leaf."""
+    return [ln for ln in text.splitlines()
+            if "stablehlo.pad" in ln and f": (tensor<{B}x{rows}x" in ln]
+
+
+def test_whisper_cross_rows_round_to_the_kernel_block(monkeypatch, tmp_path):
+    """200 encoder positions take 512 cross rows (the kernel's block
+    multiple), padded once at prefill: ``enc_len`` keeps 200, the rows
+    past it are zero codes and zero scales, and with a tuned 128-row block
+    the compiled decode step pads no cross leaf, where a cache of the
+    unrounded 200 rows is padded on every step. The greedy tokens equal
+    the unrounded cache's. ``serve.decode.held_cache_bytes`` reads the
+    cross leaves' bytes, which no step returns; qwen3's step returns its
+    whole cache and reads 0."""
+    from repro.kernels import attention_decode as attn_dec
+    from repro.kernels import autotune
+    from repro.obs import REGISTRY
+
+    cfg, _, params = _smoke_model("whisper-medium", kv_quant="int8")
+    B, P, G, rows = 2, 4, 4, 200
+    KV, H, D = cfg.num_kv_heads, cfg.num_heads, cfg.resolved_head_dim
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "tune.json"))
+    autotune.invalidate()
+    for S in (rows, 512):
+        autotune.record(autotune.attn_dec_key(B, S, KV, H // KV, D, "int8"),
+                        {"block_s": 128})
+    prompts = _prompts(cfg, B=B, P=P)
+    audio = _audio(cfg, B=B, T=2 * rows)
+    held = REGISTRY.gauge("serve.decode.held_cache_bytes")
+
+    def serve_once():
+        model = build_model(cfg, Runtime())
+        _, cache = serve.prefill_cache(model, params, prompts,
+                                       cache_len=P + G, gen_len=G,
+                                       audio=audio)
+        toks, _ = generate(model, params, prompts, gen_len=G,
+                           cache_len=P + G, audio=audio)
+        text = _lower_fused(model, params, cache, P).as_text()
+        return cache, np.asarray(toks), text, held.value(arch=cfg.name)
+
+    cache, toks, text, held_bytes = serve_once()
+    assert cache["xk"].shape[2] == cache["xv_scale"].shape[2] == 512
+    assert bool((cache["enc_len"] == rows).all())
+    for n in ("xk", "xv", "xk_scale", "xv_scale"):
+        assert bool((cache[n][:, :, rows:] == 0).all()), n
+        assert bool((cache[n][:, :, :rows] != 0).any()), n
+    assert _cross_pads(text, B, 512) == []
+    assert held_bytes == sum(cache[n].nbytes for n in (
+        "xk", "xv", "xk_scale", "xv_scale", "enc_len"))
+
+    monkeypatch.setattr(attn_dec, "block_rows", lambda r: r)
+    ucache, utoks, utext, _ = serve_once()
+    autotune.invalidate()
+    assert ucache["xk"].shape[2] == rows
+    assert len(_cross_pads(utext, B, rows)) > 0
+    np.testing.assert_array_equal(toks, utoks)
+
+    qcfg, qmodel, qparams = _smoke_model(kv_quant="int8")
+    generate(qmodel, qparams, _prompts(qcfg), gen_len=3, cache_len=24)
+    assert held.value(arch=qcfg.name) == 0.0
+
+
+@pytest.mark.parametrize("attn_decode", ["fused", "view"])
+def test_whisper_cross_cache_is_stored_lane_dense(attn_decode):
+    """The served cross K/V are (layers, B, rows, KV·hd), the layout the
+    decode kernel reads, with one scale per (row, head): prefill quantizes
+    each head's row and then merges the heads. A decode step over them
+    gives the same logits, bit for bit, as over the same codes with the
+    heads apart, (…, KV, hd) as prefill emits them, in the fused read and
+    in the dequantized view alike."""
+    cfg, _, params = _smoke_model("whisper-medium", kv_quant="int8",
+                                  attn_decode=attn_decode)
+    model = build_model(cfg, Runtime())
+    B, P, G = 2, 4, 3
+    KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    _, cache = serve.prefill_cache(model, params, _prompts(cfg, B=B, P=P),
+                                   cache_len=P + G, gen_len=G,
+                                   audio=_audio(cfg, B=B, T=64))
+    rows = (cfg.num_layers, B, 32)
+    assert cache["xk"].shape == cache["xv"].shape == (*rows, KV * hd)
+    assert cache["xk_scale"].shape == cache["xv_scale"].shape == (*rows, KV, 1)
+    apart = {**cache, **{n: cache[n].reshape(*rows, KV, hd)
+                         for n in ("xk", "xv")}}
+    step = jax.jit(model.decode_step)
+    tok = jnp.ones((B, 1), jnp.int32)
+    dense, _ = step(params, cache, tok, jnp.int32(P))
+    heads, _ = step(params, apart, tok, jnp.int32(P))
+    assert bool(jnp.isfinite(dense).all())
+    np.testing.assert_array_equal(np.asarray(dense), np.asarray(heads))
 
 
 # -- int8 KV cache ------------------------------------------------------------

@@ -28,6 +28,9 @@ FRAMES, D_MODEL = 3000, 1024
 ATTN = {
     "qwen3-1.7b": dict(B=8, S=4096, KV=8, G=2, D=128),
     "whisper-medium": dict(B=4, S=1500, KV=16, G=1, D=64),
+    # the benchmark cell's cross read: 16 slots, 1500 rows allocated at
+    # the kernel's block multiple (attention_decode.block_rows)
+    "whisper-medium.cross": dict(B=16, S=1536, KV=16, G=1, D=64),
 }
 
 
@@ -152,3 +155,47 @@ def test_attention_decode(one_chip, model, kind):
 
     _compile(one_chip, read, ((B, KV * G, D), BF16), cache, cache,
              ((B,), jnp.int32), *scales)
+
+
+def test_whisper_decode_step_reads_the_cross_cache_as_stored(
+        one_chip, monkeypatch):
+    """whisper-medium's fused decode step at the benchmark cell's shapes
+    (16 slots, 30 s of audio, 4 + 128 self rows, int8 cache) outputs no
+    cross leaf, and hands each layer's cross K/V slice to the Pallas read
+    as it is cut from the stacked cache: the cross leaves are stored
+    lane-dense (layers, B, rows, KV·hd), so no copy relays a slice out for
+    the kernel, as one did on every step for (…, KV, hd) leaves, which
+    the TPU stores rows-minor."""
+    import re
+
+    from repro.configs import get_config
+    from repro.distributed.sharding import ParamDef, Runtime
+    from repro.launch import serve
+    from repro.models import build_model
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    cfg = get_config("whisper-medium").replace(
+        kv_quant="int8", attn_decode="fused")
+    model = build_model(cfg, Runtime())
+    B, P, G = 16, 4, 128
+    abstract = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    params = jax.tree.map(lambda a: abstract(a.shape, a.dtype),
+                          model.abstract())
+    cache = jax.tree.map(
+        lambda d: abstract(d.shape, jnp.dtype(d.dtype or cfg.param_dtype)),
+        model.cache_defs(B, P + G, enc_len=1500),
+        is_leaf=lambda x: isinstance(x, ParamDef))
+    rows, lanes = 1536, cfg.num_kv_heads * cfg.resolved_head_dim
+    assert cache["xk"].shape == cache["xv"].shape == (
+        cfg.num_layers, B, rows, lanes)
+    lowered = serve._fused_decode(model).lower(
+        params, cache, abstract((B, 1), jnp.int32), abstract((), jnp.int32),
+        abstract((B,), jnp.bool_), None, None, None, nan_guard=True)
+    assert set(lowered.out_info[3]) == {"k", "v", "k_scale", "v_scale"}
+    text = lowered.compile().as_text()
+    assert "cross_attention_decode_pallas" in text
+    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+    relaid = re.compile(
+        rf"%(copy|transpose)\S* = s8\[{B},{rows},({lanes}|{kv},{hd})\]")
+    assert [ln for ln in text.splitlines() if relaid.search(ln)] == []
