@@ -36,12 +36,17 @@ Call sites across the framework use these wrappers, which
 
 ``backend`` selects the paper's technique (``sliding``) vs the baselines
 (``im2col_gemm`` fused-VMEM, ``im2col_hbm`` true-bloat, ``xla``).
+
+``conv1d``, ``conv1d_depthwise`` and ``conv2d`` only gather their
+arguments: one dispatcher, ``_conv``, makes the choices above for all
+three, reading each op's kernels, twins and tuning key from one table,
+``_CONV``.
 """
 from __future__ import annotations
 
 import functools
 import sys
-from typing import Literal, NamedTuple
+from typing import Callable, Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +68,8 @@ from repro.kernels import (
     sliding_pool,
 )
 from repro.kernels.sliding_conv1d import apply_activation
+from repro.quant import qconv
+from repro.quant.apply import quantize_depthwise_weight
 
 Backend = Literal["sliding", "im2col_gemm", "im2col_hbm", "xla"]
 # "fp" = full-precision path; the int8 modes dispatch to the quantized
@@ -177,11 +184,20 @@ def _guard_quant_scales(site, x, w, w_scale, x_scale):
     return None, False
 
 
-def _pad1d(x, padding, k, dilation):
-    lo, hi = core_conv._resolve_pad_1d(padding, k, dilation)
-    if lo or hi:
-        x = jnp.pad(x, ((0, 0), (lo, hi), (0, 0)))
+def _pad(x, w, padding, dilation):
+    """Apply SAME/CAUSAL/VALID/explicit padding to a 1-D or 2-D input, so
+    the kernels see a VALID conv."""
+    if x.ndim == 3:
+        pads = [core_conv._resolve_pad_1d(padding, w.shape[0], dilation)]
+    else:
+        pads = list(core_conv._resolve_pad_2d(padding, *w.shape[:2], dilation))
+    if any(lo or hi for lo, hi in pads):
+        x = jnp.pad(x, ((0, 0), *pads, (0, 0)))
     return x
+
+
+def _dilated(dilation) -> bool:
+    return dilation > 1 if isinstance(dilation, int) else dilation != (1, 1)
 
 
 def epilogue_unfused(y, bias, activation):
@@ -207,8 +223,8 @@ def _auto_block(c: int, explicit: int | None) -> int | None:
 def _tuned_fill(key: str, **fields):
     """Fill None fields from the autotune cache entry for this shape key.
 
-    Resolution precedence (shared by conv1d and conv2d): explicit caller
-    argument → tuned cache entry → caller-side default."""
+    Resolution precedence (every conv and the decode attention): explicit
+    caller argument → tuned cache entry → caller-side default."""
     tuned = autotune.lookup(key)
     if tuned is not None:
         # .get(): a partial / hand-edited cache entry falls back to defaults
@@ -220,88 +236,230 @@ def _tuned_fill(key: str, **fields):
 
 
 # ---------------------------------------------------------------------------
-# conv1d — sliding path with custom VJP
+# conv dispatch: one table row per op, one dispatcher for all of them
 # ---------------------------------------------------------------------------
 
-class _Conv1dCfg(NamedTuple):
-    """Static kernel configuration threaded through the custom VJP."""
-    stride: int
-    tile_l: int
-    cin_block: int | None
-    cout_block: int | None
-    regime: str | None
-    activation: str
-    has_bias: bool
-    bwd_tile_l: int
-    interpret: bool
+_TILE_DEFAULTS = {
+    "tile_l": sliding_conv1d.DEFAULT_TILE_L,
+    "tile_h": sliding_conv2d.DEFAULT_TILE_H,
+    "tile_w": sliding_conv2d.DEFAULT_TILE_W,
+}
 
 
-def _resolve_conv1d(x, w, *, stride, tile_l, cin_block, cout_block, regime,
-                    dtype_key: str | None = None):
+def _resolve(op, x, w, stride, tune, dtype_key: str | None = None):
     """explicit args → tuned cache entry → defaults (+ auto blocking).
-    Returns ``(shape key, resolved config)`` — the key labels the obs
+    Returns ``(shape key, forward kernel kwargs)`` — the key labels the obs
     dispatch series for this call.
 
     ``dtype_key`` overrides the dtype field of the autotune shape key —
     the quantized paths tune under their precision name ("w8a8"/"w8a16")
     so int8 tilings never collide with float ones."""
-    B, L, Cin = x.shape
-    K, _, Cout = w.shape
-    key = autotune.conv1d_key(
-        B, L, Cin, Cout, K, stride, dtype_key or x.dtype.name
-    )
-    cfg = _tuned_fill(
-        key, tile_l=tile_l, cin_block=cin_block,
-        cout_block=cout_block, regime=regime,
-    )
-    tile_l = cfg["tile_l"]
-    if tile_l is None:
-        tile_l = sliding_conv1d.DEFAULT_TILE_L
-    return key, dict(
-        stride=stride, tile_l=tile_l,
-        cin_block=_auto_block(Cin, cfg["cin_block"]),
-        cout_block=_auto_block(Cout, cfg["cout_block"]),
-        regime=cfg["regime"],
-    )
+    key = _CONV[op].key(x, w, stride, dtype_key or x.dtype.name)
+    kw = {"stride": stride}
+    for name, v in _tuned_fill(key, **tune).items():
+        if name in _TILE_DEFAULTS:
+            v = _TILE_DEFAULTS[name] if v is None else v
+        elif name == "cout_block":
+            v = _auto_block(w.shape[-1], v)
+        elif name != "regime":  # cin_block / c_block: the input's channels
+            v = _auto_block(x.shape[-1], v)
+        kw[name] = v
+    return key, kw
 
 
-def _conv1d_sliding_dispatch(x, w, bias, *, activation, interpret, **tune):
-    """Tuned forward kernel call WITHOUT the custom VJP — used for the
-    forward primal and for dx inside the backward pass (where it picks up
-    the dx conv's own shape key from the autotune cache)."""
-    _, cfg = _resolve_conv1d(x, w, **tune)
-    return sliding_conv1d.conv1d_sliding_pallas(
-        x, w, bias, activation=activation, interpret=interpret, **cfg
-    )
-
-
-def _bwd_tile1d(x, w, stride, explicit):
-    """Backward dw-kernel tile: explicit arg → |grad cache entry → default."""
-    if explicit is not None:
+def _bwd_tiles(op, x, w, stride, explicit, fwd):
+    """Backward dw-kernel tiles: explicit arg → ``|grad`` cache entry →
+    default. Depthwise has no grad key: its dw kernel takes the forward
+    tile."""
+    if all(v is not None for v in explicit.values()):
         return explicit
-    B, L, Cin = x.shape
-    K, _, Cout = w.shape
-    key = autotune.conv1d_key(B, L, Cin, Cout, K, stride, x.dtype.name,
-                              grad=True)
+    if not _CONV[op].tuned_bwd:
+        return {k: fwd[k] if v is None else v for k, v in explicit.items()}
+    key = _CONV[op].key(x, w, stride, x.dtype.name, grad=True)
     tuned = autotune.lookup(key) or {}
-    return tuned.get("tile_l") or sliding_conv1d.DEFAULT_TILE_L
+    return {
+        k: (tuned.get(k) or _TILE_DEFAULTS[k]) if v is None else v
+        for k, v in explicit.items()
+    }
 
 
-def _quant_operands(x, w, w_scale, x_scale, precision):
-    """Quantize any float operands onto their int8 grids (weights per-cout,
-    activations per-tensor). Returns (x, w_q, w_scale, x_scale, out_dtype)."""
-    from repro.quant import qconv
+def _sliding_kernel(op, x, w, stride, interpret):
+    """The forward kernel WITHOUT the custom VJP, at the config tuned for
+    its own shape key: dx of a multi-channel conv is this conv over the
+    dilated gradient."""
+    _, kw = _resolve(op, x, w, stride, dict.fromkeys(_CONV[op].tunables))
+    return _CONV[op].kernel(
+        x, w, None, activation="none", interpret=interpret, **kw
+    )
 
-    out_dtype = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-    if w.dtype != jnp.int8:
-        qw = qconv.quantize_weight(w)
-        w, w_scale = qw.q, qw.scale
-    elif w_scale is None:
-        raise ValueError("int8 weights need their w_scale")
-    if precision == "w8a8" and x.dtype != jnp.int8:
-        x_scale = qconv.act_scale(x) if x_scale is None else x_scale
-        x = qconv.quantize_act(x, x_scale)
-    return x, w, w_scale, x_scale, out_dtype
+
+class _SlidingCfg(NamedTuple):
+    """Static kernel configuration threaded through the custom VJP."""
+    op: str
+    kernel: tuple  # forward kernel kwargs, as (name, value) pairs
+    bwd: tuple  # backward dw-kernel tiles, likewise
+    activation: str
+    has_bias: bool
+    interpret: bool
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sliding_op(cfg: _SlidingCfg, x, w, bias):
+    return _CONV[cfg.op].kernel(
+        x, w, bias, activation=cfg.activation, interpret=cfg.interpret,
+        **dict(cfg.kernel),
+    )
+
+
+def _sliding_fwd(cfg: _SlidingCfg, x, w, bias):
+    if cfg.activation in (None, "none"):
+        y = _sliding_op(cfg, x, w, bias)
+        z = None  # y IS the (cast) pre-activation — nothing extra to save
+    else:
+        y, z = _CONV[cfg.op].kernel(
+            x, w, bias, activation=cfg.activation, interpret=cfg.interpret,
+            save_preact=True, **dict(cfg.kernel),
+        )
+    return y, (x, w, bias, z)
+
+
+def _sliding_bwd(cfg: _SlidingCfg, res, dy):
+    x, w, bias, z = res
+    dz = sliding_conv_bwd.act_bwd(dy, z, cfg.activation).astype(x.dtype)
+    dx, dw, db = _CONV[cfg.op].bwd(
+        x, w, dz, dict(cfg.kernel), dict(cfg.bwd), cfg.has_bias,
+        cfg.interpret,
+    )
+    dbias = db.astype(bias.dtype) if cfg.has_bias else None
+    return dx, dw.astype(w.dtype), dbias
+
+
+_sliding_op.defvjp(_sliding_fwd, _sliding_bwd)
+
+
+def _conv1d_bwd(x, w, dz, kw, bwd, has_bias, interpret):
+    # dx: stride-1 sliding conv of the dilated gradient with the flipped,
+    # Cin↔Cout-transposed weights — tuned under its own shape key
+    dzp, wt = sliding_conv_bwd.conv1d_dx_operands(dz, w, stride=kw["stride"])
+    dx = _sliding_kernel("conv1d", dzp, wt, 1, interpret)
+    dx = sliding_conv_bwd._fit_len(dx, x.shape[1])
+    dw, db = sliding_conv_bwd.conv1d_bwd_dw_pallas(
+        x, dz, w.shape[0], stride=kw["stride"], tile_l=bwd["tile_l"],
+        cin_block=kw["cin_block"], cout_block=kw["cout_block"],
+        has_bias=has_bias, interpret=interpret,
+    )
+    return dx, dw, db
+
+
+def _conv1d_depthwise_bwd(x, w, dz, kw, bwd, has_bias, interpret):
+    dx = sliding_conv_bwd.conv1d_depthwise_dx(
+        dz, w, stride=kw["stride"], L=x.shape[1], tile_l=kw["tile_l"],
+        c_block=kw["c_block"], interpret=interpret,
+    )
+    dw, db = sliding_conv_bwd.conv1d_depthwise_bwd_dw_pallas(
+        x, dz, w.shape[0], stride=kw["stride"], tile_l=bwd["tile_l"],
+        c_block=kw["c_block"], has_bias=has_bias, interpret=interpret,
+    )
+    return dx, dw, db
+
+
+def _conv2d_bwd(x, w, dz, kw, bwd, has_bias, interpret):
+    dzp, wt = sliding_conv_bwd.conv2d_dx_operands(dz, w, stride=kw["stride"])
+    dx = _sliding_kernel("conv2d", dzp, wt, (1, 1), interpret)
+    dx = sliding_conv_bwd._fit_len(dx, x.shape[1], 1)
+    dx = sliding_conv_bwd._fit_len(dx, x.shape[2], 2)
+    dw, db = sliding_conv_bwd.conv2d_bwd_dw_pallas(
+        x, dz, w.shape[:2], stride=kw["stride"], tile_h=bwd["tile_h"],
+        tile_w=bwd["tile_w"], cin_block=kw["cin_block"],
+        cout_block=kw["cout_block"], has_bias=has_bias, interpret=interpret,
+    )
+    return dx, dw, db
+
+
+class _ConvOp(NamedTuple):
+    """One conv's row of the dispatch table."""
+    key: Callable  # (x, w, stride, dtype[, grad]) → autotune shape key
+    tunables: tuple[str, ...]  # the forward kernel's tile/block arguments
+    tuned_bwd: bool  # dw tiles tuned under the |grad key (else forward's)
+    kernel: Callable  # fp Pallas kernel, differentiated by _sliding_op
+    bwd: Callable  # its Pallas backward kernels → (dx, dw, db)
+    quant_kernel: Callable  # int8 Pallas kernel
+    quantize_weight: Callable  # float weight → QuantizedWeight
+    qconv: Callable  # pure-JAX int8 twin
+    twin: Callable  # pure-JAX sliding twin of the fp kernel
+    ref: Callable  # XLA reference (also the ``xla`` backend)
+    dilated: Callable | None  # repro.core conv for dilation > 1
+    baselines: dict  # im2col backend → (x, w, stride, tune, interpret)
+    measured_fallback: bool  # int8 → float where tuned timings say so
+
+
+_CONV = {
+    "conv1d": _ConvOp(
+        key=lambda x, w, s, dt, grad=False: autotune.conv1d_key(
+            *x.shape, w.shape[2], w.shape[0], s, dt, grad=grad),
+        tunables=("tile_l", "cin_block", "cout_block", "regime"),
+        tuned_bwd=True,
+        kernel=sliding_conv1d.conv1d_sliding_pallas,
+        bwd=_conv1d_bwd,
+        quant_kernel=sliding_conv_quant.conv1d_quant_pallas,
+        quantize_weight=qconv.quantize_weight,
+        qconv=qconv.conv1d_q,
+        twin=core_conv.conv1d_sliding,
+        ref=core_conv.conv1d_xla,
+        dilated=core_conv.conv1d,
+        baselines={
+            "im2col_gemm": lambda x, w, s, tune, i: (
+                im2col_gemm.conv1d_im2col_fused_pallas(
+                    x, w, stride=s, interpret=i,
+                    tile_l=_TILE_DEFAULTS["tile_l"] if tune["tile_l"] is None
+                    else tune["tile_l"])),
+            "im2col_hbm": lambda x, w, s, tune, i: (
+                im2col_gemm.conv1d_im2col_hbm(x, w, stride=s, interpret=i)),
+        },
+        measured_fallback=True,
+    ),
+    "conv1d_depthwise": _ConvOp(
+        key=lambda x, w, s, dt: autotune.conv1d_dw_key(
+            *x.shape, w.shape[0], s, dt),
+        tunables=("tile_l", "c_block"),
+        tuned_bwd=False,
+        kernel=sliding_conv1d.conv1d_depthwise_pallas,
+        bwd=_conv1d_depthwise_bwd,
+        quant_kernel=sliding_conv_quant.conv1d_depthwise_quant_pallas,
+        quantize_weight=quantize_depthwise_weight,
+        qconv=qconv.conv1d_depthwise_q,
+        twin=core_conv.conv1d_depthwise_sliding,
+        ref=lambda x, w, stride, padding: core_conv.conv1d_xla(
+            x, w[:, None, :], stride=stride, padding=padding,
+            groups=x.shape[-1]),
+        dilated=None,
+        baselines={},
+        measured_fallback=False,
+    ),
+    "conv2d": _ConvOp(
+        key=lambda x, w, s, dt, grad=False: autotune.conv2d_key(
+            *x.shape, w.shape[3], *w.shape[:2], *s, dt, grad=grad),
+        tunables=("tile_h", "tile_w", "cin_block", "cout_block", "regime"),
+        tuned_bwd=True,
+        kernel=sliding_conv2d.conv2d_sliding_pallas,
+        bwd=_conv2d_bwd,
+        quant_kernel=sliding_conv_quant.conv2d_quant_pallas,
+        quantize_weight=qconv.quantize_weight,
+        qconv=qconv.conv2d_q,
+        twin=core_conv.conv2d_sliding,
+        ref=core_conv.conv2d_xla,
+        dilated=core_conv.conv2d,
+        baselines={
+            # the fused-VMEM baseline, not the HBM-bloat one
+            "im2col_gemm": lambda x, w, s, tune, i: (
+                im2col_gemm.conv2d_im2col_fused_pallas(
+                    x, w, stride=s, interpret=i)),
+            "im2col_hbm": lambda x, w, s, tune, i: (
+                im2col_gemm.conv2d_im2col_hbm(x, w, stride=s, interpret=i)),
+        },
+        measured_fallback=False,
+    ),
+}
 
 
 def _check_quant_dispatch(precision, backend, dilation):
@@ -310,8 +468,7 @@ def _check_quant_dispatch(precision, backend, dilation):
             f"precision={precision!r} is implemented for the sliding "
             f"backend only (got backend={backend!r})"
         )
-    dilated = dilation > 1 if isinstance(dilation, int) else dilation != (1, 1)
-    if dilated:
+    if _dilated(dilation):
         raise ValueError("quantized convs cover dilation == 1 only")
 
 
@@ -324,18 +481,16 @@ def _check_quant_dispatch(precision, backend, dilation):
 _QUANT_FALLBACKS = health.DispatchLog("quant_fallback")
 
 
-def _quant_fallback_reason(x, w, stride, precision) -> str | None:
-    """Measured-regression guard for the quant 1-D dispatch: when the
-    autotune cache holds timings for BOTH this shape's quant path and its
-    float path and the float one is faster (the per-tap 1-D regime is
-    accumulator-traffic-bound — int8 operands buy nothing once upcast, so
-    small-K 1-D shapes can lose to bf16/f32), dispatch the float path
-    instead of silently serving the slower kernel. Only applies when the
-    caller isn't pinned to int8 (float input, no fused requant)."""
-    B, L, Cin = x.shape
-    K, _, Cout = w.shape
-    kq = autotune.conv1d_key(B, L, Cin, Cout, K, stride, precision)
-    kf = autotune.conv1d_key(B, L, Cin, Cout, K, stride, x.dtype.name)
+def _quant_fallback_reason(op, x, w, stride, precision) -> str | None:
+    """Measured-regression guard for the quant dispatch of the ops whose
+    table row asks for it (1-D): when the autotune cache holds timings for
+    BOTH this shape's quant path and its float path and the float one is
+    faster (the per-tap 1-D regime is accumulator-traffic-bound — int8
+    operands buy nothing once upcast, so small-K 1-D shapes can lose to
+    bf16/f32), dispatch the float path instead of silently serving the
+    slower kernel."""
+    kq = _CONV[op].key(x, w, stride, precision)
+    kf = _CONV[op].key(x, w, stride, x.dtype.name)
     tq, tf = autotune.lookup(kq), autotune.lookup(kf)
     if not (tq and tf):
         return None
@@ -351,56 +506,110 @@ def _quant_fallback_reason(x, w, stride, precision) -> str | None:
     if first:
         print(f"[quant] fallback: {reason}", file=sys.stderr)
         HEALTH.record(
-            f"conv1d.{precision}", "quant_slower", "fallback:fp",
-            detail=kq,
+            f"{op}.{precision}", "quant_slower", "fallback:fp", detail=kq,
         )
     return reason
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _conv1d_sliding_op(cfg: _Conv1dCfg, x, w, bias):
-    return sliding_conv1d.conv1d_sliding_pallas(
-        x, w, bias, stride=cfg.stride, tile_l=cfg.tile_l,
-        cin_block=cfg.cin_block, cout_block=cfg.cout_block,
-        regime=cfg.regime, activation=cfg.activation, interpret=cfg.interpret,
-    )
+def _conv(op, x, w, *, stride, padding, dilation, backend, bias, activation,
+          tune, bwd_tune, interpret, precision, w_scale, x_scale, out_scale):
+    """The one conv dispatch behind ``conv1d``, ``conv1d_depthwise`` and
+    ``conv2d``; ``op`` names the table row. ``tune`` holds the forward
+    kernel's explicit tile/block/regime arguments, ``bwd_tune`` the dw
+    kernel's tiles.
 
+    A quantized ``precision`` runs the int8 ladder (Pallas kernel → qconv
+    "fast" → qconv exact int32). It falls through to the float path of
+    the same call when the scale guard finds an unusable activation scale
+    on float operands, or when tuned timings say the float kernel is
+    faster — unless the call is pinned to int8: int8 input or a fused
+    requant (a chained site keeps its int8 contract), or explicit
+    tile/block/regime arguments (the autotuner measures the exact config
+    it asked for, and a fallback would record the float path under the
+    quant key). The float path: ``xla``, then dilation > 1 through
+    ``repro.core``, then the sliding ladder (custom-VJP Pallas op → JAX
+    sliding twin → XLA reference) or an im2col baseline."""
+    spec = _CONV[op]
+    interpret = use_interpret() if interpret is None else interpret
+    if precision != "fp":
+        _check_quant_dispatch(precision, backend, dilation)
+        x, padding = _pad(x, w, padding, dilation), "VALID"
+        site = f"{op}.{precision}"
+        x_scale, to_float = _guard_quant_scales(site, x, w, w_scale, x_scale)
+        if (
+            not to_float and spec.measured_fallback
+            and x.dtype != jnp.int8 and out_scale is None
+            and all(v is None for v in tune.values())
+            and _quant_fallback_reason(op, x, w, stride, precision)
+        ):
+            to_float = True
+            if w.dtype == jnp.int8:
+                if w_scale is None:
+                    raise ValueError("int8 weights need their w_scale")
+                w = (w.astype(jnp.float32) * w_scale).astype(x.dtype)
+        if not to_float:
+            out_dtype = jnp.float32 if x.dtype == jnp.int8 else x.dtype
+            if w.dtype != jnp.int8:
+                qw = spec.quantize_weight(w)
+                w, w_scale = qw.q, qw.scale
+            elif w_scale is None:
+                raise ValueError("int8 weights need their w_scale")
+            if precision == "w8a8" and x.dtype != jnp.int8:
+                x_scale = qconv.act_scale(x) if x_scale is None else x_scale
+                x = qconv.quantize_act(x, x_scale)
+            key, kw = _resolve(op, x, w, stride, tune, dtype_key=precision)
 
-def _conv1d_sliding_fwd(cfg: _Conv1dCfg, x, w, bias):
-    if cfg.activation in (None, "none"):
-        y = _conv1d_sliding_op(cfg, x, w, bias)
-        z = None  # y IS the (cast) pre-activation — nothing extra to save
-    else:
-        y, z = sliding_conv1d.conv1d_sliding_pallas(
-            x, w, bias, stride=cfg.stride, tile_l=cfg.tile_l,
-            cin_block=cfg.cin_block, cout_block=cfg.cout_block,
-            regime=cfg.regime, activation=cfg.activation,
-            interpret=cfg.interpret, save_preact=True,
+            def q_jax(accumulate):
+                # "fast" = compiled serving evaluation, "int32" = exact oracle
+                return spec.qconv(
+                    x, qconv.QuantizedWeight(w, w_scale), bias,
+                    mode=precision, x_scale=x_scale, out_scale=out_scale,
+                    stride=stride, padding="VALID", activation=activation,
+                    accumulate=accumulate, out_dtype=out_dtype,
+                )
+
+            return _ladder(site, key=key, rungs=[
+                ("pallas", lambda: spec.quant_kernel(
+                    x, w, w_scale, bias, x_scale=x_scale,
+                    out_scale=out_scale, mode=precision,
+                    activation=activation, out_dtype=out_dtype,
+                    interpret=interpret, **kw,
+                )),
+                ("jax", lambda: q_jax("fast")),
+                ("ref", lambda: q_jax("int32")),
+            ])
+    if backend == "xla":
+        y = spec.ref(x, w, stride=stride, padding=padding, dilation=dilation)
+        return epilogue_unfused(y, bias, activation)
+    if _dilated(dilation):  # kernels cover dilation=1; core handles the rest
+        y = spec.dilated(
+            x, w, stride=stride, padding=padding, dilation=dilation,
+            backend="sliding" if backend == "sliding" else "im2col_gemm",
         )
-    return y, (x, w, bias, z)
-
-
-def _conv1d_sliding_bwd(cfg: _Conv1dCfg, res, dy):
-    x, w, bias, z = res
-    dz = sliding_conv_bwd.act_bwd(dy, z, cfg.activation).astype(x.dtype)
-    # dx: stride-1 sliding conv of the dilated gradient with the flipped,
-    # Cin↔Cout-transposed weights — tuned under its own shape key
-    dzp, wt = sliding_conv_bwd.conv1d_dx_operands(dz, w, stride=cfg.stride)
-    dx = _conv1d_sliding_dispatch(
-        dzp, wt, None, activation="none", interpret=cfg.interpret,
-        stride=1, tile_l=None, cin_block=None, cout_block=None, regime=None,
-    )
-    dx = sliding_conv_bwd._fit_len(dx, x.shape[1])
-    dw, db = sliding_conv_bwd.conv1d_bwd_dw_pallas(
-        x, dz, w.shape[0], stride=cfg.stride, tile_l=cfg.bwd_tile_l,
-        cin_block=cfg.cin_block, cout_block=cfg.cout_block,
-        has_bias=cfg.has_bias, interpret=cfg.interpret,
-    )
-    dbias = db.astype(bias.dtype) if cfg.has_bias else None
-    return dx, dw.astype(w.dtype), dbias
-
-
-_conv1d_sliding_op.defvjp(_conv1d_sliding_fwd, _conv1d_sliding_bwd)
+        return epilogue_unfused(y, bias, activation)
+    x = _pad(x, w, padding, dilation)
+    if backend == "sliding":
+        key, kw = _resolve(op, x, w, stride, tune)
+        cfg = _SlidingCfg(
+            op, tuple(kw.items()),
+            tuple(_bwd_tiles(op, x, w, stride, bwd_tune, kw).items()),
+            activation, bias is not None, interpret,
+        )
+        return _ladder(op, key=key, rungs=[
+            ("pallas", lambda: _sliding_op(cfg, x, w, bias)),
+            ("jax", lambda: epilogue_unfused(
+                spec.twin(x, w, stride=stride, padding="VALID"),
+                bias, activation,
+            )),
+            ("ref", lambda: epilogue_unfused(
+                spec.ref(x, w, stride=stride, padding="VALID"),
+                bias, activation,
+            )),
+        ])
+    if backend not in spec.baselines:
+        raise ValueError(backend)
+    y = spec.baselines[backend](x, w, stride, tune, interpret)
+    return epilogue_unfused(y, bias, activation)
 
 
 def conv1d(
@@ -439,175 +648,15 @@ def conv1d(
     ``out_scale`` fuses an int8 requant after the activation. Tuned under
     the precision-suffixed autotune shape key.
     """
-    interpret = use_interpret() if interpret is None else interpret
-    if precision != "fp":
-        _check_quant_dispatch(precision, backend, dilation)
-        x = _pad1d(x, padding, w.shape[0], 1)
-        site = f"conv1d.{precision}"
-        x_scale, to_float = _guard_quant_scales(site, x, w, w_scale, x_scale)
-        if to_float:
-            # unusable calibrated scale, float operands: serve the fp path
-            return conv1d(
-                x, w, stride=stride, padding="VALID", backend=backend,
-                bias=bias, activation=activation, tile_l=tile_l,
-                cin_block=cin_block, cout_block=cout_block, regime=regime,
-                bwd_tile_l=bwd_tile_l, interpret=interpret,
-            )
-        explicit_cfg = not (
-            tile_l is None and cin_block is None and cout_block is None
-            and regime is None
-        )
-        if (
-            x.dtype != jnp.int8
-            and out_scale is None
-            and not explicit_cfg
-            and _quant_fallback_reason(x, w, stride, precision) is not None
-        ):
-            # measured regression: run the float sliding path instead.
-            # Pinned to the quant kernels regardless: int8 inputs / fused
-            # requant (chained sites must keep their int8 contract) and
-            # calls with explicit tile/block/regime arguments (the
-            # autotuner measures the exact config it asked for — falling
-            # back would record the float path under the quant key).
-            wf = w
-            if w.dtype == jnp.int8:
-                if w_scale is None:
-                    raise ValueError("int8 weights need their w_scale")
-                wf = (w.astype(jnp.float32) * w_scale).astype(x.dtype)
-            return conv1d(
-                x, wf, stride=stride, padding="VALID", backend=backend,
-                bias=bias, activation=activation, tile_l=tile_l,
-                cin_block=cin_block, cout_block=cout_block, regime=regime,
-                bwd_tile_l=bwd_tile_l, interpret=interpret,
-            )
-        x, w, w_scale, x_scale, out_dtype = _quant_operands(
-            x, w, w_scale, x_scale, precision
-        )
-        qkey, tuned = _resolve_conv1d(
-            x, w, stride=stride, tile_l=tile_l, cin_block=cin_block,
-            cout_block=cout_block, regime=regime, dtype_key=precision,
-        )
-
-        def _q_jax(accumulate):
-            # the pure-JAX quant twin (qconv): "fast" = compiled serving
-            # evaluation, "int32" = exact integer oracle
-            from repro.quant import qconv
-
-            return qconv.conv1d_q(
-                x, qconv.QuantizedWeight(w, w_scale), bias, mode=precision,
-                x_scale=x_scale, out_scale=out_scale, stride=stride,
-                padding="VALID", activation=activation,
-                accumulate=accumulate, out_dtype=out_dtype,
-            )
-
-        return _ladder(site, key=qkey, rungs=[
-            ("pallas", lambda: sliding_conv_quant.conv1d_quant_pallas(
-                x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
-                mode=precision, activation=activation, out_dtype=out_dtype,
-                interpret=interpret, **tuned,
-            )),
-            ("jax", lambda: _q_jax("fast")),
-            ("ref", lambda: _q_jax("int32")),
-        ])
-    if backend == "xla":
-        y = core_conv.conv1d_xla(
-            x, w, stride=stride, padding=padding, dilation=dilation
-        )
-        return epilogue_unfused(y, bias, activation)
-    if dilation > 1:  # kernels cover dilation=1; core handles the rest
-        y = core_conv.conv1d(
-            x, w, stride=stride, padding=padding, dilation=dilation,
-            backend="sliding" if backend == "sliding" else "im2col_gemm",
-        )
-        return epilogue_unfused(y, bias, activation)
-    x = _pad1d(x, padding, w.shape[0], dilation)
-    if backend == "sliding":
-        key, tuned = _resolve_conv1d(
-            x, w, stride=stride, tile_l=tile_l, cin_block=cin_block,
-            cout_block=cout_block, regime=regime,
-        )
-        cfg = _Conv1dCfg(
-            activation=activation, has_bias=bias is not None,
-            bwd_tile_l=_bwd_tile1d(x, w, stride, bwd_tile_l),
-            interpret=interpret, **tuned,
-        )
-        return _ladder("conv1d", key=key, rungs=[
-            ("pallas", lambda: _conv1d_sliding_op(cfg, x, w, bias)),
-            ("jax", lambda: epilogue_unfused(
-                core_conv.conv1d_sliding(
-                    x, w, stride=stride, padding="VALID"
-                ), bias, activation,
-            )),
-            ("ref", lambda: epilogue_unfused(
-                core_conv.conv1d_xla(x, w, stride=stride, padding="VALID"),
-                bias, activation,
-            )),
-        ])
-    tile_l = sliding_conv1d.DEFAULT_TILE_L if tile_l is None else tile_l
-    if backend == "im2col_gemm":
-        y = im2col_gemm.conv1d_im2col_fused_pallas(
-            x, w, stride=stride, tile_l=tile_l, interpret=interpret
-        )
-    elif backend == "im2col_hbm":
-        y = im2col_gemm.conv1d_im2col_hbm(
-            x, w, stride=stride, interpret=interpret
-        )
-    else:
-        raise ValueError(backend)
-    return epilogue_unfused(y, bias, activation)
-
-
-# ---------------------------------------------------------------------------
-# depthwise conv1d — custom VJP
-# ---------------------------------------------------------------------------
-
-class _DepthwiseCfg(NamedTuple):
-    stride: int
-    tile_l: int
-    c_block: int | None
-    activation: str
-    has_bias: bool
-    bwd_tile_l: int
-    interpret: bool
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _conv1d_depthwise_op(cfg: _DepthwiseCfg, x, w, bias):
-    return sliding_conv1d.conv1d_depthwise_pallas(
-        x, w, bias, stride=cfg.stride, tile_l=cfg.tile_l,
-        c_block=cfg.c_block, activation=cfg.activation,
-        interpret=cfg.interpret,
+    return _conv(
+        "conv1d", x, w, stride=stride, padding=padding, dilation=dilation,
+        backend=backend, bias=bias, activation=activation,
+        tune=dict(tile_l=tile_l, cin_block=cin_block, cout_block=cout_block,
+                  regime=regime),
+        bwd_tune=dict(tile_l=bwd_tile_l), interpret=interpret,
+        precision=precision, w_scale=w_scale, x_scale=x_scale,
+        out_scale=out_scale,
     )
-
-
-def _conv1d_depthwise_fwd(cfg: _DepthwiseCfg, x, w, bias):
-    if cfg.activation in (None, "none"):
-        y, z = _conv1d_depthwise_op(cfg, x, w, bias), None
-    else:
-        y, z = sliding_conv1d.conv1d_depthwise_pallas(
-            x, w, bias, stride=cfg.stride, tile_l=cfg.tile_l,
-            c_block=cfg.c_block, activation=cfg.activation,
-            interpret=cfg.interpret, save_preact=True,
-        )
-    return y, (x, w, bias, z)
-
-
-def _conv1d_depthwise_bwd(cfg: _DepthwiseCfg, res, dy):
-    x, w, bias, z = res
-    dz = sliding_conv_bwd.act_bwd(dy, z, cfg.activation).astype(x.dtype)
-    dx = sliding_conv_bwd.conv1d_depthwise_dx(
-        dz, w, stride=cfg.stride, L=x.shape[1], tile_l=cfg.tile_l,
-        c_block=cfg.c_block, interpret=cfg.interpret,
-    )
-    dw, db = sliding_conv_bwd.conv1d_depthwise_bwd_dw_pallas(
-        x, dz, w.shape[0], stride=cfg.stride, tile_l=cfg.bwd_tile_l,
-        c_block=cfg.c_block, has_bias=cfg.has_bias, interpret=cfg.interpret,
-    )
-    dbias = db.astype(bias.dtype) if cfg.has_bias else None
-    return dx, dw.astype(w.dtype), dbias
-
-
-_conv1d_depthwise_op.defvjp(_conv1d_depthwise_fwd, _conv1d_depthwise_bwd)
 
 
 def conv1d_depthwise(
@@ -638,192 +687,14 @@ def conv1d_depthwise(
     input quantizes onto ``x_scale`` (dynamic absmax when None). Tuned
     under the depthwise precision-named autotune shape key.
     """
-    interpret = use_interpret() if interpret is None else interpret
-    x = _pad1d(x, padding, w.shape[0], 1)
-    if precision != "fp":
-        from repro.quant import qconv
-        from repro.quant.apply import quantize_depthwise_weight
-
-        site = f"conv1d_depthwise.{precision}"
-        x_scale, to_float = _guard_quant_scales(site, x, w, w_scale, x_scale)
-        if to_float:
-            return conv1d_depthwise(
-                x, w, stride=stride, padding="VALID", bias=bias,
-                activation=activation, tile_l=tile_l, c_block=c_block,
-                bwd_tile_l=bwd_tile_l, interpret=interpret,
-            )
-        out_dtype = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-        if w.dtype != jnp.int8:
-            qw = quantize_depthwise_weight(w)
-            w, w_scale = qw.q, qw.scale
-        elif w_scale is None:
-            raise ValueError("int8 weights need their w_scale")
-        if precision == "w8a8" and x.dtype != jnp.int8:
-            x_scale = qconv.act_scale(x) if x_scale is None else x_scale
-            x = qconv.quantize_act(x, x_scale)
-        B, L, C = x.shape
-        key = autotune.conv1d_dw_key(B, L, C, w.shape[0], stride, precision)
-        cfg = _tuned_fill(key, tile_l=tile_l, c_block=c_block)
-
-        def _q_jax(accumulate):
-            return qconv.conv1d_depthwise_q(
-                x, qconv.QuantizedWeight(w, w_scale), bias, mode=precision,
-                x_scale=x_scale, out_scale=out_scale, stride=stride,
-                padding="VALID", activation=activation,
-                accumulate=accumulate, out_dtype=out_dtype,
-            )
-
-        return _ladder(site, key=key, rungs=[
-            ("pallas", lambda: sliding_conv_quant.conv1d_depthwise_quant_pallas(
-                x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
-                mode=precision, stride=stride,
-                tile_l=cfg["tile_l"] or sliding_conv1d.DEFAULT_TILE_L,
-                c_block=_auto_block(C, cfg["c_block"]),
-                activation=activation, out_dtype=out_dtype,
-                interpret=interpret,
-            )),
-            ("jax", lambda: _q_jax("fast")),
-            ("ref", lambda: _q_jax("int32")),
-        ])
-    tile_l = sliding_conv1d.DEFAULT_TILE_L if tile_l is None else tile_l
-    cfg = _DepthwiseCfg(
-        stride=stride, tile_l=tile_l,
-        c_block=_auto_block(x.shape[-1], c_block), activation=activation,
-        has_bias=bias is not None,
-        bwd_tile_l=bwd_tile_l if bwd_tile_l is not None else tile_l,
-        interpret=interpret,
+    return _conv(
+        "conv1d_depthwise", x, w, stride=stride, padding=padding, dilation=1,
+        backend="sliding", bias=bias, activation=activation,
+        tune=dict(tile_l=tile_l, c_block=c_block),
+        bwd_tune=dict(tile_l=bwd_tile_l), interpret=interpret,
+        precision=precision, w_scale=w_scale, x_scale=x_scale,
+        out_scale=out_scale,
     )
-    dw_key = autotune.conv1d_dw_key(
-        *x.shape, w.shape[0], stride, x.dtype.name
-    )
-    return _ladder("conv1d_depthwise", key=dw_key, rungs=[
-        ("pallas", lambda: _conv1d_depthwise_op(cfg, x, w, bias)),
-        ("jax", lambda: epilogue_unfused(
-            core_conv.conv1d_depthwise_sliding(
-                x, w, stride=stride, padding="VALID"
-            ), bias, activation,
-        )),
-        ("ref", lambda: epilogue_unfused(
-            core_conv.conv1d_xla(
-                x, w[:, None, :], stride=stride, padding="VALID",
-                groups=x.shape[-1],
-            ), bias, activation,
-        )),
-    ])
-
-
-# ---------------------------------------------------------------------------
-# conv2d — sliding path with custom VJP
-# ---------------------------------------------------------------------------
-
-class _Conv2dCfg(NamedTuple):
-    stride: tuple[int, int]
-    tile_h: int
-    tile_w: int
-    cin_block: int | None
-    cout_block: int | None
-    regime: str | None
-    activation: str
-    has_bias: bool
-    bwd_tile_h: int
-    bwd_tile_w: int
-    interpret: bool
-
-
-def _resolve_conv2d(x, w, *, stride, tile_h, tile_w, cin_block, cout_block,
-                    regime, dtype_key: str | None = None):
-    """Like :func:`_resolve_conv1d`: returns ``(shape key, config)``."""
-    B, H, W, Cin = x.shape
-    kh, kw, _, Cout = w.shape
-    key = autotune.conv2d_key(B, H, W, Cin, Cout, kh, kw, *stride,
-                              dtype_key or x.dtype.name)
-    cfg = _tuned_fill(
-        key, tile_h=tile_h, tile_w=tile_w, cin_block=cin_block,
-        cout_block=cout_block, regime=regime,
-    )
-    tile_h = cfg["tile_h"]
-    tile_w = cfg["tile_w"]
-    if tile_h is None:
-        tile_h = sliding_conv2d.DEFAULT_TILE_H
-    if tile_w is None:
-        tile_w = sliding_conv2d.DEFAULT_TILE_W
-    return key, dict(
-        stride=stride, tile_h=tile_h, tile_w=tile_w,
-        cin_block=_auto_block(Cin, cfg["cin_block"]),
-        cout_block=_auto_block(Cout, cfg["cout_block"]),
-        regime=cfg["regime"],
-    )
-
-
-def _conv2d_sliding_dispatch(x, w, bias, *, activation, interpret, **tune):
-    _, cfg = _resolve_conv2d(x, w, **tune)
-    return sliding_conv2d.conv2d_sliding_pallas(
-        x, w, bias, activation=activation, interpret=interpret, **cfg
-    )
-
-
-def _bwd_tile2d(x, w, stride, explicit_h, explicit_w):
-    if explicit_h is not None and explicit_w is not None:
-        return explicit_h, explicit_w
-    B, H, W, Cin = x.shape
-    kh, kw, _, Cout = w.shape
-    key = autotune.conv2d_key(B, H, W, Cin, Cout, kh, kw, *stride,
-                              x.dtype.name, grad=True)
-    tuned = autotune.lookup(key) or {}
-    th = explicit_h if explicit_h is not None else (
-        tuned.get("tile_h") or sliding_conv2d.DEFAULT_TILE_H
-    )
-    tw = explicit_w if explicit_w is not None else (
-        tuned.get("tile_w") or sliding_conv2d.DEFAULT_TILE_W
-    )
-    return th, tw
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _conv2d_sliding_op(cfg: _Conv2dCfg, x, w, bias):
-    return sliding_conv2d.conv2d_sliding_pallas(
-        x, w, bias, stride=cfg.stride, tile_h=cfg.tile_h, tile_w=cfg.tile_w,
-        cin_block=cfg.cin_block, cout_block=cfg.cout_block,
-        regime=cfg.regime, activation=cfg.activation, interpret=cfg.interpret,
-    )
-
-
-def _conv2d_sliding_fwd(cfg: _Conv2dCfg, x, w, bias):
-    if cfg.activation in (None, "none"):
-        y, z = _conv2d_sliding_op(cfg, x, w, bias), None
-    else:
-        y, z = sliding_conv2d.conv2d_sliding_pallas(
-            x, w, bias, stride=cfg.stride, tile_h=cfg.tile_h,
-            tile_w=cfg.tile_w, cin_block=cfg.cin_block,
-            cout_block=cfg.cout_block, regime=cfg.regime,
-            activation=cfg.activation, interpret=cfg.interpret,
-            save_preact=True,
-        )
-    return y, (x, w, bias, z)
-
-
-def _conv2d_sliding_bwd(cfg: _Conv2dCfg, res, dy):
-    x, w, bias, z = res
-    dz = sliding_conv_bwd.act_bwd(dy, z, cfg.activation).astype(x.dtype)
-    dzp, wt = sliding_conv_bwd.conv2d_dx_operands(dz, w, stride=cfg.stride)
-    dx = _conv2d_sliding_dispatch(
-        dzp, wt, None, activation="none", interpret=cfg.interpret,
-        stride=(1, 1), tile_h=None, tile_w=None, cin_block=None,
-        cout_block=None, regime=None,
-    )
-    dx = sliding_conv_bwd._fit_len(dx, x.shape[1], 1)
-    dx = sliding_conv_bwd._fit_len(dx, x.shape[2], 2)
-    dw, db = sliding_conv_bwd.conv2d_bwd_dw_pallas(
-        x, dz, w.shape[:2], stride=cfg.stride, tile_h=cfg.bwd_tile_h,
-        tile_w=cfg.bwd_tile_w, cin_block=cfg.cin_block,
-        cout_block=cfg.cout_block, has_bias=cfg.has_bias,
-        interpret=cfg.interpret,
-    )
-    dbias = db.astype(bias.dtype) if cfg.has_bias else None
-    return dx, dw.astype(w.dtype), dbias
-
-
-_conv2d_sliding_op.defvjp(_conv2d_sliding_fwd, _conv2d_sliding_bwd)
 
 
 def conv2d(
@@ -855,103 +726,15 @@ def conv2d(
     sliding path is differentiable (custom VJP, Pallas backward kernels).
     ``precision`` selects the int8 quantized kernels — see ``conv1d``.
     """
-    interpret = use_interpret() if interpret is None else interpret
-    if precision != "fp":
-        _check_quant_dispatch(precision, backend, dilation)
-        kh_, kw_ = w.shape[:2]
-        (plo_h, phi_h), (plo_w, phi_w) = core_conv._resolve_pad_2d(
-            padding, kh_, kw_, (1, 1)
-        )
-        if plo_h or phi_h or plo_w or phi_w:
-            x = jnp.pad(x, ((0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))
-        site = f"conv2d.{precision}"
-        x_scale, to_float = _guard_quant_scales(site, x, w, w_scale, x_scale)
-        if to_float:
-            return conv2d(
-                x, w, stride=stride, padding="VALID", backend=backend,
-                bias=bias, activation=activation, tile_h=tile_h,
-                tile_w=tile_w, cin_block=cin_block, cout_block=cout_block,
-                regime=regime, bwd_tile_h=bwd_tile_h, bwd_tile_w=bwd_tile_w,
-                interpret=interpret,
-            )
-        x, w, w_scale, x_scale, out_dtype = _quant_operands(
-            x, w, w_scale, x_scale, precision
-        )
-        qkey, tuned = _resolve_conv2d(
-            x, w, stride=stride, tile_h=tile_h, tile_w=tile_w,
-            cin_block=cin_block, cout_block=cout_block, regime=regime,
-            dtype_key=precision,
-        )
-
-        def _q_jax(accumulate):
-            from repro.quant import qconv
-
-            return qconv.conv2d_q(
-                x, qconv.QuantizedWeight(w, w_scale), bias, mode=precision,
-                x_scale=x_scale, out_scale=out_scale, stride=stride,
-                padding="VALID", activation=activation,
-                accumulate=accumulate, out_dtype=out_dtype,
-            )
-
-        return _ladder(site, key=qkey, rungs=[
-            ("pallas", lambda: sliding_conv_quant.conv2d_quant_pallas(
-                x, w, w_scale, bias, x_scale=x_scale, out_scale=out_scale,
-                mode=precision, activation=activation, out_dtype=out_dtype,
-                interpret=interpret, **tuned,
-            )),
-            ("jax", lambda: _q_jax("fast")),
-            ("ref", lambda: _q_jax("int32")),
-        ])
-    if backend == "xla":
-        y = core_conv.conv2d_xla(
-            x, w, stride=stride, padding=padding, dilation=dilation
-        )
-        return epilogue_unfused(y, bias, activation)
-    if dilation != (1, 1):
-        y = core_conv.conv2d(
-            x, w, stride=stride, padding=padding, dilation=dilation,
-            backend="sliding" if backend == "sliding" else "im2col_gemm",
-        )
-        return epilogue_unfused(y, bias, activation)
-    kh, kw = w.shape[:2]
-    (plo_h, phi_h), (plo_w, phi_w) = core_conv._resolve_pad_2d(
-        padding, kh, kw, dilation
+    return _conv(
+        "conv2d", x, w, stride=stride, padding=padding, dilation=dilation,
+        backend=backend, bias=bias, activation=activation,
+        tune=dict(tile_h=tile_h, tile_w=tile_w, cin_block=cin_block,
+                  cout_block=cout_block, regime=regime),
+        bwd_tune=dict(tile_h=bwd_tile_h, tile_w=bwd_tile_w),
+        interpret=interpret, precision=precision, w_scale=w_scale,
+        x_scale=x_scale, out_scale=out_scale,
     )
-    if plo_h or phi_h or plo_w or phi_w:
-        x = jnp.pad(x, ((0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))
-    if backend == "sliding":
-        key, tuned = _resolve_conv2d(
-            x, w, stride=stride, tile_h=tile_h, tile_w=tile_w,
-            cin_block=cin_block, cout_block=cout_block, regime=regime,
-        )
-        bth, btw = _bwd_tile2d(x, w, stride, bwd_tile_h, bwd_tile_w)
-        cfg = _Conv2dCfg(
-            activation=activation, has_bias=bias is not None,
-            bwd_tile_h=bth, bwd_tile_w=btw, interpret=interpret, **tuned,
-        )
-        return _ladder("conv2d", key=key, rungs=[
-            ("pallas", lambda: _conv2d_sliding_op(cfg, x, w, bias)),
-            ("jax", lambda: epilogue_unfused(
-                core_conv.conv2d_sliding(
-                    x, w, stride=stride, padding="VALID"
-                ), bias, activation,
-            )),
-            ("ref", lambda: epilogue_unfused(
-                core_conv.conv2d_xla(x, w, stride=stride, padding="VALID"),
-                bias, activation,
-            )),
-        ])
-    if backend == "im2col_gemm":
-        # the fused-VMEM baseline — NOT the HBM-bloat one (which previously
-        # shadowed it here, mislabeling fig1/fig2 "im2col" numbers)
-        y = im2col_gemm.conv2d_im2col_fused_pallas(
-            x, w, stride=stride, interpret=interpret
-        )
-        return epilogue_unfused(y, bias, activation)
-    if backend == "im2col_hbm":
-        y = im2col_gemm.conv2d_im2col_hbm(x, w, stride=stride, interpret=interpret)
-        return epilogue_unfused(y, bias, activation)
-    raise ValueError(backend)
 
 
 # ---------------------------------------------------------------------------

@@ -60,22 +60,23 @@ def act_fn(name: str):
 # ---------------------------------------------------------------------------
 # Fused conv→bias→activation building blocks
 # ---------------------------------------------------------------------------
-# On the Pallas path (backend "sliding_pallas") the bias add and activation
-# run inside the conv kernel's epilogue — one launch, no extra HBM round
-# trips. Pure-JAX / XLA backends apply them unfused with identical
-# semantics (activations are the kernel-epilogue set: none/relu/gelu/
-# gelu_erf/silu).
+# Every model conv (whisper's frontend, llava's patch_embed, mamba's
+# depthwise conv, the edge CNN) goes through ``conv_bias_act``. On the
+# Pallas path (backend "sliding_pallas") it calls ``repro.kernels.ops``,
+# where the bias add and activation run inside the conv kernel's epilogue
+# — one launch, no extra HBM round trips. Pure-JAX / XLA backends apply
+# them unfused with identical semantics (activations are the
+# kernel-epilogue set: none/relu/gelu/gelu_erf/silu).
 # Every backend is differentiable: the Pallas ops carry a custom VJP with
-# sliding-window backward kernels (DESIGN.md §6), so whisper's frontend,
-# mamba's conv and llava's patch_embed train unchanged under any backend.
+# sliding-window backward kernels (DESIGN.md §6).
 #
 # Quantized inference (DESIGN.md §7): ``w`` may be a
 # ``repro.quant.QuantizedWeight`` (int8 + scales, from quant.apply) and/or
 # ``precision`` ∈ {"w8a8", "w8a16"} may be set (float weights quantize on
 # the fly). The Pallas backend then runs the fused int8 kernels; pure-JAX
-# backends run ``repro.quant.qconv`` with the same int32 arithmetic. Both
-# entry points are calibration sites: under ``quant.calibrate.collecting``
-# the input activation is observed (eagerly) under ``site``.
+# backends run ``repro.quant.qconv`` with the same int32 arithmetic. Every
+# conv is a calibration site: under ``quant.calibrate.collecting`` the
+# input activation is observed (eagerly) under ``site``.
 
 
 def _quant_mode(w, precision: str) -> str | None:
@@ -86,6 +87,88 @@ def _quant_mode(w, precision: str) -> str | None:
     if isinstance(w, QuantizedWeight):  # quantized leaf, default weight-only
         return "w8a16"
     return None
+
+
+def conv_bias_act(
+    op: str,
+    x: Array,
+    w: Any,
+    b: Array | None,
+    *,
+    mode: str | None,
+    activation: str = "none",
+    stride=1,
+    padding="VALID",
+    backend: str = "sliding",
+    site: str | None = None,
+) -> Array:
+    """conv→bias→activation for ``op`` ∈ {"conv1d", "conv2d",
+    "conv1d_depthwise"} (the ``repro.kernels.ops`` names).
+
+    ``mode`` is the caller's int8 choice ("w8a8"/"w8a16"; None for
+    float): a float ``w`` quantizes here, a ``QuantizedWeight`` is used
+    as stored — and with no mode it dequantizes in registers. ``site``
+    defaults to the shape-derived calibration site."""
+    from repro.core import conv as C
+    from repro.kernels import ops
+    from repro.kernels.sliding_conv1d import apply_activation
+    from repro.quant import calibrate, qconv
+
+    quantized = isinstance(w, qconv.QuantizedWeight)
+    shape = (w.q if quantized else w).shape
+    taps = shape[:1] if op == "conv1d_depthwise" else shape[:-2]
+    site = site or calibrate.conv_site(
+        {"conv1d_depthwise": "conv1d_dw"}.get(op, op), x.shape[-1],
+        shape[-1], "x".join(map(str, taps)),
+    )
+    calibrate.observe(site, x)
+    kernel = {"conv1d": ops.conv1d, "conv2d": ops.conv2d,
+              "conv1d_depthwise": ops.conv1d_depthwise}[op]
+    if mode is not None:
+        qw = w if quantized else qconv.quantize_weight(w)
+        # requant chaining (DESIGN.md §8): a leaf carrying out_scale emits
+        # int8 on the consumer's grid — only meaningful in w8a8, where the
+        # consumer quantizes its input anyway. An int8 INPUT here is the
+        # other end of a chain: its scale is this site's calibrated x_scale.
+        out_scale = qw.out_scale if mode == "w8a8" else None
+        if out_scale is None:
+            calibrate.note_dequant(site)
+        if backend == "sliding_pallas":
+            return kernel(
+                x, qw.q, stride=stride, padding=padding, bias=b,
+                activation=activation, precision=mode, w_scale=qw.scale,
+                x_scale=qw.x_scale, out_scale=out_scale,
+            )
+        # accumulate="fast": the compiled CPU evaluation (int8 storage,
+        # f32 GEMMs) — the exact-int32 default is the test oracle, ~4×
+        # slower than f32 through XLA CPU's integer matmul
+        q = {"conv1d": qconv.conv1d_q, "conv2d": qconv.conv2d_q,
+             "conv1d_depthwise": qconv.conv1d_depthwise_q}[op]
+        return q(
+            x, qw, b, mode=mode, stride=stride, padding=padding,
+            x_scale=qw.x_scale, out_scale=out_scale, activation=activation,
+            out_dtype=jnp.float32 if x.dtype == jnp.int8 else x.dtype,
+            accumulate="fast",
+        )
+    w = w.dequant(x.dtype) if quantized else w.astype(x.dtype)
+    if backend == "sliding_pallas":
+        return kernel(
+            x, w, stride=stride, padding=padding, bias=b,
+            activation=activation,
+        )
+    if op != "conv1d_depthwise":
+        conv = C.conv1d if op == "conv1d" else C.conv2d
+        y = conv(x, w, stride=stride, padding=padding, backend=backend)
+        return ops.epilogue_unfused(y, b, activation)
+    # mamba's depthwise conv adds the bias and activates in its own dtype
+    if backend == "sliding":
+        y = C.conv1d_depthwise_sliding(x, w, stride=stride, padding=padding)
+    elif backend == "xla":
+        y = C.conv1d_xla(x, w[:, None, :], stride=stride, padding=padding,
+                         groups=w.shape[1])
+    else:
+        raise ValueError(backend)
+    return apply_activation(y + b.astype(y.dtype), activation)
 
 
 def conv1d_bias_act(
@@ -102,52 +185,11 @@ def conv1d_bias_act(
 ) -> Array:
     """Multi-channel conv1d + bias + activation. x: (B,L,Cin), w: (K,Cin,Cout)
     float or ``QuantizedWeight``."""
-    from repro.quant import calibrate, qconv
-
-    k, cout = (w.q if isinstance(w, qconv.QuantizedWeight) else w).shape[::2]
-    site = site or calibrate.conv_site("conv1d", x.shape[-1], cout, k)
-    calibrate.observe(site, x)
-    mode = _quant_mode(w, precision)
-    if mode is not None:
-        qw = w if isinstance(w, qconv.QuantizedWeight) else qconv.quantize_weight(w)
-        # requant chaining (DESIGN.md §8): a leaf carrying out_scale emits
-        # int8 on the consumer's grid — only meaningful in w8a8, where the
-        # consumer quantizes its input anyway. An int8 INPUT here is the
-        # other end of a chain: its scale is this site's calibrated x_scale.
-        out_scale = qw.out_scale if mode == "w8a8" else None
-        if out_scale is None:
-            calibrate.note_dequant(site)
-        out_dtype = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-        if backend == "sliding_pallas":
-            from repro.kernels import ops
-
-            return ops.conv1d(
-                x, qw.q, stride=stride, padding=padding, bias=b,
-                activation=activation, precision=mode, w_scale=qw.scale,
-                x_scale=qw.x_scale, out_scale=out_scale,
-            )
-        # accumulate="fast": the compiled CPU evaluation (int8 storage,
-        # f32 GEMMs) — the exact-int32 default is the test oracle, ~4×
-        # slower than f32 through XLA CPU's integer matmul
-        return qconv.conv1d_q(
-            x, qw, b, mode=mode, stride=stride, padding=padding,
-            x_scale=qw.x_scale, out_scale=out_scale,
-            activation=activation, out_dtype=out_dtype, accumulate="fast",
-        )
-    w = w.astype(x.dtype)
-    if backend == "sliding_pallas":
-        from repro.kernels import ops
-
-        return ops.conv1d(
-            x, w, stride=stride, padding=padding, bias=b,
-            activation=activation,
-        )
-    from repro.core import conv as C
-    from repro.kernels.ops import epilogue_unfused
-
-    cb = "sliding" if backend.startswith("sliding") else backend
-    y = C.conv1d(x, w, stride=stride, padding=padding, backend=cb)
-    return epilogue_unfused(y, b, activation)
+    return conv_bias_act(
+        "conv1d", x, w, b, mode=_quant_mode(w, precision),
+        activation=activation, stride=stride, padding=padding,
+        backend=backend, site=site,
+    )
 
 
 def conv2d_bias_act(
@@ -164,47 +206,11 @@ def conv2d_bias_act(
 ) -> Array:
     """Multi-channel conv2d + bias + activation. x: (B,H,W,Cin), w: HWIO
     float or ``QuantizedWeight``."""
-    from repro.quant import calibrate, qconv
-
-    wq = w.q if isinstance(w, qconv.QuantizedWeight) else w
-    site = site or calibrate.conv_site(
-        "conv2d", x.shape[-1], wq.shape[-1], f"{wq.shape[0]}x{wq.shape[1]}"
+    return conv_bias_act(
+        "conv2d", x, w, b, mode=_quant_mode(w, precision),
+        activation=activation, stride=stride, padding=padding,
+        backend=backend, site=site,
     )
-    calibrate.observe(site, x)
-    mode = _quant_mode(w, precision)
-    if mode is not None:
-        qw = w if isinstance(w, qconv.QuantizedWeight) else qconv.quantize_weight(w)
-        out_scale = qw.out_scale if mode == "w8a8" else None
-        if out_scale is None:
-            calibrate.note_dequant(site)
-        out_dtype = jnp.float32 if x.dtype == jnp.int8 else x.dtype
-        if backend == "sliding_pallas":
-            from repro.kernels import ops
-
-            return ops.conv2d(
-                x, qw.q, stride=stride, padding=padding, bias=b,
-                activation=activation, precision=mode, w_scale=qw.scale,
-                x_scale=qw.x_scale, out_scale=out_scale,
-            )
-        return qconv.conv2d_q(
-            x, qw, b, mode=mode, stride=stride, padding=padding,
-            x_scale=qw.x_scale, out_scale=out_scale,
-            activation=activation, out_dtype=out_dtype, accumulate="fast",
-        )
-    w = w.astype(x.dtype)
-    if backend == "sliding_pallas":
-        from repro.kernels import ops
-
-        return ops.conv2d(
-            x, w, stride=stride, padding=padding, bias=b,
-            activation=activation,
-        )
-    from repro.core import conv as C
-    from repro.kernels.ops import epilogue_unfused
-
-    cb = "sliding" if backend.startswith("sliding") else backend
-    y = C.conv2d(x, w, stride=stride, padding=padding, backend=cb)
-    return epilogue_unfused(y, b, activation)
 
 
 # ---------------------------------------------------------------------------

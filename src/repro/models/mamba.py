@@ -10,7 +10,7 @@ block is a sliding-window convolution. It routes through
   * ``sliding_pallas`` — the Pallas VPU kernel
                          (``repro.kernels.ops.conv1d_depthwise``; TPU runtime
                          path, validated in interpret mode),
-  * ``im2col_gemm``/``xla`` — baselines.
+  * ``xla``            — the XLA grouped-conv baseline.
 
 Selective scan: chunked — outer ``lax.scan`` carries the (B, d_inner, N)
 state across chunks (checkpointed boundaries), inner chunk evaluated with a
@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.conv import conv1d_depthwise_sliding, conv1d_xla
 from repro.distributed.sharding import ParamDef, Runtime
+from repro.models.layers import conv_bias_act
 
 Array = jax.Array
 
@@ -64,7 +64,7 @@ def _resolve_conv_w(p, dt) -> Array:
 
 
 def _conv_act(x: Array, w: Any, b: Array, cfg: ModelConfig) -> Array:
-    """Causal depthwise conv→bias→silu via the selected evaluation strategy.
+    """Causal depthwise conv→bias→silu through ``layers.conv_bias_act``.
 
     On the Pallas path the bias and silu run in the kernel's fused epilogue
     (one launch); the pure-JAX/XLA paths apply them unfused.
@@ -72,56 +72,20 @@ def _conv_act(x: Array, w: Any, b: Array, cfg: ModelConfig) -> Array:
     With ``cfg.conv_precision == "w8a8"`` and an int8 ``QuantizedWeight``
     leaf (from ``quant.apply``), the conv runs int8 *activations* through
     the dedicated depthwise kernel (Pallas VPU int8×int8→int32, or the
-    compiled ``qconv`` fast path on non-Pallas backends) — not just
-    register-dequantized weights. This is the PREFILL path; the per-token
-    decode window conv (``mamba_apply`` with ``state``) is an O(K·C)
-    elementwise product with nothing to win from int8 and stays float.
-    The activation scale is the leaf's calibrated ``x_scale`` when
-    present, dynamic absmax otherwise (mamba sites execute under the
-    period scan, where calibration can't observe)."""
-    from repro.quant import calibrate
-    from repro.quant.qconv import QuantizedWeight, conv1d_depthwise_q
-
-    backend = cfg.conv_backend
-    calibrate.observe(
-        calibrate.conv_site("conv1d_dw", x.shape[-1], x.shape[-1],
-                            _conv_w_taps(w)),
-        x,
-    )
-    if isinstance(w, QuantizedWeight) and cfg.conv_precision == "w8a8":
-        if backend == "sliding_pallas":
-            from repro.kernels import ops
-
-            return ops.conv1d_depthwise(
-                x, w.q, padding="CAUSAL", bias=b, activation="silu",
-                precision="w8a8", w_scale=w.scale, x_scale=w.x_scale,
-            )
-        return conv1d_depthwise_q(
-            x, w, b, mode="w8a8", x_scale=w.x_scale, padding="CAUSAL",
-            activation="silu", accumulate="fast", out_dtype=x.dtype,
-        )
-    # weight-only (w8a16-style) fallback: dequantize in registers
-    w = w.dequant(x.dtype) if isinstance(w, QuantizedWeight) else w.astype(x.dtype)
-    if backend == "sliding_pallas":
-        from repro.kernels import ops
-
-        return ops.conv1d_depthwise(
-            x, w, padding="CAUSAL", bias=b, activation="silu"
-        )
-    if backend == "sliding":
-        y = conv1d_depthwise_sliding(x, w, padding="CAUSAL")
-    elif backend == "xla":
-        y = conv1d_xla(x, w[:, None, :].reshape(w.shape[0], 1, w.shape[1]),
-                       padding="CAUSAL", groups=w.shape[1])
-    else:
-        raise ValueError(backend)
-    return jax.nn.silu(y + b.astype(y.dtype))
-
-
-def _conv_w_taps(w) -> int:
+    compiled ``qconv`` fast path on non-Pallas backends); any other
+    ``QuantizedWeight`` dequantizes in registers. This is the PREFILL
+    path; the per-token decode window conv (``mamba_apply`` with
+    ``state``) is an O(K·C) elementwise product with nothing to win from
+    int8 and stays float. The activation scale is the leaf's calibrated
+    ``x_scale`` when present, dynamic absmax otherwise (mamba sites
+    execute under the period scan, where calibration can't observe)."""
     from repro.quant.qconv import QuantizedWeight
 
-    return (w.q if isinstance(w, QuantizedWeight) else w).shape[0]
+    w8a8 = isinstance(w, QuantizedWeight) and cfg.conv_precision == "w8a8"
+    return conv_bias_act(
+        "conv1d_depthwise", x, w, b, mode="w8a8" if w8a8 else None,
+        activation="silu", padding="CAUSAL", backend=cfg.conv_backend,
+    )
 
 
 SUBCHUNK = 32

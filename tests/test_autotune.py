@@ -42,6 +42,19 @@ def test_autotune_conv2d_writes_cache(rng, tuning_cache):
     assert {"tile_h", "tile_w"} <= set(entry)
 
 
+def _spy_conv1d_kernel(monkeypatch):
+    """Record the kwargs of each fp conv1d kernel call the dispatch makes."""
+    seen = {}
+    row = ops._CONV["conv1d"]
+
+    def spy(x, w, bias=None, **kw):
+        seen.update(kw)
+        return row.kernel(x, w, bias, **kw)
+
+    monkeypatch.setitem(ops._CONV, "conv1d", row._replace(kernel=spy))
+    return seen
+
+
 def test_ops_consults_tuned_config(rng, tuning_cache, monkeypatch):
     """ops.conv1d must pick up a cached non-default tiling for its shape."""
     x = jnp.asarray(rng.normal(size=(1, 100, 8)).astype(np.float32))
@@ -50,14 +63,7 @@ def test_ops_consults_tuned_config(rng, tuning_cache, monkeypatch):
     autotune.record(key, {"tile_l": 13, "cin_block": 0, "cout_block": 0,
                           "regime": "generic"})
 
-    seen = {}
-    real = ops.sliding_conv1d.conv1d_sliding_pallas
-
-    def spy(x, w, bias=None, **kw):
-        seen.update(kw)
-        return real(x, w, bias, **kw)
-
-    monkeypatch.setattr(ops.sliding_conv1d, "conv1d_sliding_pallas", spy)
+    seen = _spy_conv1d_kernel(monkeypatch)
     got = ops.conv1d(x, w, backend="sliding", interpret=True)
     assert seen["tile_l"] == 13 and seen["regime"] == "generic"
     np.testing.assert_allclose(got, ref.conv1d_ref(x, w), **TOL)
@@ -71,14 +77,7 @@ def test_auto_channel_blocking_large_channels(rng, tuning_cache, monkeypatch):
     """Above AUTO_BLOCK_THRESHOLD the dispatcher blocks channels even with
     no tuned entry — the acceptance guarantee that Cin=Cout=512 never loads
     a full-channel VMEM tile."""
-    seen = {}
-    real = ops.sliding_conv1d.conv1d_sliding_pallas
-
-    def spy(x, w, bias=None, **kw):
-        seen.update(kw)
-        return real(x, w, bias, **kw)
-
-    monkeypatch.setattr(ops.sliding_conv1d, "conv1d_sliding_pallas", spy)
+    seen = _spy_conv1d_kernel(monkeypatch)
     x = jnp.asarray(rng.normal(size=(1, 24, 512)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(3, 512, 512)).astype(np.float32))
     got = ops.conv1d(x, w, backend="sliding", interpret=True)
